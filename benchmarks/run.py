@@ -378,6 +378,8 @@ def e2e_sharded_gemm():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_REPO_ROOT, "src")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # host devices only: this process may already hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-m", "repro.parallel.benchrun",
                         "--mesh", "4x2", "--json"],
                        env=env, capture_output=True, text=True, timeout=900)
@@ -793,6 +795,8 @@ def main() -> None:
                     help="enable repro.obs tracing and write a Chrome "
                          "trace-event JSON of the benchmark run to PATH")
     args = ap.parse_args()
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         from repro import obs
         obs.enable(clear_events=True)

@@ -55,7 +55,11 @@ from repro.core import quant as quantlib
 from .spec import IMPLS, QuantSpec
 
 __all__ = ["GemmEngine", "register", "get_engine", "engine_names",
-           "active_planes"]
+           "active_planes", "TRACED_INT8_ROUTE"]
+
+# repro_gemm_dispatch_total route label of a kernel engine called under
+# tracing without a plan record (lowered to one int8 dot, no kernel)
+TRACED_INT8_ROUTE = "traced_int8"
 
 _REGISTRY: Dict[str, "GemmEngine"] = {}
 
@@ -360,7 +364,12 @@ class PallasEngine(GemmEngine):
         if _is_traced(x, w):
             # traced without a plan (dry-run cost analysis, jit'd train
             # steps): lower to the int8 engine -- one int8 dot is the
-            # kernel's cost-representative, bit-exact lowering.
+            # kernel's cost-representative, bit-exact lowering.  Counted
+            # under its own route, so a serving path that should run the
+            # kernels can prove it never took this lowering.
+            from repro.obs import trace as obs_trace
+            if obs_trace.enabled():
+                ops.count_dispatch(TRACED_INT8_ROUTE)
             return get_engine("int8").apply(
                 w, x, spec, bias=bias, activation=activation,
                 out_dtype=out_dtype)

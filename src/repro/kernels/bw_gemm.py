@@ -54,6 +54,10 @@ SCHED_COLS = {"plane": 0, "row": 1, "kblk": 2, "weight": 3,
               "first": 4, "last": 5, "d_slot": 6, "b_slot": 7, "b_fetch": 8}
 (_PLANE, _ROW, _KBLK, _WEIGHT, _FIRST, _LAST,
  _DSLOT, _BSLOT, _BFETCH) = range(9)
+# The kernels read the schedule row-major from a flat int32 [L * _NCOLS]
+# SMEM array.  A 2-D [L, 9] array is padded to 128 words a row there, so
+# the v5e's 1 MiB of SMEM would refuse any schedule past about 2k steps.
+_NCOLS = len(SCHED_COLS)
 
 # Activations the fused epilogue can apply on the dequantised accumulator.
 # Single source of truth: repro.models.layers.activation resolves names
@@ -117,20 +121,45 @@ def _check_epilogue(fn: str, activation, scale, scale_shape, scale_n,
             f"(1, {n})")
 
 
-def _kernel(mask_ref, d_ref, b_ref, o_ref, *, n_planes: int, radix: int):
+def _int_dot(d, b):
+    """int8 x int8 -> int32 on the MXU (exact: no int32 operand widening,
+    which the TPU's matmul unit does not take)."""
+    return jax.lax.dot_general(d, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
+def _flat_mask(mask):
+    """bool [BW, Mb, Kb] -> int32 [BW*Mb*Kb] for scalar prefetch (SMEM
+    holds 32-bit scalars, and a 1-D array is not tile-padded there)."""
+    return jnp.asarray(mask).astype(jnp.int32).reshape(-1)
+
+
+def _flat_schedule(schedule):
+    """int [L, >=6] schedule -> int32 [L * _NCOLS] (missing pipelined
+    columns zero-filled; the v2 kernels never read them)."""
+    sched = jnp.asarray(schedule, jnp.int32)
+    sched = jnp.pad(sched, ((0, 0), (0, _NCOLS - sched.shape[1])))
+    return sched.reshape(-1)
+
+
+def _cell(sched, step, col):
+    """Schedule entry (step, col) of a flat row-major schedule ref."""
+    return sched[step * _NCOLS + col]
+
+
+def _kernel(mask_ref, d_ref, b_ref, o_ref, *, n_planes: int, radix: int,
+            mb: int, kb: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
-    b = b_ref[...].astype(jnp.int32)
+    b = b_ref[...]
+    i, kk = pl.program_id(0), pl.program_id(2)
     for bw in range(n_planes):          # unrolled: BW is small and static
         weight = radix ** bw
 
-        @pl.when(mask_ref[bw, 0, 0])
+        @pl.when(mask_ref[(bw * mb + i) * kb + kk] != 0)
         def _plane(bw=bw, weight=weight):
-            d = d_ref[bw].astype(jnp.int32)
-            pp = jax.lax.dot_general(
-                d, b, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
+            pp = _int_dot(d_ref[bw], b)
             # deferred shift (OPT2): one scale per plane-block, post-MXU
             o_ref[...] += pp * weight
 
@@ -150,26 +179,32 @@ def bw_gemm(digits, b, mask, *, block_m: int = 128, block_n: int = 128,
     _check_dims("bw_gemm", m, k, k2, n, block_m, block_n, block_k)
     _check_mask("bw_gemm", mask, bw_n, m // block_m, k // block_k)
     grid = (m // block_m, n // block_n, k // block_k)
-    kernel = functools.partial(_kernel, n_planes=bw_n, radix=radix)
-    return pl.pallas_call(
-        kernel,
+    kernel = functools.partial(_kernel, n_planes=bw_n, radix=radix,
+                               mb=grid[0], kb=grid[2])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # plane-block mask: scalar-prefetched into SMEM, read by pl.when
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            # plane-block mask: tiny, lives alongside the tiles
-            pl.BlockSpec((bw_n, 1, 1), lambda i, j, kk: (0, i, kk)),
             # all BW planes of the (i, kk) block of A
-            pl.BlockSpec((bw_n, block_m, block_k), lambda i, j, kk: (0, i, kk)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((bw_n, block_m, block_k),
+                         lambda i, j, kk, msk: (0, i, kk)),
+            pl.BlockSpec((block_k, block_n), lambda i, j, kk, msk: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, kk, msk: (i, j)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-    )(mask, digits, b)
+    )(_flat_mask(mask), digits, b)
 
 
 def _fused_kernel(mask_ref, d_ref, b_ref, scale_ref, scale_n_ref, bias_ref,
-                  o_ref, acc_ref, *, n_planes: int, radix: int, k_steps: int,
-                  activation, has_bias: bool, has_scale_n: bool):
+                  o_ref, acc_ref, *, n_planes: int, radix: int, mb: int,
+                  kb: int, activation, has_bias: bool, has_scale_n: bool):
     """bw_gemm with the dequant epilogue folded in.
 
     The int32 accumulator lives in a VMEM scratch block revisited across the
@@ -182,19 +217,16 @@ def _fused_kernel(mask_ref, d_ref, b_ref, scale_ref, scale_n_ref, bias_ref,
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-    b = b_ref[...].astype(jnp.int32)
+    b = b_ref[...]
+    i, kk = pl.program_id(0), pl.program_id(2)
     for bw in range(n_planes):          # unrolled: BW is small and static
         weight = radix ** bw
 
-        @pl.when(mask_ref[bw, 0, 0])
+        @pl.when(mask_ref[(bw * mb + i) * kb + kk] != 0)
         def _plane(bw=bw, weight=weight):
-            d = d_ref[bw].astype(jnp.int32)
-            pp = jax.lax.dot_general(
-                d, b, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-            acc_ref[...] += pp * weight
+            acc_ref[...] += _int_dot(d_ref[bw], b) * weight
 
-    @pl.when(pl.program_id(2) == k_steps - 1)
+    @pl.when(kk == kb - 1)
     def _epilogue():
         s = scale_ref[...]
         if has_scale_n:
@@ -240,15 +272,15 @@ def bw_gemm_fused(digits, b, mask, scale, bias=None, scale_n=None, *,
     if epilogue_axis == "m":
         _check_epilogue("bw_gemm_fused", activation, scale, (m, 1),
                         scale_n, n)
-        vec_spec = pl.BlockSpec((block_m, 1), lambda i, j, kk: (i, 0))
-        col_spec = pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j))
+        vec_spec = pl.BlockSpec((block_m, 1), lambda i, j, kk, msk: (i, 0))
+        col_spec = pl.BlockSpec((1, block_n), lambda i, j, kk, msk: (0, j))
     else:
         if scale_n is not None:
             raise ValueError("bw_gemm_fused: scale_n only supports "
                              "epilogue_axis='m'")
         _check_epilogue("bw_gemm_fused", activation, scale, (1, n),
                         scale_n, n)
-        vec_spec = pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j))
+        vec_spec = pl.BlockSpec((1, block_n), lambda i, j, kk, msk: (0, j))
         col_spec = vec_spec
     has_scale_n = scale_n is not None
     if not has_scale_n:                 # placeholder so arity is static
@@ -258,24 +290,29 @@ def bw_gemm_fused(digits, b, mask, scale, bias=None, scale_n=None, *,
         bias = jnp.zeros_like(scale)
     grid = (m // block_m, n // block_n, k // block_k)
     kernel = functools.partial(_fused_kernel, n_planes=bw_n, radix=radix,
-                               k_steps=grid[2], activation=activation,
+                               mb=grid[0], kb=grid[2], activation=activation,
                                has_bias=has_bias, has_scale_n=has_scale_n)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bw_n, 1, 1), lambda i, j, kk: (0, i, kk)),
-            pl.BlockSpec((bw_n, block_m, block_k), lambda i, j, kk: (0, i, kk)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((bw_n, block_m, block_k),
+                         lambda i, j, kk, msk: (0, i, kk)),
+            pl.BlockSpec((block_k, block_n), lambda i, j, kk, msk: (kk, j)),
             vec_spec,
             col_spec,
             vec_spec,
         ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, kk, msk: (i, j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
         interpret=interpret,
-    )(mask, digits, b, scale.astype(jnp.float32),
+    )(_flat_mask(mask), digits, b, scale.astype(jnp.float32),
       scale_n.astype(jnp.float32), bias.astype(jnp.float32))
 
 
@@ -298,17 +335,14 @@ def bw_gemm_fused(digits, b, mask, scale, bias=None, scale_n=None, *,
 def _sparse_kernel(sched_ref, d_ref, b_ref, o_ref):
     s = pl.program_id(1)
 
-    @pl.when(sched_ref[s, _FIRST] == 1)
+    @pl.when(_cell(sched_ref, s, _FIRST) == 1)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    d = d_ref[0].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
-    pp = jax.lax.dot_general(d, b, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.int32)
+    pp = _int_dot(d_ref[0], b_ref[...])
     # deferred shift (OPT2): the plane scale comes from the schedule, so
     # sentinel/padding steps (weight 0) contribute exact zeros
-    o_ref[...] += pp * sched_ref[s, _WEIGHT]
+    o_ref[...] += pp * _cell(sched_ref, s, _WEIGHT)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
@@ -335,21 +369,21 @@ def bw_gemm_sparse(digits, b, schedule, *, block_m: int = 128,
         in_specs=[
             # gather exactly the one digit plane this step needs
             pl.BlockSpec((1, block_m, block_k),
-                         lambda j, s, sched: (sched[s, _PLANE],
-                                              sched[s, _ROW],
-                                              sched[s, _KBLK])),
+                         lambda j, s, sched: (_cell(sched, s, _PLANE),
+                                              _cell(sched, s, _ROW),
+                                              _cell(sched, s, _KBLK))),
             pl.BlockSpec((block_k, block_n),
-                         lambda j, s, sched: (sched[s, _KBLK], j)),
+                         lambda j, s, sched: (_cell(sched, s, _KBLK), j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
-                               lambda j, s, sched: (sched[s, _ROW], j)),
+                               lambda j, s, sched: (_cell(sched, s, _ROW), j)),
     )
     return pl.pallas_call(
         _sparse_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-    )(jnp.asarray(schedule, jnp.int32), digits, b)
+    )(_flat_schedule(schedule), digits, b)
 
 
 def _sparse_fused_kernel(sched_ref, d_ref, b_ref, scale_ref, scale_n_ref,
@@ -363,17 +397,14 @@ def _sparse_fused_kernel(sched_ref, d_ref, b_ref, scale_ref, scale_n_ref,
     """
     s = pl.program_id(1)
 
-    @pl.when(sched_ref[s, _FIRST] == 1)
+    @pl.when(_cell(sched_ref, s, _FIRST) == 1)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    d = d_ref[0].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
-    pp = jax.lax.dot_general(d, b, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.int32)
-    acc_ref[...] += pp * sched_ref[s, _WEIGHT]
+    pp = _int_dot(d_ref[0], b_ref[...])
+    acc_ref[...] += pp * _cell(sched_ref, s, _WEIGHT)
 
-    @pl.when(sched_ref[s, _LAST] == 1)
+    @pl.when(_cell(sched_ref, s, _LAST) == 1)
     def _epilogue():
         sc = scale_ref[...]
         if has_scale_n:
@@ -421,7 +452,7 @@ def bw_gemm_sparse_fused(digits, b, schedule, scale, bias=None, scale_n=None,
         bias = jnp.zeros_like(scale)
     steps = schedule.shape[0]
     vec_spec = pl.BlockSpec((block_m, 1),
-                            lambda j, s, sched: (sched[s, _ROW], 0))
+                            lambda j, s, sched: (_cell(sched, s, _ROW), 0))
     col_spec = pl.BlockSpec((1, block_n), lambda j, s, sched: (0, j))
     kernel = functools.partial(_sparse_fused_kernel, activation=activation,
                                has_bias=has_bias, has_scale_n=has_scale_n)
@@ -430,17 +461,17 @@ def bw_gemm_sparse_fused(digits, b, schedule, scale, bias=None, scale_n=None,
         grid=(n // block_n, steps),
         in_specs=[
             pl.BlockSpec((1, block_m, block_k),
-                         lambda j, s, sched: (sched[s, _PLANE],
-                                              sched[s, _ROW],
-                                              sched[s, _KBLK])),
+                         lambda j, s, sched: (_cell(sched, s, _PLANE),
+                                              _cell(sched, s, _ROW),
+                                              _cell(sched, s, _KBLK))),
             pl.BlockSpec((block_k, block_n),
-                         lambda j, s, sched: (sched[s, _KBLK], j)),
+                         lambda j, s, sched: (_cell(sched, s, _KBLK), j)),
             vec_spec,
             col_spec,
             vec_spec,
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
-                               lambda j, s, sched: (sched[s, _ROW], j)),
+                               lambda j, s, sched: (_cell(sched, s, _ROW), j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
     )
     return pl.pallas_call(
@@ -448,7 +479,7 @@ def bw_gemm_sparse_fused(digits, b, schedule, scale, bias=None, scale_n=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
         interpret=interpret,
-    )(jnp.asarray(schedule, jnp.int32), digits, b,
+    )(_flat_schedule(schedule), digits, b,
       scale.astype(jnp.float32), scale_n.astype(jnp.float32),
       bias.astype(jnp.float32))
 
@@ -492,53 +523,53 @@ def _pipelined_dma_plumbing(sched_ref, d_hbm, b_hbm, d_buf, b_buf, d_sem,
     s = pl.program_id(1)
 
     def d_copy(step):
-        slot = sched_ref[step, _DSLOT]
+        slot = _cell(sched_ref, step, _DSLOT)
         return pltpu.make_async_copy(
-            d_hbm.at[sched_ref[step, _PLANE],
-                     pl.ds(sched_ref[step, _ROW] * block_m, block_m),
-                     pl.ds(sched_ref[step, _KBLK] * block_k, block_k)],
+            d_hbm.at[_cell(sched_ref, step, _PLANE),
+                     pl.ds(_cell(sched_ref, step, _ROW) * block_m, block_m),
+                     pl.ds(_cell(sched_ref, step, _KBLK) * block_k, block_k)],
             d_buf.at[slot], d_sem.at[slot])
 
     def b_copy(step):
-        slot = sched_ref[step, _BSLOT]
+        slot = _cell(sched_ref, step, _BSLOT)
         return pltpu.make_async_copy(
-            b_hbm.at[pl.ds(sched_ref[step, _KBLK] * block_k, block_k),
+            b_hbm.at[pl.ds(_cell(sched_ref, step, _KBLK) * block_k, block_k),
                      pl.ds(j * block_n, block_n)],
             b_buf.at[slot], b_sem.at[slot])
 
     @pl.when(s == 0)
     def _warmup():                       # step 0 has no predecessor
-        @pl.when(sched_ref[0, _WEIGHT] != 0)
+        @pl.when(_cell(sched_ref, 0, _WEIGHT) != 0)
         def _():
             d_copy(0).start()
 
-        @pl.when(sched_ref[0, _BFETCH] == 1)
+        @pl.when(_cell(sched_ref, 0, _BFETCH) == 1)
         def _():
             b_copy(0).start()
 
     @pl.when(s + 1 < steps)
     def _prefetch():                     # issue s+1's gather under s's MXU
-        @pl.when(sched_ref[s + 1, _WEIGHT] != 0)
+        @pl.when(_cell(sched_ref, s + 1, _WEIGHT) != 0)
         def _():
             d_copy(s + 1).start()
 
-        @pl.when(sched_ref[s + 1, _BFETCH] == 1)
+        @pl.when(_cell(sched_ref, s + 1, _BFETCH) == 1)
         def _():
             b_copy(s + 1).start()
 
     # wait only for what was started: the issue predicates at step s-1 (or
     # the warm-up) read the same schedule cells, so starts and waits pair
     # exactly once per slot
-    @pl.when(sched_ref[s, _WEIGHT] != 0)
+    @pl.when(_cell(sched_ref, s, _WEIGHT) != 0)
     def _wait_d():
         d_copy(s).wait()
 
-    @pl.when(sched_ref[s, _BFETCH] == 1)
+    @pl.when(_cell(sched_ref, s, _BFETCH) == 1)
     def _wait_b():
         b_copy(s).wait()
 
-    d = d_buf[sched_ref[s, _DSLOT]].astype(jnp.int32)
-    b = b_buf[sched_ref[s, _BSLOT]].astype(jnp.int32)
+    d = d_buf[_cell(sched_ref, s, _DSLOT)]
+    b = b_buf[_cell(sched_ref, s, _BSLOT)]
     return d, b
 
 
@@ -551,22 +582,21 @@ def _sparse_pipelined_kernel(sched_ref, d_hbm, b_hbm, o_hbm, acc_ref, d_buf,
     d, b = _pipelined_dma_plumbing(
         sched_ref, d_hbm, b_hbm, d_buf, b_buf, d_sem, b_sem,
         block_m=block_m, block_n=block_n, block_k=block_k, steps=steps)
-    row = sched_ref[s, _ROW]
+    row = _cell(sched_ref, s, _ROW)
 
-    @pl.when(sched_ref[s, _FIRST] == 1)
+    @pl.when(_cell(sched_ref, s, _FIRST) == 1)
     def _init():
         acc_ref[pl.ds(row * block_m, block_m), :] = jnp.zeros(
             (block_m, block_n), jnp.int32)
 
-    @pl.when(sched_ref[s, _WEIGHT] != 0)
+    @pl.when(_cell(sched_ref, s, _WEIGHT) != 0)
     def _compute():
-        pp = jax.lax.dot_general(d, b, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)
+        pp = _int_dot(d, b)
         # deferred shift (OPT2): plane scale from the schedule
         acc_ref[pl.ds(row * block_m, block_m), :] += \
-            pp * sched_ref[s, _WEIGHT]
+            pp * _cell(sched_ref, s, _WEIGHT)
 
-    @pl.when(sched_ref[s, _LAST] == 1)
+    @pl.when(_cell(sched_ref, s, _LAST) == 1)
     def _flush():                        # row complete: write its only HBM
         stage_ref[...] = acc_ref[pl.ds(row * block_m, block_m), :]
         cp = pltpu.make_async_copy(
@@ -604,9 +634,9 @@ def bw_gemm_sparse_pipelined(digits, b, schedule, *, block_m: int = 128,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n // block_n, steps),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),    # digits (HBM)
-                  pl.BlockSpec(memory_space=pltpu.ANY)],   # B (HBM)
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),    # digits (HBM)
+                  pl.BlockSpec(memory_space=pl.ANY)],   # B (HBM)
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((m, block_n), jnp.int32),           # acc panel
             pltpu.VMEM((2, block_m, block_k), jnp.int8),   # digit dbl-buf
@@ -622,7 +652,7 @@ def bw_gemm_sparse_pipelined(digits, b, schedule, *, block_m: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-    )(jnp.asarray(schedule, jnp.int32), digits, b)
+    )(_flat_schedule(schedule), digits, b)
 
 
 def _sparse_fused_pipelined_kernel(sched_ref, d_hbm, b_hbm, scale_ref,
@@ -636,21 +666,20 @@ def _sparse_fused_pipelined_kernel(sched_ref, d_hbm, b_hbm, scale_ref,
     d, b = _pipelined_dma_plumbing(
         sched_ref, d_hbm, b_hbm, d_buf, b_buf, d_sem, b_sem,
         block_m=block_m, block_n=block_n, block_k=block_k, steps=steps)
-    row = sched_ref[s, _ROW]
+    row = _cell(sched_ref, s, _ROW)
 
-    @pl.when(sched_ref[s, _FIRST] == 1)
+    @pl.when(_cell(sched_ref, s, _FIRST) == 1)
     def _init():
         acc_ref[pl.ds(row * block_m, block_m), :] = jnp.zeros(
             (block_m, block_n), jnp.int32)
 
-    @pl.when(sched_ref[s, _WEIGHT] != 0)
+    @pl.when(_cell(sched_ref, s, _WEIGHT) != 0)
     def _compute():
-        pp = jax.lax.dot_general(d, b, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)
+        pp = _int_dot(d, b)
         acc_ref[pl.ds(row * block_m, block_m), :] += \
-            pp * sched_ref[s, _WEIGHT]
+            pp * _cell(sched_ref, s, _WEIGHT)
 
-    @pl.when(sched_ref[s, _LAST] == 1)
+    @pl.when(_cell(sched_ref, s, _LAST) == 1)
     def _epilogue():
         sc = scale_ref[pl.ds(row * block_m, block_m), :]
         if has_scale_n:
@@ -712,15 +741,15 @@ def bw_gemm_sparse_fused_pipelined(digits, b, schedule, scale, bias=None,
         num_scalar_prefetch=1,
         grid=(n // block_n, steps),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),          # digits (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),          # B (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),          # digits (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),          # B (HBM)
             # the per-row vectors are tiny: keep them whole in VMEM and
             # slice the LAST row's span in the epilogue
             pl.BlockSpec((m, 1), lambda j, s, sched: (0, 0)),
             pl.BlockSpec((1, block_n), lambda j, s, sched: (0, j)),
             pl.BlockSpec((m, 1), lambda j, s, sched: (0, 0)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((m, block_n), jnp.int32),           # acc panel
             pltpu.VMEM((2, block_m, block_k), jnp.int8),   # digit dbl-buf
@@ -736,6 +765,6 @@ def bw_gemm_sparse_fused_pipelined(digits, b, schedule, scale, bias=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
         interpret=interpret,
-    )(jnp.asarray(schedule, jnp.int32), digits, b,
+    )(_flat_schedule(schedule), digits, b,
       scale.astype(jnp.float32), scale_n.astype(jnp.float32),
       bias.astype(jnp.float32))

@@ -64,11 +64,17 @@ __all__ = ["PlannedOperand", "encode_planes", "plane_block_mask",
            "schedule_stats", "bw_gemm_sparse", "bw_gemm_sparse_fused",
            "bw_gemm_sparse_pipelined", "bw_gemm_sparse_fused_pipelined",
            "SPARSE_DENSITY_THRESHOLD", "SCHEDULE_ORDERS", "DISPATCHES",
-           "verification_enabled", "ENV_VERIFY"]
+           "verification_enabled", "ENV_VERIFY", "count_dispatch"]
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def count_dispatch(route: str) -> None:
+    """Count one quantized-GEMM dispatch under ``route`` (callers gate
+    this on obs being enabled)."""
+    _M_DISPATCH.labels(route=route).inc()
 
 
 # ---------------------------------------------------------------------------
@@ -985,7 +991,7 @@ def planned_dense_apply(plan: dict, x, spec, n_out: int, *, bias=None,
     # hot path: the span + dispatch counter take one no-op branch when
     # obs is disabled (pinned by the obs.overhead bench lane)
     if obs_trace.enabled():
-        _M_DISPATCH.labels(route=route).inc()
+        count_dispatch(route)
         sp = obs_trace.span("ops.planned_dense_apply", cat="kernel",
                             route=route, fused=bool(fused), order=order,
                             m=int(n_out), k=int(k), n=int(batch))
@@ -1118,16 +1124,19 @@ def plan_params(params, spec, should_plan=None, order: Optional[str] = None):
             out["w_plan"] = plan_dense_weight(w, spec, order=order)
             count += 1
         else:                  # [L, K, N] stacked for the layer scan
-            plans = [plan_dense_weight(w[i], spec, use_cache=False,
-                                       order=order)
-                     for i in range(w.shape[0])]
+            # each layer's plan moves to host memory as it is built and the
+            # stack is assembled there: stacking on the device would hold
+            # every layer's digit planes twice at the peak
+            plans = [jax.tree.map(np.asarray, plan_dense_weight(
+                w[i], spec, use_cache=False, order=order))
+                for i in range(w.shape[0])]
             # per-layer schedules have data-dependent lengths: pad to the
             # longest with exact no-op entries so the stack scans cleanly
             max_steps = max(p["schedule"].shape[0] for p in plans)
             for p in plans:
-                p["schedule"] = jnp.asarray(pad_schedule(
-                    np.asarray(p["schedule"]), max_steps))
-            out["w_plan"] = jax.tree.map(lambda *xs: jnp.stack(xs), *plans)
+                p["schedule"] = pad_schedule(p["schedule"], max_steps)
+            out["w_plan"] = jax.tree.map(
+                lambda *xs: jnp.asarray(np.stack(xs)), *plans)
             count += w.shape[0]
         return out
 

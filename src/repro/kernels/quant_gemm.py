@@ -22,10 +22,10 @@ def _kernel(a_ref, b_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU, int32 accumulation
     o_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
@@ -60,10 +60,9 @@ def _fused_kernel(a_ref, b_ref, scale_ref, bias_ref, o_ref, acc_ref, *,
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():

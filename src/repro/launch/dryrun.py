@@ -1,9 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST precede every other import: jax locks the device
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512")
+# The lines above MUST precede every other import: jax locks the device
 # count at first init, and the dry-run needs 512 placeholder host devices to
 # build the production meshes.  (Smoke tests / benches import repro without
-# this module and see 1 device.)
+# this module and see 1 device.)  main() also pins the process, and the
+# per-cell children it starts, to the CPU backend.
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture x input-shape) cell, lower + compile the step the
@@ -132,8 +134,6 @@ def _compile_step(cfg, shape, mesh, rules, multi_pod: bool,
 def _cost_tuple(compiled) -> dict:
     """(flops, bytes, collective-bytes, coll-by-op) of a compiled module."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # older jaxlib: list of one dict
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = rl.collective_bytes(hlo)
     return {"flops": float(cost.get("flops", 0.0)),
@@ -397,6 +397,8 @@ def main(argv=None) -> int:
                     help="enable repro.obs tracing and write a Chrome "
                          "trace-event JSON of the lower/compile cells")
     args = ap.parse_args(argv)
+    from repro.launch.runtime import pin_cpu
+    pin_cpu("repro.launch.dryrun")
 
     if args.trace:
         obs_trace.enable(clear_events=True)

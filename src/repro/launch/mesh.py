@@ -9,6 +9,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "require_devices",
            "parse_mesh_shape"]
@@ -28,7 +29,10 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     for size in shape:
         need *= int(size)
     require_devices(need, shape=shape, axes=axes)
-    return jax.make_mesh(tuple(int(s) for s in shape), tuple(axes))
+    # Auto axes: the sharding rules place arrays with
+    # with_sharding_constraint / NamedSharding, which Explicit axes refuse
+    return jax.make_mesh(tuple(int(s) for s in shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def parse_mesh_shape(text: str) -> Tuple[int, ...]:

@@ -69,7 +69,10 @@ def _parse_slack(text):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=ARCHS, default="granite-34b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced same-family config (--no-smoke: the "
+                         "published widths)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -176,6 +179,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.resume and not args.journal:
         ap.error("--resume requires --journal PATH")
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
 
     from repro import obs
     if args.trace:

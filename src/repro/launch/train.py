@@ -130,6 +130,8 @@ def main(argv=None) -> int:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
     out = train(args.arch, smoke=args.smoke, steps=args.steps,
                 global_batch=args.batch, seq_len=args.seq, lr=args.lr,
                 schedule=args.schedule, quant_planes=args.quant_planes,

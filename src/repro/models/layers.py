@@ -36,8 +36,10 @@ __all__ = [
 
 def truncated_normal(key, shape, scale, dtype=jnp.float32):
     stddev = scale / np.sqrt(max(shape[0], 1))
-    return jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32) \
-        .astype(dtype) * stddev
+    # scale in float32, then cast: a numpy scalar multiplied after the cast
+    # would promote a bfloat16 result back to float32
+    return (jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
+            * stddev).astype(dtype)
 
 
 # ---------------------------------------------------------------------------

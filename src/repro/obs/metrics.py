@@ -294,7 +294,9 @@ GLOSSARY = {
     "repro_gemm_dispatch_total": {
         "type": "counter",
         "help": "planned_dense_apply dispatches by resolved route "
-                "(label route=); recorded only while obs is enabled."},
+                "(label route=), plus route=traced_int8 for a kernel "
+                "engine traced without a plan; recorded only while obs "
+                "is enabled."},
     "repro_serve_admitted_total": {
         "type": "counter",
         "help": "Requests admitted by the scheduler."},

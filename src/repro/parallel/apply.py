@@ -26,7 +26,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import chaos as _chaos
@@ -198,13 +197,13 @@ def sharded_planned_apply(splan: ShardedPlan, x, spec, n_out: int, *,
     else:
         sp = obs_trace.NULL_SPAN
     with sp:
-        acc = shard_map(
+        acc = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(None, AXIS_MODEL, AXIS_DATA),    # digit planes
                       P(None, AXIS_MODEL, AXIS_DATA),    # occupancy mask
                       P(AXIS_MODEL, AXIS_DATA, None, None),  # schedules
                       P(AXIS_DATA, None)),               # B (k-sliced)
-            out_specs=out_spec, check_rep=False,
+            out_specs=out_spec, check_vma=False,
         )(digits, mask, scheds, bt)
     with obs_trace.span("parallel.epilogue", cat="parallel",
                         n_out=int(n_out), batch=int(batch)):

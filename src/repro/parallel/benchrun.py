@@ -118,6 +118,8 @@ def main(argv=None) -> int:
                     help="print the result dict as JSON on stdout")
     args = ap.parse_args(argv)
 
+    from repro.launch.runtime import pin_cpu
+    pin_cpu("repro.parallel.benchrun")
     from repro.launch.mesh import parse_mesh_shape
     from repro.parallel.collectives import enable_async_collectives
     enable_async_collectives()          # no-op flags on the CPU backend

@@ -85,8 +85,6 @@ def shard_map_allreduce_int8(mesh, axis: str = "data"):
     f(local_grads) -> averaged grads; int8 payload + fp32 scale cross the
     wire (scales are psum'd to obtain a shared max-scale upper bound).
     """
-    from jax.experimental.shard_map import shard_map
-
     def allreduce(g):
         q, s = quantize_grad(g)
         # share a common scale so the int8 sum is well-defined
@@ -102,5 +100,5 @@ def shard_map_allreduce_int8(mesh, axis: str = "data"):
         return jax.tree.map(allreduce, tree)
 
     spec = P(axis)
-    return shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)

@@ -13,6 +13,16 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # suite: any plan a test builds is checked before a kernel sees it
 os.environ.setdefault("REPRO_VERIFY", "1")
 
+try:
+    from hypothesis import settings as _hyp_settings
+except ImportError:     # offline: tests fall back to tests/_propcheck
+    pass
+else:
+    # a property test's first example traces and compiles, which takes
+    # seconds: wall-clock deadlines would fail it by chance, not by bug
+    _hyp_settings.register_profile("repro", deadline=None)
+    _hyp_settings.load_profile("repro")
+
 
 @pytest.fixture(scope="session")
 def rng():

@@ -287,3 +287,35 @@ def test_fallback_under_tracing_without_plan_is_bit_exact(rng):
         lambda pp, xx: L.dense_apply(pp, xx, jnp.float32, spec))(p, x),
         np.float32)
     np.testing.assert_array_equal(got, want)
+
+
+def test_traced_fallback_counts_its_own_dispatch_route(rng):
+    """The traced-without-plan int8 lowering is counted under its own
+    route label, apart from the kernel routes a planned weight takes."""
+    from repro import obs
+    from repro.engine.registry import TRACED_INT8_ROUTE
+    from repro.models import layers as L
+    from repro.obs import metrics as obs_metrics
+    spec = QuantSpec(planes=3, impl="pallas_fused")
+    x = jnp.asarray(rng.normal(0, 1, size=(3, 64)).astype(np.float32))
+    w = jnp.asarray(rng.normal(0, 0.05, size=(64, 48)).astype(np.float32))
+    planned = {"w": w, "w_plan": ops.plan_dense_weight(w, spec)}
+    family = obs_metrics.get_registry().counter("repro_gemm_dispatch_total")
+
+    def count(route):
+        return family.labels(route=route).value
+
+    was = obs.enabled()
+    obs.enable()
+    try:
+        before = count(TRACED_INT8_ROUTE), count("dense")
+        jax.jit(lambda pp, xx: L.dense_apply(pp, xx, jnp.float32, spec))(
+            {"w": w}, x)
+        assert count(TRACED_INT8_ROUTE) == before[0] + 1
+        jax.jit(lambda pp, xx: L.dense_apply(pp, xx, jnp.float32, spec))(
+            planned, x)
+        assert count(TRACED_INT8_ROUTE) == before[0] + 1
+        assert count("dense") == before[1] + 1
+    finally:
+        if not was:
+            obs.disable()

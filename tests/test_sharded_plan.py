@@ -74,7 +74,7 @@ def test_shard_schedules_partition_global_mask(order, shards):
 
 
 @given(density=st.floats(0.05, 0.9), planes=st.integers(2, 4))
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 def test_partition_property_random_densities(density, planes):
     planned, _spec = _plan(256, 256, planes=planes, density=density,
                            seed=int(density * 1000) + planes)
