@@ -166,17 +166,19 @@ def attn_decode(p, x, cfg, cache_k, cache_v, pos, dtype=jnp.bfloat16):
     positions = pos[:, None]
     q, k_new, v_new = _project_qkv(p, x, cfg, positions, dtype)
     # scatter the new token into the cache at `pos`
-    upd_idx = (jnp.arange(b), pos)
-    cache_k = cache_k.at[upd_idx].set(k_new[:, 0])
-    cache_v = cache_v.at[upd_idx].set(v_new[:, 0])
-    qg = q.reshape(b, 1, n_kv, g, hd)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, cache_k,
-                        preferred_element_type=jnp.float32) / np.sqrt(hd)
-    valid = jnp.arange(cache_k.shape[1])[None, None, None, None, :] <= \
-        pos[:, None, None, None, None]
-    scores = jnp.where(valid, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, cache_v)
-    out = out.reshape(b, 1, cfg.n_heads * hd)
+    with jax.named_scope("kv_write"):
+        upd_idx = (jnp.arange(b), pos)
+        cache_k = cache_k.at[upd_idx].set(k_new[:, 0])
+        cache_v = cache_v.at[upd_idx].set(v_new[:, 0])
+    with jax.named_scope("attention"):
+        qg = q.reshape(b, 1, n_kv, g, hd)
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, cache_k,
+                            preferred_element_type=jnp.float32) / np.sqrt(hd)
+        valid = jnp.arange(cache_k.shape[1])[None, None, None, None, :] <= \
+            pos[:, None, None, None, None]
+        scores = jnp.where(valid, scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs, cache_v)
+        out = out.reshape(b, 1, cfg.n_heads * hd)
     return (L.dense_apply(p["wo"], out, dtype, cfg.quant_spec()),
             cache_k, cache_v)

@@ -90,7 +90,8 @@ def block_decode(p, x, cfg, ck, cv, pos, dtype=jnp.bfloat16):
     if cfg.n_experts:
         h, _ = M.moe_apply(p["moe"], norm_apply(cfg, p["ln2"], x), cfg, dtype)
     else:
-        h = mlp_apply(p["mlp"], norm_apply(cfg, p["ln2"], x), cfg, dtype)
+        with jax.named_scope("mlp"):
+            h = mlp_apply(p["mlp"], norm_apply(cfg, p["ln2"], x), cfg, dtype)
     return x + h, ck, cv
 
 
@@ -141,10 +142,12 @@ def _run_blocks(params, x, cfg, positions, dtype):
 
 def _logits(params, x, cfg, dtype):
     x = norm_apply(cfg, params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = L.embed_logits(params["embed"], x, dtype)
-    else:
-        logits = L.dense_apply(params["lm_head"], x, dtype, cfg.quant_spec())
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = L.embed_logits(params["embed"], x, dtype)
+        else:
+            logits = L.dense_apply(params["lm_head"], x, dtype,
+                                   cfg.quant_spec())
     if cfg.logit_softcap:
         c = cfg.logit_softcap
         logits = c * jnp.tanh(logits.astype(jnp.float32) / c)

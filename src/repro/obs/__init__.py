@@ -3,7 +3,9 @@
 Three pillars (see the README "Observability" section):
 
 * ``trace``     -- span API + Chrome/Perfetto trace-event JSON export;
-                   enabled by ``REPRO_TRACE`` (near-zero-cost when off).
+                   enabled by ``REPRO_TRACE`` (near-zero-cost when off);
+                   enabled spans and the always-on ``region()`` also
+                   land in a running ``jax.profiler`` trace.
 * ``metrics``   -- typed Counter/Gauge/Histogram registry with fixed
                    bucket edges (deterministic snapshots in virtual-time
                    mode), JSON + Prometheus exposition.
@@ -23,6 +25,6 @@ from .metrics import (GLOSSARY, MetricsRegistry,  # noqa: F401
                       prometheus_text, reset_metrics, snapshot)
 from .trace import (ENV_TRACE, NULL_SPAN, PID_RUNTIME,  # noqa: F401
                     PID_SERVER, complete_event, disable, enable, enabled,
-                    instant, save, span, to_chrome)
+                    instant, region, save, span, to_chrome)
 from .trace import clear as clear_trace  # noqa: F401
 from .trace import events as trace_events  # noqa: F401

@@ -9,7 +9,8 @@ Prometheus text format.
 Determinism contract: histograms use *fixed bucket edges*, so a
 virtual-time serving run — whose observed values are simulated seconds —
 produces a bit-identical snapshot on every host.  Nothing in a snapshot
-reads a wall clock.
+reads a wall clock, except ``repro_region_seconds``: the host seconds of
+``trace.region()`` boundaries, which count whether or not tracing is on.
 
 The default registry is pre-populated with the full metric glossary
 (``GLOSSARY``; documented in the README), so a snapshot always contains
@@ -62,6 +63,9 @@ class Counter:
         v = self.value
         return int(v) if float(v).is_integer() else v
 
+    def zero(self) -> None:
+        self.value = 0
+
 
 class Gauge:
     """Last-write-wins scalar."""
@@ -81,6 +85,9 @@ class Gauge:
 
     def snapshot(self):
         return self.value
+
+    def zero(self) -> None:
+        self.value = 0.0
 
 
 class Histogram:
@@ -114,6 +121,11 @@ class Histogram:
     def snapshot(self):
         return {"edges": list(self.edges), "counts": list(self.counts),
                 "sum": self.total, "count": self.count}
+
+    def zero(self) -> None:
+        self.counts = [0] * (len(self.edges) + 1)
+        self.total = 0.0
+        self.count = 0
 
 
 _KINDS = {"counter": Counter, "gauge": Gauge, "histogram": Histogram}
@@ -173,7 +185,12 @@ class MetricFamily:
         return {"type": self.kind, "help": self.help, "values": values}
 
     def reset(self) -> None:
-        self._children.clear()
+        """Zero every child in place: a child bound before the reset
+        (``fam.labels(...)`` kept by a hot path) keeps counting into the
+        snapshot."""
+        with self._lock:
+            for child in self._children.values():
+                child.zero()
 
 
 class MetricsRegistry:
@@ -247,7 +264,7 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
     def reset(self) -> None:
-        """Zero every family (children dropped; names kept)."""
+        """Zero every family (children zeroed in place; names kept)."""
         for fam in self._families.values():
             fam.reset()
 
@@ -259,6 +276,10 @@ _TIME_EDGES = (1e-5, 1e-4, 1e-3, 5e-3, 0.01, 0.05, 0.1, 0.5,
 _DENSITY_EDGES = (0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0)
 _DEPTH_EDGES = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
 _OCC_EDGES = (0.0, 0.25, 0.5, 0.75, 1.0)
+#: edges of ``repro_region_seconds``: 1-2-5 steps from 10 us to 100 s,
+#: so both a step's dispatch and an engine's weight planning fit
+REGION_EDGES = tuple(float(f"{m}e{e}") for e in range(-5, 2)
+                     for m in (1, 2, 5)) + (100.0,)
 
 #: The standard metric names (the README glossary is generated from the
 #: help strings here).  Every entry is pre-registered on the default
@@ -296,7 +317,8 @@ GLOSSARY = {
         "help": "planned_dense_apply dispatches by resolved route "
                 "(label route=), plus route=traced_int8 for a kernel "
                 "engine traced without a plan; recorded only while obs "
-                "is enabled."},
+                "is enabled.  Inside a jitted function it counts "
+                "lowerings (traces), not calls of the compiled step."},
     "repro_serve_admitted_total": {
         "type": "counter",
         "help": "Requests admitted by the scheduler."},
@@ -313,6 +335,27 @@ GLOSSARY = {
         "type": "counter",
         "help": "Engine decode steps; recorded only while obs is "
                 "enabled (hot path)."},
+    "repro_region_seconds": {
+        "type": "histogram", "edges": REGION_EDGES,
+        "help": "Host seconds of each trace.region() (label region=: "
+                "serve.build, serve.init_params, serve.plan_params, "
+                "serve.init_state, serve.admit, serve.step, "
+                "serve.upload, serve.compile, serve.dispatch, "
+                "serve.readback, serve.advance); counts with obs "
+                "disabled."},
+    "repro_serve_slot_steps_bound_total": {
+        "type": "counter",
+        "help": "Slots bound to a request, summed over engine steps; "
+                "counts with obs disabled."},
+    "repro_serve_kv_positions_live_total": {
+        "type": "counter",
+        "help": "Live cache positions (pos + 1 of each bound slot), "
+                "summed over engine steps; counts with obs disabled."},
+    "repro_serve_kv_positions_read_total": {
+        "type": "counter",
+        "help": "Cache positions the decode step reads (batch x "
+                "max_len), summed over engine steps; counts with obs "
+                "disabled."},
     "repro_serve_queue_depth": {
         "type": "histogram", "edges": _DEPTH_EDGES,
         "help": "Admission queue depth sampled per scheduling round."},

@@ -1,6 +1,24 @@
-"""Lightweight tracing: spans -> Chrome/Perfetto trace-event JSON.
+"""Lightweight tracing: spans and regions -> Chrome/Perfetto trace-event
+JSON and the JAX profiler's trace.
 
-Design constraints, in order:
+Two entry points:
+
+* ``span(name, **attrs)`` -- an opt-in span.  Disabled (the default) it
+  returns the shared ``NULL_SPAN``: no event, no timestamp, no
+  allocation.  Enabled it writes a JSON complete event and also opens a
+  ``jax.profiler.TraceAnnotation``, so the span lands in any running
+  profiler trace on the device trace's clock.
+* ``region(name)`` -- for the few host boundaries around device work
+  (the serving engine's build and step phases).  It is *always on*,
+  whether or not tracing is enabled: it opens a ``TraceAnnotation``
+  (recorded only while a profiler session runs; about half a
+  microsecond otherwise) and adds its duration to the fixed-edge
+  histogram ``repro_region_seconds{region=<name>}`` of the metrics
+  registry.  When tracing is enabled it also writes a JSON event.  So a
+  run that never enables tracing -- the chip benchmark -- still reads
+  region times from ``metrics.snapshot()``.
+
+Design constraints of ``span``, in order:
 
 1.  **Near-zero cost when disabled.**  ``span()`` checks one module-level
     bool and returns a shared no-op context manager — no event object,
@@ -37,9 +55,13 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["ENV_TRACE", "PID_RUNTIME", "PID_SERVER", "enabled", "enable",
-           "disable", "span", "instant", "complete_event", "events",
-           "clear", "save", "to_chrome"]
+from jax.profiler import TraceAnnotation
+
+from . import metrics as _metrics
+
+__all__ = ["ENV_TRACE", "PID_RUNTIME", "PID_SERVER", "REGION_SECONDS",
+           "enabled", "enable", "disable", "span", "region", "instant",
+           "complete_event", "events", "clear", "save", "to_chrome"]
 
 ENV_TRACE = "REPRO_TRACE"
 
@@ -99,7 +121,7 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args: Optional[dict]):
         self.name = name
@@ -115,11 +137,14 @@ class _Span:
         return self
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = _now_us()
+        self._ann.__exit__(*exc)
         ev = {"name": self.name, "cat": self.cat, "ph": "X",
               "ts": self._t0, "dur": t1 - self._t0,
               "pid": PID_RUNTIME, "tid": threading.get_ident()}
@@ -138,6 +163,55 @@ def span(name: str, cat: str = "repro", **attrs):
     if not _enabled:
         return NULL_SPAN
     return _Span(name, cat, attrs or None)
+
+
+REGION_SECONDS = "repro_region_seconds"
+_region_hists: Dict[str, Any] = {}
+
+
+class _Region:
+    __slots__ = ("name", "_hist", "_ann", "_t0")
+
+    def __init__(self, name: str, hist):
+        self.name = name
+        self._hist = hist
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._hist.observe(t1 - self._t0)
+        if _enabled:
+            ev = {"name": self.name, "cat": "region", "ph": "X",
+                  "ts": (self._t0 - _epoch) * 1e6,
+                  "dur": (t1 - self._t0) * 1e6,
+                  "pid": PID_RUNTIME, "tid": threading.get_ident()}
+            with _lock:
+                _events.append(ev)
+        return False
+
+
+def region(name: str) -> _Region:
+    """Context manager timing a host region around device work; always on.
+
+    ``with obs.region("serve.dispatch"): ...`` opens a profiler
+    ``TraceAnnotation`` and observes the region's seconds into
+    ``repro_region_seconds{region=name}`` whether or not tracing is
+    enabled; with tracing enabled it also writes the JSON event.  The
+    histogram child is bound once per name, so a call does no label
+    lookup.
+    """
+    hist = _region_hists.get(name)
+    if hist is None:
+        hist = _metrics.get_registry().histogram(
+            REGION_SECONDS, _metrics.REGION_EDGES).labels(region=name)
+        _region_hists[name] = hist
+    return _Region(name, hist)
 
 
 def instant(name: str, cat: str = "repro", **attrs) -> None:

@@ -51,6 +51,11 @@ _M_STEPS = _REG.counter("repro_serve_engine_steps_total")
 _M_SNAPSHOTS = _REG.counter("repro_serve_snapshots_total")
 _M_RESTORES = _REG.counter("repro_serve_restores_total")
 _M_TOK_RECOVERED = _REG.counter("repro_serve_tokens_recovered_total")
+# counted every step whether or not obs is enabled (the chip benchmark's
+# per-layer metrics read them); pre-bound, so a step does no lookup
+_M_SLOTS_BOUND = _REG.counter("repro_serve_slot_steps_bound_total").labels()
+_M_KV_LIVE = _REG.counter("repro_serve_kv_positions_live_total").labels()
+_M_KV_READ = _REG.counter("repro_serve_kv_positions_read_total").labels()
 
 # Families whose decode state is a recurrence (no position-masked cache):
 # their per-slot state row must be re-initialized when a slot is reused.
@@ -137,10 +142,29 @@ class ServeEngine:
         self.batch = batch
         self.max_len = max_len
         self.on_too_long = on_too_long
-        self.params = unbox(self.api.init(jax.random.PRNGKey(seed), cfg))
-        self.state = unbox(self.api.init_decode(cfg, batch, max_len))
-        self._state0 = jax.tree.map(jnp.copy, self.state) \
-            if self.api.family in RESET_STATE_FAMILIES else None
+        with obs_trace.region("serve.build"):
+            self._build(cfg, spec, seed)
+        self.slots = SlotAllocator(batch, max_len, audit=audit)
+        self.steps = 0
+        # checkpoint/restore tallies (the async server folds the per-run
+        # deltas into its failover stats)
+        self.ckpt_stats = {"snapshots": 0, "restored": 0,
+                           "reprefilled": 0, "tokens_recovered": 0,
+                           "tokens_reprefilled": 0}
+
+    def _build(self, cfg, spec, seed: int) -> None:
+        """Weights, the decode state, the weights' digit-plane plans and
+        the jitted step.  Each region blocks on its arrays, so its time is
+        the work it dispatched, not the next region's wait."""
+        with obs_trace.region("serve.init_params"):
+            self.params = jax.block_until_ready(
+                unbox(self.api.init(jax.random.PRNGKey(seed), cfg)))
+        with obs_trace.region("serve.init_state"):
+            self.state = unbox(self.api.init_decode(
+                cfg, self.batch, self.max_len))
+            self._state0 = jax.tree.map(jnp.copy, self.state) \
+                if self.api.family in RESET_STATE_FAMILIES else None
+            jax.block_until_ready((self.state, self._state0))
         self._kernel_path = spec is not None and \
             spec.impl in ("pallas", "pallas_fused", "pallas_sparse",
                           "pallas_pipelined")
@@ -154,7 +178,9 @@ class ServeEngine:
             # scans/slices them like any other parameter and every quantized
             # matmul executes the Pallas kernel.
             from repro.kernels import ops
-            self.params, planned = ops.plan_params(self.params, spec)
+            with obs_trace.region("serve.plan_params"):
+                self.params, planned = ops.plan_params(self.params, spec)
+                jax.block_until_ready(self.params)
             self.plan_density = ops.plan_tree_density(self.params)
             self.quant.plan_stats = {
                 "planned_weights": planned,
@@ -162,13 +188,6 @@ class ServeEngine:
                 "schedules_verified": ops.verification_enabled(),
                 **ops.plan_cache_stats()}
         self.step_fn = jax.jit(make_serve_step(cfg))
-        self.slots = SlotAllocator(batch, max_len, audit=audit)
-        self.steps = 0
-        # checkpoint/restore tallies (the async server folds the per-run
-        # deltas into its failover stats)
-        self.ckpt_stats = {"snapshots": 0, "restored": 0,
-                           "reprefilled": 0, "tokens_recovered": 0,
-                           "tokens_reprefilled": 0}
 
     # -- stepping surface (driven by the async server) -----------------------
 
@@ -190,6 +209,10 @@ class ServeEngine:
         and for any request with committed tokens but no usable
         snapshot — it re-prefills prompt + committed output, so the
         tokens survive either way."""
+        with obs_trace.region("serve.admit"):
+            return self._admit_from(scheduler, now)
+
+    def _admit_from(self, scheduler: Scheduler, now: float) -> int:
         admitted = 0
         for slot in self.slots.free_slots():
             req = scheduler.pop(now)
@@ -339,23 +362,34 @@ class ServeEngine:
                               tokens=len(req.out))
 
     def step(self, now: float = 0.0) -> List[ServeRequest]:
-        """One batched decode step; returns requests finished this step."""
-        # hot path: one no-op branch when obs is disabled (the
-        # obs.overhead bench lane + test_obs pin this)
-        if obs_trace.enabled():
-            _M_STEPS.inc()
-            sp = obs_trace.span("serve.decode_step", cat="serve",
-                                active=self.slots.active,
-                                impl=self.spec.impl if self.spec
-                                else None)
-        else:
-            sp = obs_trace.NULL_SPAN
-        with sp:
-            nxt, self.state = self.step_fn(
-                self.params, jnp.asarray(self.slots.cur),
-                jnp.asarray(self.slots.pos), self.state)
+        """One batched decode step; returns requests finished this step.
+
+        Always-on regions split it: ``serve.upload`` (tokens and
+        positions to the device), ``serve.dispatch`` (the jitted step
+        call, until it returns), ``serve.readback`` (waiting for the
+        sampled tokens) and ``serve.advance`` (the slots' bookkeeping).
+        The engine's first call is ``serve.compile`` in place of
+        ``serve.dispatch``: JAX traces and compiles the step there."""
+        slots = self.slots
+        with obs_trace.region("serve.step"):
+            if obs_trace.enabled():
+                _M_STEPS.inc()
+            pos = slots.pos
+            bound = [i for i, _ in slots.bound()]
+            _M_SLOTS_BOUND.inc(len(bound))
+            _M_KV_LIVE.inc(int(pos[bound].sum()) + len(bound))
+            _M_KV_READ.inc(self.batch * self.max_len)
+            with obs_trace.region("serve.upload"):
+                cur, pos = jnp.asarray(slots.cur), jnp.asarray(pos)
+            with obs_trace.region("serve.dispatch" if self.steps
+                                  else "serve.compile"):
+                nxt, self.state = self.step_fn(self.params, cur, pos,
+                                               self.state)
             self.steps += 1
-            return self.slots.advance(np.asarray(nxt), now)
+            with obs_trace.region("serve.readback"):
+                nxt = np.asarray(nxt)
+            with obs_trace.region("serve.advance"):
+                return slots.advance(nxt, now)
 
     # -- legacy blocking loop ------------------------------------------------
 

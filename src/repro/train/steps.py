@@ -199,7 +199,8 @@ def make_serve_step(cfg) -> Callable:
 
     def serve_step(params, tokens, pos, state):
         logits, new_state = api.decode_step(params, tokens, pos, state, cfg)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
         return nxt[:, None], new_state
 
     return serve_step

@@ -131,6 +131,37 @@ class TestTrace:
                 assert "ts" in ev and "dur" in ev
         assert any(e["ph"] == "i" for e in evs)
 
+    def test_region_counts_without_tracing(self, no_tracing):
+        hist = obs_metrics.get_registry().histogram(
+            obs_trace.REGION_SECONDS, obs_metrics.REGION_EDGES)
+        child = hist.labels(region="test.region_off")
+        n0, s0 = child.count, child.total
+        with obs.region("test.region_off"):
+            pass
+        assert child.count == n0 + 1 and child.total >= s0
+        assert obs.trace_events() == []
+
+    def test_region_writes_event_when_tracing(self, tracing):
+        child = obs_metrics.get_registry().histogram(
+            obs_trace.REGION_SECONDS, obs_metrics.REGION_EDGES) \
+            .labels(region="test.region_on")
+        n0 = child.count
+        with obs.region("test.region_on"):
+            pass
+        (ev,) = obs.trace_events()
+        assert ev["name"] == "test.region_on" and ev["ph"] == "X"
+        assert ev["pid"] == obs.PID_RUNTIME and ev["dur"] >= 0
+        assert child.count == n0 + 1
+
+    def test_region_survives_metrics_reset(self, no_tracing):
+        with obs.region("test.region_reset"):
+            pass
+        obs_metrics.reset_metrics()
+        with obs.region("test.region_reset"):
+            pass
+        values = obs_metrics.snapshot()[obs_trace.REGION_SECONDS]["values"]
+        assert values["region=test.region_reset"]["count"] == 1
+
     def test_enable_clears_on_request(self, tracing):
         with obs.span("a"):
             pass
@@ -242,6 +273,94 @@ def _tiny_engine():
                             quant=QuantSpec(planes=2, impl="pallas_fused"))
 
 
+def _profile_host_events(tmp_path, fn):
+    """Run ``fn`` under a JAX profiler session; returns the host plane's
+    events as {line id: [(name, start_ns, end_ns)]}."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                lines[(plane.name, i)] = [
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
+                    for e in line.events]
+    return lines
+
+
+class TestProfilerSink:
+    def test_span_and_region_land_in_profiler_trace(self, tmp_path):
+        from repro.serving import Request
+        from repro.serving.scheduler import Scheduler
+        cfg, eng = _tiny_engine()
+        sched = Scheduler("fcfs", max_len=12)
+        sched.submit(Request(0, [1, 2, 3], 4))
+        eng.admit_from(sched)
+        eng.step()                              # compile outside the trace
+
+        def traced():
+            obs.enable()
+            try:
+                with obs.span("test.enabled_span"):
+                    pass
+            finally:
+                obs.disable()
+                obs.clear_trace()
+            eng.step()
+
+        lines = _profile_host_events(tmp_path, traced)
+        names = {n for evs in lines.values() for n, _, _ in evs}
+        assert "test.enabled_span" in names
+        # serve.step holds its phases, on the thread that ran it
+        (evs,) = [evs for evs in lines.values()
+                  if any(n == "serve.step" for n, _, _ in evs)]
+        (_, s0, e0), = [e for e in evs if e[0] == "serve.step"]
+        for child in ("serve.upload", "serve.dispatch", "serve.readback",
+                      "serve.advance"):
+            (_, s, e), = [ev for ev in evs if ev[0] == child]
+            assert s0 <= s <= e <= e0, child
+        dispatch = next(ev for ev in evs if ev[0] == "serve.dispatch")
+        readback = next(ev for ev in evs if ev[0] == "serve.readback")
+        assert dispatch[2] <= readback[1]
+
+
+class TestEngineCounters:
+    def test_counters_tick_without_tracing(self, no_tracing):
+        from repro.serving import Request
+        from repro.serving.scheduler import Scheduler
+        reg = obs_metrics.get_registry()
+        names = ("repro_serve_slot_steps_bound_total",
+                 "repro_serve_kv_positions_live_total",
+                 "repro_serve_kv_positions_read_total")
+        cfg, eng = _tiny_engine()
+        sched = Scheduler("fcfs", max_len=12)
+        sched.submit(Request(0, [1, 2, 3], 3))
+        eng.admit_from(sched)
+        before = [reg.counter(n).value for n in names]
+        steps = 0
+        expect_live = 0
+        while eng.has_work(sched):
+            expect_live += sum(int(eng.slots.pos[i]) + 1
+                               for i, _ in eng.slots.bound())
+            eng.step()
+            steps += 1
+        bound, live, read = (reg.counter(n).value - b
+                             for n, b in zip(names, before))
+        assert steps > 0 and bound == steps     # one request, one slot
+        assert live == expect_live
+        assert read == steps * eng.batch * eng.max_len
+        assert 0 < live <= read
+        assert obs.trace_events() == []
+
+
 class TestDisabledMode:
     def test_serve_step_records_nothing_when_disabled(self, no_tracing):
         from repro.serving import Request
@@ -277,7 +396,7 @@ class TestDisabledMode:
         eng.step()
         assert steps.value == n0 + 1
         names = [e["name"] for e in obs.trace_events()]
-        assert "serve.decode_step" in names
+        assert "serve.step" in names
 
     def test_kernel_dispatch_span_gated(self, no_tracing):
         from repro.engine import QuantSpec
@@ -353,7 +472,7 @@ class TestServingIntegration:
             by_name.setdefault(ev["name"], []).append(ev)
         assert len(by_name.get("PREFILL", [])) == stats["completed"]
         assert len(by_name.get("DECODE", [])) == stats["completed"]
-        assert "serve.decode_step" in by_name
+        assert "serve.step" in by_name
         # lifecycle spans ride the virtual serving clock
         assert all(e["pid"] == obs.PID_SERVER
                    for e in by_name["PREFILL"])
