@@ -1,6 +1,7 @@
 """Grouped-query attention with RoPE, chunked (flash-style) causal
 computation for long sequences, and a single-token decode path over a
-preallocated KV cache.
+preallocated KV cache stored sequence-minor, [L, B, n_kv, D, S], and
+updated in place one column per token.
 """
 from __future__ import annotations
 
@@ -12,7 +13,8 @@ import numpy as np
 from repro.parallel.sharding import box, constrain
 from . import layers as L
 
-__all__ = ["attn_init", "attn_apply", "attn_decode", "init_kv_cache"]
+__all__ = ["attn_init", "attn_apply", "attn_decode", "attend_kv_cache",
+           "init_kv_cache", "write_kv_column"]
 
 NEG_INF = -1e30
 
@@ -139,46 +141,83 @@ def attn_apply(p, x, cfg, positions, dtype=jnp.bfloat16):
 
 
 def init_kv_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
-    """Per-layer KV cache [B, S, n_kv, D] (boxed logical axes for sharding)."""
+    """Per-layer KV cache [B, n_kv, D, S] (boxed logical axes for sharding).
+
+    The sequence axis is minor: the order decode attention reads the cache
+    in, so the step reads it in place and writes one column per token."""
     hd = cfg.head_dim
-    shape = (batch, max_len, cfg.n_kv_heads, hd)
-    ax = ("batch", "kv_seq", "kv_heads", "head_dim")
+    shape = (batch, cfg.n_kv_heads, hd, max_len)
+    ax = ("batch", "kv_heads", "head_dim", "kv_seq")
     return {
         "k": box(jnp.zeros(shape, dtype), ax),
         "v": box(jnp.zeros(shape, dtype), ax),
     }
 
 
-def attn_decode(p, x, cfg, cache_k, cache_v, pos, dtype=jnp.bfloat16):
-    """Single-token decode.  x: [B, 1, d]; pos: [B] current positions.
+def write_kv_column(cache, col, layer, pos):
+    """Write each slot's new K or V into the stacked cache.
 
-    GQA is computed with a grouped einsum instead of materializing
-    `repeat_kv` over the cache: repeating a (possibly seq-sharded) cache
+    cache: [L, B, n_kv, D, S]; col: [B, n_kv, D]; layer: scalar int32;
+    pos: [B].  Slot b's column lands at (layer, b, :, :, pos[b]), by one
+    ``dynamic_update_slice`` per slot, which XLA does in place on a
+    carried (donated) cache; a scatter would copy the stack.
+
+    The layout XLA gives the carried cache follows the updates, so each
+    update is shaped to leave it sequence-minor and unpadded: it goes
+    through the [L, B, n_kv * D, S] view (a bitcast), and is built from a
+    1-D row of the column, which has one layout.  Against a
+    [1, 1, n_kv, D, 1] update, or one sliced out of a [B, 1, n_kv * D, 1]
+    column that a kernel wrote batch-minor, the TPU compiler lays the
+    stack out otherwise and copies it in and out every step."""
+    n_layers, b, n_kv, hd, s = cache.shape
+    flat = cache.reshape(n_layers, b, n_kv * hd, s)
+    rows = col.reshape(b, n_kv * hd).astype(cache.dtype)
+    zero = jnp.zeros((), jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    for i in range(b):
+        flat = jax.lax.dynamic_update_slice(
+            flat, rows[i].reshape(1, 1, n_kv * hd, 1),
+            (layer, jnp.int32(i), zero, pos[i]))
+    return flat.reshape(cache.shape)
+
+
+def attend_kv_cache(q, cache_k, cache_v, pos, dtype=jnp.bfloat16):
+    """One query token per slot against a layer's cache.
+
+    q: [B, 1, H, D]; cache_k / cache_v: [B, n_kv, D, S]; pos: [B].
+    Positions past ``pos[b]`` are masked.  GQA is a grouped einsum, not a
+    ``repeat_kv`` of the cache: repeating a (possibly seq-sharded) cache
     n_heads/n_kv-fold forces an 8x resident blow-up and a reshard under
     GSPMD (observed: +200 GB collectives/step on qwen decode_32k).
+    Returns [B, 1, H * D]."""
+    b, _, h, hd = q.shape
+    n_kv, s = cache_k.shape[1], cache_k.shape[3]
+    qg = q.reshape(b, n_kv, h // n_kv, hd)
+    scores = jnp.einsum("bkgd,bkds->bkgs", qg, cache_k,
+                        preferred_element_type=jnp.float32) / np.sqrt(hd)
+    valid = jnp.arange(s)[None, None, None, :] <= pos[:, None, None, None]
+    scores = jnp.where(valid, scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    out = jnp.einsum("bkgs,bkds->bkgd", probs, cache_v)
+    return out.reshape(b, 1, h * hd)
 
-    Returns (out [B,1,d], new_k, new_v) -- caller scatters into the cache.
-    """
-    b = x.shape[0]
-    hd = cfg.head_dim
-    n_kv = cfg.n_kv_heads
-    g = cfg.n_heads // n_kv
-    positions = pos[:, None]
-    q, k_new, v_new = _project_qkv(p, x, cfg, positions, dtype)
-    # scatter the new token into the cache at `pos`
+
+def attn_decode(p, x, cfg, cache_k, cache_v, layer, pos,
+                dtype=jnp.bfloat16):
+    """Single-token decode of one layer against the stacked cache.
+
+    x: [B, 1, d]; cache_k / cache_v: [L, B, n_kv, D, S]; layer: this
+    layer's index; pos: [B] current positions.  Writes the token's K/V
+    column, then attends over the layer's slice of the updated stack.
+    Returns (out [B, 1, d], cache_k, cache_v)."""
+    q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None], dtype)
     with jax.named_scope("kv_write"):
-        upd_idx = (jnp.arange(b), pos)
-        cache_k = cache_k.at[upd_idx].set(k_new[:, 0])
-        cache_v = cache_v.at[upd_idx].set(v_new[:, 0])
+        cache_k = write_kv_column(cache_k, k_new[:, 0], layer, pos)
+        cache_v = write_kv_column(cache_v, v_new[:, 0], layer, pos)
     with jax.named_scope("attention"):
-        qg = q.reshape(b, 1, n_kv, g, hd)
-        scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, cache_k,
-                            preferred_element_type=jnp.float32) / np.sqrt(hd)
-        valid = jnp.arange(cache_k.shape[1])[None, None, None, None, :] <= \
-            pos[:, None, None, None, None]
-        scores = jnp.where(valid, scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-        out = jnp.einsum("bkgqs,bskd->bqkgd", probs, cache_v)
-        out = out.reshape(b, 1, cfg.n_heads * hd)
+        out = attend_kv_cache(
+            q, jax.lax.dynamic_index_in_dim(cache_k, layer, keepdims=False),
+            jax.lax.dynamic_index_in_dim(cache_v, layer, keepdims=False),
+            pos, dtype)
     return (L.dense_apply(p["wo"], out, dtype, cfg.quant_spec()),
             cache_k, cache_v)

@@ -85,6 +85,31 @@ def cross_apply(p, x, k, v, cfg, dtype):
     return L.dense_apply(p["wo"], out, dtype, cfg.quant_spec())
 
 
+def self_attn_decode(p, x, cfg, cache_k, cache_v, pos, dtype):
+    """Decoder self-attention for one token against this layer's cache
+    [B, S, n_kv, hd]: scatter the new K/V at ``pos``, then grouped-query
+    attention over the positions up to it.  x: [B, 1, d]; pos: [B].
+    Returns (out [B, 1, d], cache_k, cache_v)."""
+    b = x.shape[0]
+    hd = cfg.head_dim
+    n_kv = cfg.n_kv_heads
+    q, k_new, v_new = A._project_qkv(p, x, cfg, pos[:, None], dtype)
+    idx = (jnp.arange(b), pos)
+    cache_k = cache_k.at[idx].set(k_new[:, 0])
+    cache_v = cache_v.at[idx].set(v_new[:, 0])
+    qg = q.reshape(b, n_kv, cfg.n_heads // n_kv, hd)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qg, cache_k,
+                        preferred_element_type=jnp.float32) / np.sqrt(hd)
+    valid = jnp.arange(cache_k.shape[1])[None, None, None, :] <= \
+        pos[:, None, None, None]
+    scores = jnp.where(valid, scores, A.NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    out = jnp.einsum("bkgs,bskd->bkgd", probs, cache_v)
+    out = out.reshape(b, 1, cfg.n_heads * hd)
+    return (L.dense_apply(p["wo"], out, dtype, cfg.quant_spec()),
+            cache_k, cache_v)
+
+
 # ---------------------------------------------------------------------------
 # blocks
 # ---------------------------------------------------------------------------
@@ -231,8 +256,8 @@ def encdec_decode_step(params, tokens, pos, caches, cfg):
     def body(h, scanned):
         layer_params, cache = scanned
         hn = norm_apply(cfg, layer_params["ln1"], h)
-        a, ck, cv = A.attn_decode(layer_params["attn"], hn, cfg,
-                                  cache["k"], cache["v"], pos, dtype)
+        a, ck, cv = self_attn_decode(layer_params["attn"], hn, cfg,
+                                     cache["k"], cache["v"], pos, dtype)
         h = h + a
         h = h + cross_apply(layer_params["cross"],
                             norm_apply(cfg, layer_params["ln_x"], h),

@@ -83,9 +83,10 @@ def block_apply(p, x, cfg, positions, dtype=jnp.bfloat16):
     return x + h, aux
 
 
-def block_decode(p, x, cfg, ck, cv, pos, dtype=jnp.bfloat16):
+def block_decode(p, x, cfg, ck, cv, layer, pos, dtype=jnp.bfloat16):
+    """One layer of a decode step; ck / cv are the stacked caches."""
     h, ck, cv = A.attn_decode(p["attn"], norm_apply(cfg, p["ln1"], x), cfg,
-                              ck, cv, pos, dtype)
+                              ck, cv, layer, pos, dtype)
     x = x + h
     if cfg.n_experts:
         h, _ = M.moe_apply(p["moe"], norm_apply(cfg, p["ln2"], x), cfg, dtype)
@@ -174,7 +175,7 @@ def lm_apply(params, tokens, cfg, frontend_embeds=None):
 
 
 def init_caches(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
-    """Stacked per-layer KV caches [L, B, S, n_kv, hd] (boxed)."""
+    """Stacked per-layer KV caches [L, B, n_kv, hd, S] (boxed)."""
     one = A.init_kv_cache(cfg, batch, max_len, dtype)
     return jax.tree.map(
         lambda b: Boxed(jnp.broadcast_to(b.value[None], (cfg.n_layers,)
@@ -186,9 +187,9 @@ def init_caches(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
 def lm_prefill(params, tokens, cfg, max_len: int, frontend_embeds=None):
     """Run the full prompt, return (last-position logits, filled caches).
 
-    Prefill reuses the train-path attention and recomputes K/V into the
-    cache layout afterwards -- single extra pass, keeps one attention code
-    path.  tokens: [B, T]; caches sized for max_len >= T.
+    Prefill reuses the train-path attention and transposes its K/V into
+    the cache order, [L, B, n_kv, hd, max_len] -- keeps one attention
+    code path.  tokens: [B, T]; caches sized for max_len >= T.
     """
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed_apply(params["embed"], tokens, dtype)
@@ -219,7 +220,8 @@ def lm_prefill(params, tokens, cfg, max_len: int, frontend_embeds=None):
         pad = max_len - t
         kc = jnp.pad(kc, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vc = jnp.pad(vc, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return h + m, {"k": kc, "v": vc}
+        return h + m, {"k": jnp.moveaxis(kc, 1, 3),
+                       "v": jnp.moveaxis(vc, 1, 3)}
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
     x, caches = jax.lax.scan(body_fn, x, params["blocks"],
@@ -231,18 +233,22 @@ def lm_prefill(params, tokens, cfg, max_len: int, frontend_embeds=None):
 def lm_decode_step(params, tokens, pos, caches, cfg):
     """One decode step.  tokens [B, 1]; pos [B]; caches from init/prefill.
 
+    The stacked caches ride in the layer scan's carry with the layer
+    index, and each layer writes its K/V column into them, so a donated
+    cache is updated in place.
     Returns (logits [B, 1, V], updated caches).
     """
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed_apply(params["embed"], tokens, dtype)
     x = constrain(x, "batch", None, None)
 
-    def body(h, scanned):
-        layer_params, cache = scanned
-        h, ck, cv = block_decode(layer_params, h, cfg, cache["k"], cache["v"],
-                                 pos, dtype)
-        return h, {"k": ck, "v": cv}
+    def body(carry, layer_params):
+        h, layer, ck, cv = carry
+        h, ck, cv = block_decode(layer_params, h, cfg, ck, cv, layer, pos,
+                                 dtype)
+        return (h, layer + 1, ck, cv), None
 
-    x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches),
-                                 unroll=cfg.scan_unroll)
-    return _logits(params, x, cfg, dtype), new_caches
+    (x, _, ck, cv), _ = jax.lax.scan(
+        body, (x, jnp.int32(0), caches["k"], caches["v"]), params["blocks"],
+        unroll=cfg.scan_unroll)
+    return _logits(params, x, cfg, dtype), {"k": ck, "v": cv}

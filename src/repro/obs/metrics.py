@@ -356,6 +356,15 @@ GLOSSARY = {
         "help": "Cache positions the decode step reads (batch x "
                 "max_len), summed over engine steps; counts with obs "
                 "disabled."},
+    "repro_serve_step_alias_bytes": {
+        "type": "gauge",
+        "help": "Bytes of the compiled decode step's outputs that alias "
+                "its donated inputs (the state updated in place), from "
+                "memory_analysis() after the engine's first step."},
+    "repro_serve_step_temp_bytes": {
+        "type": "gauge",
+        "help": "Temporary bytes of the compiled decode step, from "
+                "memory_analysis() after the engine's first step."},
     "repro_serve_queue_depth": {
         "type": "histogram", "edges": _DEPTH_EDGES,
         "help": "Admission queue depth sampled per scheduling round."},

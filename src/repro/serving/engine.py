@@ -56,6 +56,8 @@ _M_TOK_RECOVERED = _REG.counter("repro_serve_tokens_recovered_total")
 _M_SLOTS_BOUND = _REG.counter("repro_serve_slot_steps_bound_total").labels()
 _M_KV_LIVE = _REG.counter("repro_serve_kv_positions_live_total").labels()
 _M_KV_READ = _REG.counter("repro_serve_kv_positions_read_total").labels()
+_M_ALIAS_BYTES = _REG.gauge("repro_serve_step_alias_bytes")
+_M_TEMP_BYTES = _REG.gauge("repro_serve_step_temp_bytes")
 
 # Families whose decode state is a recurrence (no position-masked cache):
 # their per-slot state row must be re-initialized when a slot is reused.
@@ -187,7 +189,15 @@ class ServeEngine:
                 "plane_block_density": self.plan_density,
                 "schedules_verified": ops.verification_enabled(),
                 **ops.plan_cache_stats()}
-        self.step_fn = jax.jit(make_serve_step(cfg))
+        # ``step_fn`` leaves the state it is given intact, for callers that
+        # call it apart from the engine or wrap it (chip_smoke.py, the
+        # chip benchmark's fault hooks).  The engine's own steps donate
+        # the state, so the KV cache is updated in place; a ``step_fn``
+        # set from outside may return or keep its input, so the engine
+        # does not donate to one.
+        serve_step = make_serve_step(cfg)
+        self.step_fn = self._built_step_fn = jax.jit(serve_step)
+        self._step_in_place = jax.jit(serve_step, donate_argnums=(3,))
 
     # -- stepping surface (driven by the async server) -----------------------
 
@@ -369,7 +379,9 @@ class ServeEngine:
         call, until it returns), ``serve.readback`` (waiting for the
         sampled tokens) and ``serve.advance`` (the slots' bookkeeping).
         The engine's first call is ``serve.compile`` in place of
-        ``serve.dispatch``: JAX traces and compiles the step there."""
+        ``serve.dispatch``: JAX traces and compiles the step there.  The
+        engine's own step takes the state by donation, so the previous
+        state's arrays are deleted once it is dispatched."""
         slots = self.slots
         with obs_trace.region("serve.step"):
             if obs_trace.enabled():
@@ -381,15 +393,29 @@ class ServeEngine:
             _M_KV_READ.inc(self.batch * self.max_len)
             with obs_trace.region("serve.upload"):
                 cur, pos = jnp.asarray(slots.cur), jnp.asarray(pos)
+            in_place = self.step_fn is self._built_step_fn
+            step_fn = self._step_in_place if in_place else self.step_fn
             with obs_trace.region("serve.dispatch" if self.steps
                                   else "serve.compile"):
-                nxt, self.state = self.step_fn(self.params, cur, pos,
-                                               self.state)
+                nxt, self.state = step_fn(self.params, cur, pos, self.state)
+                if in_place and not self.steps:
+                    self._record_step_memory(cur, pos)
             self.steps += 1
             with obs_trace.region("serve.readback"):
                 nxt = np.asarray(nxt)
             with obs_trace.region("serve.advance"):
                 return slots.advance(nxt, now)
+
+    def _record_step_memory(self, cur, pos) -> None:
+        """Put the compiled step's aliased (updated in place) and
+        temporary bytes into the metrics registry.  The lowering hits the
+        jit's cache of the step just compiled; the new state has the
+        donated one's shapes."""
+        mem = self._step_in_place.lower(
+            self.params, cur, pos, self.state).compile().memory_analysis()
+        if mem is not None:
+            _M_ALIAS_BYTES.set(mem.alias_size_in_bytes)
+            _M_TEMP_BYTES.set(mem.temp_size_in_bytes)
 
     # -- legacy blocking loop ------------------------------------------------
 

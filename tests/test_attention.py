@@ -71,16 +71,17 @@ def test_decode_attends_only_to_valid_positions(rng):
     cfg = get_config("nemotron-4-15b", smoke=True)
     p = unbox(A.attn_init(jax.random.PRNGKey(0), cfg))
     b, s = 1, 8
-    ck = jnp.zeros((b, s, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    # stacked cache [L, B, n_kv, D, S], one layer
+    ck = jnp.zeros((1, b, cfg.n_kv_heads, cfg.head_dim, s), jnp.bfloat16)
     cv = jnp.zeros_like(ck)
     x = jnp.asarray(rng.standard_normal((b, 1, cfg.d_model))
                     .astype(np.float32))
     pos = jnp.asarray([2], jnp.int32)
-    out1, _, _ = A.attn_decode(p, x, cfg, ck, cv, pos)
+    out1, _, _ = A.attn_decode(p, x, cfg, ck, cv, 0, pos)
     # poison future cache slots
-    ck2 = ck.at[:, 5:].set(99.0)
-    cv2 = cv.at[:, 5:].set(-99.0)
-    out2, _, _ = A.attn_decode(p, x, cfg, ck2, cv2, pos)
+    ck2 = ck.at[..., 5:].set(99.0)
+    cv2 = cv.at[..., 5:].set(-99.0)
+    out2, _, _ = A.attn_decode(p, x, cfg, ck2, cv2, 0, pos)
     np.testing.assert_allclose(np.asarray(out1, np.float32),
                                np.asarray(out2, np.float32), atol=1e-5)
 

@@ -95,3 +95,44 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape):
     # the digit planes are the dominant operand: 4 int8 planes per weight
     assert mem.argument_size_in_bytes >= \
         SPEC.num_digits * shape[0] * shape[1]
+
+
+@pytest.mark.parametrize("quant", [None, SPEC], ids=["bf16", "pallas_fused"])
+def test_decode_step_updates_kv_cache_in_place(one_chip, monkeypatch, quant):
+    """The decode step, compiled with its state donated, updates the KV
+    cache in place: its output aliases the whole state, and no copy or
+    temporary holds the stacked cache.  minicpm-2b's 64-wide heads and the
+    chip cell's 32 slots x 640 positions, at 2 layers of d_model 256 (the
+    layout the compiler picks for the cache follows the column updates,
+    and the kernel path writes them batch-minor)."""
+    import re
+    from repro.configs.registry import get_config
+    from repro.models.api import get_api
+    from repro.parallel.sharding import unbox
+    from repro.train.steps import make_serve_step
+    cfg = get_config("minicpm-2b").replace(
+        n_layers=2, d_model=256, n_heads=4, n_kv_heads=4, d_ff=512,
+        vocab_size=1024, quant=quant,
+        quant_planes=quant.planes if quant else 0)
+    api = get_api(cfg)
+    params = unbox(api.init(jax.random.PRNGKey(0), cfg))
+    if quant is not None:
+        params, _ = ops.plan_params(params, quant)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def s(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    b, max_len = 32, 640
+    state = jax.eval_shape(lambda: unbox(api.init_decode(cfg, b, max_len)))
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(3,)).lower(
+        s(params), s(jax.ShapeDtypeStruct((b, 1), jnp.int32)),
+        s(jax.ShapeDtypeStruct((b,), jnp.int32)), s(state)).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < state_bytes / 8
+    assert not re.search(r"= bf16\[2,32,[\d,]+,640\]\S* copy\(",
+                         compiled.as_text())
