@@ -19,6 +19,19 @@ Departures from the published MiniCPM and Granite descriptions are the
 model code's and are mirrored: no muP embedding/residual/logit scaling,
 RMSNorm epsilon 1e-6 with unit scales, a vocabulary padded to a multiple
 of 128 whose extra rows are initialized like the others.
+
+This is the dense decoder's reference, and the default of every
+configuration.  A configuration of another block names its own module of
+this directory under ``"reference"``; ``run.check`` calls that module's
+``plane_qmax(planes)`` and ``logit_gaps(seed, model, seqs, starts,
+length, qmax, control_qmax)``.  Such a module imports this one and writes
+only what differs: a per-layer weight drawer ``weights(seed, model,
+layer)`` and a jitted per-layer block ``block(w, x, model_items, qmax)``,
+handed to ``logit_gaps``.  The block gets the model's entries as the
+hashable ``model_items`` (``_items``); what differs from layer to layer,
+such as a window, rides in ``w`` as arrays.  The seeding (``_keys``,
+``_draw``), ``qdense``, ``rmsnorm``, ``rope``, ``attention``, ``mlp``,
+the embedding and head, and ``_gaps`` are its to import.
 """
 from __future__ import annotations
 
@@ -127,31 +140,41 @@ def act(name: str, x):
     return jax.nn.silu(x)
 
 
+def attention(w, y, model: dict, qmax: int):
+    """Causal softmax attention of the normalized ``y`` [S, T, d] with
+    RoPE on every position, through the output projection."""
+    s, t, _ = y.shape
+    h, kvh, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
+    q = qdense(y, w["wq"], qmax).reshape(s, t, h, hd)
+    k = qdense(y, w["wk"], qmax).reshape(s, t, kvh, hd)
+    v = qdense(y, w["wv"], qmax).reshape(s, t, kvh, hd)
+    q, k = rope(q, model["rope_theta"]), rope(k, model["rope_theta"])
+    k = jnp.repeat(k, h // kvh, axis=2)
+    v = jnp.repeat(v, h // kvh, axis=2)
+    scores = jnp.einsum("sqhd,skhd->shqk", q, k) / np.sqrt(hd)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    scores = jnp.where(causal, scores, -jnp.inf)
+    o = jnp.einsum("shqk,skhd->sqhd", jax.nn.softmax(scores, -1), v)
+    return qdense(o.reshape(s, t, h * hd), w["wo"], qmax)
+
+
+def mlp(w, y, model: dict, qmax: int):
+    """The (gated) MLP of the normalized ``y``."""
+    if "gate" in w:
+        m = act(model["act"], qdense(y, w["gate"], qmax)) * \
+            qdense(y, w["up"], qmax)
+    else:
+        m = act(model["act"], qdense(y, w["up"], qmax))
+    return qdense(m, w["down"], qmax)
+
+
 @functools.partial(jax.jit, static_argnames=("model_items", "qmax"))
 def _layer(w, x, model_items, qmax):
+    """The dense decoder layer: pre-norm attention, then pre-norm MLP."""
     model = dict(model_items)
-    s, t, _ = x.shape
-    h, kvh, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
     with jax.default_matmul_precision("highest"):
-        y = rmsnorm(x)
-        q = qdense(y, w["wq"], qmax).reshape(s, t, h, hd)
-        k = qdense(y, w["wk"], qmax).reshape(s, t, kvh, hd)
-        v = qdense(y, w["wv"], qmax).reshape(s, t, kvh, hd)
-        q, k = rope(q, model["rope_theta"]), rope(k, model["rope_theta"])
-        k = jnp.repeat(k, h // kvh, axis=2)
-        v = jnp.repeat(v, h // kvh, axis=2)
-        scores = jnp.einsum("sqhd,skhd->shqk", q, k) / np.sqrt(hd)
-        causal = jnp.tril(jnp.ones((t, t), bool))
-        scores = jnp.where(causal, scores, -jnp.inf)
-        o = jnp.einsum("shqk,skhd->sqhd", jax.nn.softmax(scores, -1), v)
-        x = x + qdense(o.reshape(s, t, h * hd), w["wo"], qmax)
-        y = rmsnorm(x)
-        if "gate" in w:
-            m = act(model["act"], qdense(y, w["gate"], qmax)) * \
-                qdense(y, w["up"], qmax)
-        else:
-            m = act(model["act"], qdense(y, w["up"], qmax))
-        return x + qdense(m, w["down"], qmax)
+        x = x + attention(w, rmsnorm(x), model, qmax)
+        return x + mlp(w, rmsnorm(x), model, qmax)
 
 
 def _logits(h, head, qmax: int, tied: bool):
@@ -178,42 +201,50 @@ def _gaps(hid, hid_control, head, targets, qmax, control_qmax, tied):
     return jax.lax.map(row, (hid, hid_control, targets))
 
 
-def _items(model: dict):
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-            "d_ff", "act", "rope_theta", "gated_mlp")
-    return tuple((k, model[k]) for k in keys)
+def _items(model):
+    """The model's entries, hashable, as a block's static argument:
+    a dict as its sorted pairs, a list as a tuple, at every depth."""
+    if isinstance(model, dict):
+        return tuple(sorted((k, _items(v)) for k, v in model.items()))
+    if isinstance(model, list):
+        return tuple(_items(v) for v in model)
+    return model
 
 
 def final_hidden(seed: int, model: dict, tokens: np.ndarray,
-                 qmaxes: List[int]) -> Dict[int, jax.Array]:
+                 qmaxes: List[int], weights=layer_weights,
+                 block=_layer) -> Dict[int, jax.Array]:
     """Normalized final hidden states [S, T, d] of ``tokens`` [S, T]
-    under each grid in ``qmaxes``; weights are drawn once per layer."""
+    under each grid in ``qmaxes``: ``block`` applied to each layer's
+    ``weights(seed, model, layer)``, drawn once per layer."""
     table = embedding(seed, model)
     x0 = jnp.take(table, jnp.asarray(tokens), axis=0).astype(jnp.float32)
     del table
     xs = {q: x0 for q in qmaxes}
     items = _items(model)
     for layer in range(model["n_layers"]):
-        w = layer_weights(seed, model, layer)
-        xs = {q: _layer(w, x, items, q) for q, x in xs.items()}
+        w = weights(seed, model, layer)
+        xs = {q: block(w, x, items, q) for q, x in xs.items()}
     return {q: rmsnorm(x) for q, x in xs.items()}
 
 
 def logit_gaps(seed: int, model: dict, seqs: List[List[int]],
                starts: List[int], length: int, qmax: int,
-               control_qmax=None) -> dict:
+               control_qmax=None, weights=layer_weights,
+               block=_layer) -> dict:
     """For each sequence (prompt + served tokens) and each served token
     (positions ``starts[i]`` onward), the gap by which the reference's
     logit of that token lies below the reference's best.  With
     ``control_qmax``, also the gap of the token that the reference at
     that coarser grid puts first.  Sequences are padded to ``length``
-    positions, so every run of a cell compiles the same shapes.  Returns
+    positions, so every run of a cell compiles the same shapes.  The
+    layers are ``weights`` and ``block`` (``final_hidden``).  Returns
     numpy arrays of the gaps of all served tokens."""
     tokens = np.zeros((len(seqs), length), np.int32)
     for i, s in enumerate(seqs):
         tokens[i, :len(s)] = s
     grids = [qmax] + ([control_qmax] if control_qmax else [])
-    hid = final_hidden(seed, model, tokens, grids)
+    hid = final_hidden(seed, model, tokens, grids, weights, block)
     tied = bool(model.get("tie_embeddings", False))
     head = embedding(seed, model) if tied else lm_head(seed, model)
     targets = np.zeros_like(tokens)

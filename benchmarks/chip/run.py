@@ -5,7 +5,11 @@
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>``:
 the model as served, its batch, cache length and QuantSpec) and a traffic
-mix (``traffic/<mix>.json``).  A run:
+mix (``traffic/<mix>.json``).  The configuration may name, as plain
+module names of this directory, its plain reference (``"reference"``,
+default ``reference.py``) and its work counts (``"work"``, default
+``work.py``), so that a block other than the dense decoder comes with
+files of its own.  A run:
 
 1. refuses any platform but a TPU, and fewer chips than the cell asks;
 2. keeps JAX's compilation cache at ``<checkout>/.jax_cache``;
@@ -17,8 +21,8 @@ mix (``traffic/<mix>.json``).  A run:
    ``Scheduler`` for ``--seconds``, counting compilations (there should
    be none) and stamping every generated token on the host clock;
 6. reads the device's peak memory, frees the engine, and compares a
-   sample of the finished requests with the plain float32 reference
-   (``reference.py``): the widest gap by which a served token's
+   sample of the finished requests with the configuration's plain
+   float32 reference: the widest gap by which a served token's
    reference logit lies below the reference's best;
 7. prints the end-to-end metrics (``--trace 0``) or the per-layer ones
    (``--trace 1``: the first ``TRACE_SECONDS`` of the window are traced
@@ -35,10 +39,12 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import types  # noqa: E402
@@ -57,13 +63,15 @@ TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration")
+MODULE_NAME = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 
 
 # -- the cell, from files -----------------------------------------------------
 
-def load_cell(name: str, root: str = ROOT) -> dict:
+def load_cell(name: str, root: str = ROOT, modules: str = HERE) -> dict:
     """The cell ``name`` of BENCHMARK.json with its configuration file,
-    traffic mix and the metrics it reports."""
+    traffic mix and the metrics it reports.  ``modules`` is the directory
+    of the modules the configuration names (``module_path``)."""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -81,8 +89,35 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name])
                  and m["moves"] in reported]
+    for key in ("reference", "work"):
+        module_path(config, key, modules)
     return {"name": name, "chips": cell["chips"], "config": config,
-            "mix": mix, "end_to_end": e2e, "per_layer": per_layer}
+            "mix": mix, "end_to_end": e2e, "per_layer": per_layer,
+            "modules": modules}
+
+
+def module_path(config: dict, key: str, directory: str) -> str:
+    """The file of the module that the configuration's ``key`` names
+    (``reference``, ``work``; where the key is absent, the module of
+    that name): a plain name, no path, and a file ``<name>.py`` of
+    ``directory``.  Anything else is refused."""
+    name = config.get(key, key)
+    path = os.path.join(directory, f"{name}.py")
+    if not (isinstance(name, str) and MODULE_NAME.fullmatch(name)
+            and os.path.isfile(path)):
+        raise SystemExit(f"{config['name']}: {key} {name!r} is not a "
+                         f"module of {directory}")
+    return path
+
+
+@functools.cache
+def load_module(path: str):
+    """The Python file ``path`` as a module, executed once a process."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + re.sub(r"\W", "_", os.path.relpath(path, ROOT)), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def model_config(config: dict):
@@ -232,9 +267,11 @@ def sample_requests(finished, seed: int):
     return [longest] + [rest[i] for i in order[:SAMPLE_REQUESTS - 1]]
 
 
-def check(config: dict, seed: int, picked, control: bool) -> dict:
-    """Compare the served tokens of ``picked`` with the reference."""
-    import reference
+def check(config: dict, seed: int, picked, control: bool,
+          modules: str = HERE) -> dict:
+    """Compare the served tokens of ``picked`` with the reference that
+    the configuration names, a module of ``modules``."""
+    reference = load_module(module_path(config, "reference", modules))
     spec = dict(kv.split("=") for kv in
                 config["serve"]["quant_spec"].split(","))
     planes = int(spec["planes"])
@@ -260,12 +297,7 @@ def check(config: dict, seed: int, picked, control: bool) -> dict:
 # -- one run ------------------------------------------------------------------
 
 def load_metric(name: str):
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_module(os.path.join(HERE, "metrics", name + ".py"))
 
 
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
@@ -283,6 +315,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
     clock = time.perf_counter
     config, serve = cell["config"], cell["config"]["serve"]
+    work = load_module(module_path(config, "work", cell["modules"]))
     cfg = model_config(config)
     spec = QuantSpec.parse(serve["quant_spec"])
     t_engine = clock()
@@ -362,7 +395,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         summary = profile_trace.summarize(profile_trace.load(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     t_check = clock()
-    result = check(config, seed, picked, control) if picked else \
+    result = check(config, seed, picked, control, cell["modules"]) \
+        if picked else \
         {"max_logit_gap": None, "tokens": 0, "mismatched": 0}
     check_s = clock() - t_check
     limit = float(config["check"]["max_logit_gap"])
@@ -391,7 +425,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     if trace:
         run = types.SimpleNamespace(
             model=config["model"], serve=serve, bits=spec.bits,
-            peaks=cell.get("peaks"), window_steps=window_steps,
+            work=work, peaks=cell.get("peaks"), window_steps=window_steps,
             traced_steps=loop.steps[warm:traced], trace=summary)
         record["per_layer"] = {m["name"]: load_metric(m["name"]).read(run)
                                for m in cell["per_layer"]}

@@ -1,9 +1,25 @@
-"""The work a decode step requires, counted from shapes.
+"""The work a decode step of the dense decoder requires, counted from
+shapes.
 
 These counts are the same whatever implements a GEMM: a digit-plane
 kernel, a plain int8 matmul or a bf16 one.  The planes a kernel streams
 (4 bytes per parameter today) are the implementation's overhead, not the
 work, so they are not counted.
+
+This is the default work module of a configuration.  One of another
+block names its own module of this directory under ``"work"``; the
+metrics call it as ``run.work``, and it provides, with these signatures:
+
+- ``step_gemm_least_time(model, tokens, peaks, bits=8)``: the least time
+  of one step's quantized GEMMs for ``tokens`` token rows
+  (``bw_gemm_roofline``);
+- ``useful_least_time(model, tokens, context_sum, peaks)``: the least
+  time of the useful work of ``tokens`` token positions whose attention
+  spans ``context_sum`` positions in all (``step_mfu``).
+
+The same rule holds there: work is counted from shapes, whatever
+implements a GEMM.  A routed layer counts the experts its tokens were
+routed to (and the router), never all the experts it holds.
 """
 from __future__ import annotations
 

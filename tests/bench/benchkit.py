@@ -21,17 +21,25 @@ V5E = "TPU v5 lite"
 SMOKE_LIMIT = 0.5
 
 
-def smoke_cell(name: str = "minicpm-2b.decode") -> dict:
-    """The cell ``name`` at smoke widths: two layers of d_model 128, an
-    8192-token vocabulary, four slots, a 48-position cache and a matching
-    short mix.  The quant spec, the traffic generator, the loop and the
-    check are the cell's own; the limit is ``SMOKE_LIMIT``."""
+# the dense decoder's smoke widths, for a configuration without "smoke"
+DENSE_SMOKE = dict(n_layers=2, d_model=128, n_heads=4, head_dim=32,
+                   d_ff=256, vocab_size=8192)
+
+
+def smoke_cell(name: str = "minicpm-2b.decode", root: str = ROOT,
+               modules: str = CHIP_DIR) -> dict:
+    """The cell ``name`` at smoke widths: the model overrides of the
+    configuration's ``"smoke"`` object, or without one two layers of
+    d_model 128, an 8192-token vocabulary and at most four KV heads; four
+    slots, a 48-position cache and a matching short mix.  The quant spec,
+    the traffic generator, the loop and the check are the cell's own; the
+    limit is ``SMOKE_LIMIT``.  ``root`` and ``modules`` are
+    ``run.load_cell``'s."""
     import run
-    cell = copy.deepcopy(run.load_cell(name))
+    cell = copy.deepcopy(run.load_cell(name, root, modules))
     cfg = cell["config"]
-    cfg["model"].update(n_layers=2, d_model=128, n_heads=4, head_dim=32,
-                        d_ff=256, vocab_size=8192,
-                        n_kv_heads=min(cfg["model"]["n_kv_heads"], 4))
+    cfg["model"].update(cfg.get("smoke") or dict(
+        DENSE_SMOKE, n_kv_heads=min(cfg["model"]["n_kv_heads"], 4)))
     cfg["check"]["max_logit_gap"] = SMOKE_LIMIT
     cfg["reduced"] = {k: "smoke size" for k in cfg["model"]}
     cfg["serve"].update(batch=4, max_len=48)
