@@ -37,6 +37,11 @@ class ModelConfig:
     moe_shard: str = "expert"      # 'expert' (EP) or 'mlp' (TP over d_ff)
     moe_dispatch_groups: int = 1   # >1: DP-shard-local dispatch (no gathers)
     router_aux_coef: float = 0.01
+    # the experts this chip holds, [experts_first, experts_first +
+    # experts_held) of each layer (0: all): the router still spans all
+    # n_experts, and the layer returns its held experts' part of the result
+    experts_held: int = 0
+    experts_first: int = 0
     # --- RWKV / SSM ---
     rwkv_head_size: int = 0
     ssm_state: int = 0
@@ -52,6 +57,17 @@ class ModelConfig:
     act: str = "silu"
     gated_mlp: bool = True
     rope_theta: float = 1e4
+    # layer kinds, as one period repeated over the layers: "S" a sliding-
+    # window layer (a query at p sees keys p - sliding_window < j <= p,
+    # default RoPE), "F" a full layer (YaRN RoPE when yarn_factor > 0:
+    # interpolation by 1 / yarn_factor below the original context's
+    # correction range, transformers' default betas and attention factor).
+    # "" keeps every layer full, with the default RoPE unless yarn_factor
+    # is set.  Scalars and strings only: a configuration file compares them.
+    layer_pattern: str = ""
+    sliding_window: int = 0
+    yarn_factor: float = 0.0       # 0: no YaRN
+    yarn_original_max_position: int = 0
     norm: str = "rms"              # rms | layer
     tie_embeddings: bool = False
     logit_softcap: float = 0.0     # grok-style tanh soft capping
@@ -98,6 +114,22 @@ class ModelConfig:
     def padded_vocab(self) -> int:
         return pad_vocab(self.vocab_size)
 
+    @property
+    def held_experts(self) -> int:
+        """Experts of each layer held here (all unless experts_held)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def has_layer_kinds(self) -> bool:
+        """Whether layers differ in window or RoPE (per-layer tables ride
+        in the layer scans); False keeps the uniform dense path."""
+        return bool(self.layer_pattern) or self.yarn_factor > 0
+
+    def layer_kind(self, layer: int) -> str:
+        """"S" (sliding window) or "F" (full) for ``layer``."""
+        pattern = self.layer_pattern or "F"
+        return pattern[layer % len(pattern)]
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -111,7 +143,7 @@ class ModelConfig:
         mlp_mats = 3 if self.gated_mlp else 2
         mlp = mlp_mats * d * self.d_ff
         if self.n_experts:
-            mlp = mlp * self.n_experts + d * self.n_experts
+            mlp = mlp * self.held_experts + d * self.n_experts
         block = attn + mlp
         n_blocks = self.n_layers + self.n_encoder_layers
         emb = self.padded_vocab * d * (1 if self.tie_embeddings else 2)

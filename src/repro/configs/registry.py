@@ -1,8 +1,8 @@
 """Architecture registry: ``--arch <id>`` -> ModelConfig (full or smoke).
 
-The ten assigned architectures plus the paper's own evaluation backbones
-are addressable by name; each <arch>.py module also exposes ``smoke()``
-with a reduced same-family config for CPU tests.
+The assigned architectures, and Mellum2-12B-A2.5B, are addressable by
+name; each <arch>.py module also exposes ``smoke()`` with a reduced
+same-family config for CPU tests.
 """
 from __future__ import annotations
 
@@ -12,7 +12,7 @@ from .base import ModelConfig, ShapeConfig, SHAPES
 
 from . import (rwkv6_3b, olmoe_1b_7b, grok_1_314b, phi_3_vision_4_2b,
                seamless_m4t_medium, minicpm_2b, nemotron_4_15b,
-               qwen1_5_110b, granite_34b, hymba_1_5b)
+               qwen1_5_110b, granite_34b, hymba_1_5b, mellum2_12b_a2_5b)
 
 _MODULES = {
     "rwkv6-3b": rwkv6_3b,
@@ -25,6 +25,7 @@ _MODULES = {
     "qwen1.5-110b": qwen1_5_110b,
     "granite-34b": granite_34b,
     "hymba-1.5b": hymba_1_5b,
+    "mellum2-12b-a2.5b": mellum2_12b_a2_5b,
 }
 
 ARCHS: List[str] = list(_MODULES)

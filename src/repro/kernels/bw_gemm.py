@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["bw_gemm", "bw_gemm_fused", "bw_gemm_sparse",
+__all__ = ["bw_gemm", "bw_gemm_fused", "bw_gemm_experts", "bw_gemm_sparse",
            "bw_gemm_sparse_fused", "bw_gemm_sparse_pipelined",
            "bw_gemm_sparse_fused_pipelined", "EPILOGUE_ACTIVATIONS",
            "SCHED_COLS"]
@@ -314,6 +314,124 @@ def bw_gemm_fused(digits, b, mask, scale, bias=None, scale_n=None, *,
         interpret=interpret,
     )(_flat_mask(mask), digits, b, scale.astype(jnp.float32),
       scale_n.astype(jnp.float32), bias.astype(jnp.float32))
+
+
+def _experts_kernel(mask_ref, count_ref, d_ref, b_ref, scale_ref,
+                    scale_n_ref, o_ref, acc_ref, *, n_planes: int, radix: int,
+                    mb: int, kb: int, block_n: int, activation,
+                    has_scale_n: bool):
+    """_fused_kernel with the expert as the leading grid axis.  Token
+    blocks at or past the expert's routed count skip their MXU passes
+    (their accumulator stays zero, and the caller never reads them)."""
+    h, i, j, kk = (pl.program_id(a) for a in range(4))
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+    b = b_ref[...]
+    live = j * block_n < count_ref[h]
+    for bw in range(n_planes):          # unrolled: BW is small and static
+        weight = radix ** bw
+
+        @pl.when(jnp.logical_and(
+            live, mask_ref[((h * n_planes + bw) * mb + i) * kb + kk] != 0))
+        def _plane(bw=bw, weight=weight):
+            acc_ref[...] += _int_dot(d_ref[bw], b) * weight
+
+    @pl.when(kk == kb - 1)
+    def _epilogue():
+        s = scale_ref[...]
+        if has_scale_n:
+            s = s * scale_n_ref[...]
+        y = EPILOGUE_ACTIVATIONS[activation](acc_ref[...].astype(jnp.float32)
+                                             * s)
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_m", "block_n", "block_k", "radix", "interpret", "activation",
+    "out_dtype"))
+def bw_gemm_experts(digits, b, mask, scale, counts, scale_n=None, *,
+                    block_m: int = 128, block_n: int = 128,
+                    block_k: int = 256, radix: int = 4,
+                    interpret: bool = False, activation=None,
+                    out_dtype=jnp.float32):
+    """C[h] = act((sum_bw (digits[h, bw] @ B[h]) * radix**bw) * scales),
+    one grouped call over a layer's held experts: the expert is the
+    leading grid axis, and each grid step's ``index_map`` picks that
+    expert's planes and token columns.
+
+    digits:  int8 [H, BW, M, K] planes of each expert's weight (kernel
+             rows = output channels).
+    b:       int8 [H, K, N]: each expert's gathered tokens on N, its
+             routed ones first.
+    mask:    bool [H, BW, M//block_m, K//block_k] plane-block occupancy.
+    scale:   f32 [H, M, 1] per-channel scales (act scale folded in when
+             it is per tensor).
+    counts:  int32 [H]: routed tokens of each expert.  Token blocks past
+             the count skip their MXU passes, and their B block is not
+             fetched again; their output is zero.
+    scale_n: optional f32 [H, 1, N] per-token act scales.
+    """
+    n_exp, bw_n, m, k = digits.shape
+    n_exp2, k2, n = b.shape
+    _check_dims("bw_gemm_experts", m, k, k2, n, block_m, block_n, block_k)
+    if n_exp2 != n_exp or mask.shape != (n_exp, bw_n, m // block_m,
+                                         k // block_k):
+        raise ValueError(
+            f"bw_gemm_experts: {n_exp} experts of digits, {n_exp2} of b, "
+            f"mask {tuple(mask.shape)}; expected "
+            f"({n_exp}, {bw_n}, {m // block_m}, {k // block_k})")
+    for name, arr, want in (("scale", scale, (n_exp, m, 1)),
+                            ("counts", counts, (n_exp,))):
+        if arr.shape != want:
+            raise ValueError(f"bw_gemm_experts: {name} shape "
+                             f"{tuple(arr.shape)} != {want}")
+    if activation not in EPILOGUE_ACTIVATIONS:
+        raise ValueError(f"bw_gemm_experts: unknown activation "
+                         f"{activation!r}")
+    has_scale_n = scale_n is not None
+    if not has_scale_n:                 # placeholder so arity is static
+        scale_n = jnp.ones((n_exp, 1, n), jnp.float32)
+    elif scale_n.shape != (n_exp, 1, n):
+        raise ValueError(f"bw_gemm_experts: scale_n shape "
+                         f"{tuple(scale_n.shape)} != {(n_exp, 1, n)}")
+    grid = (n_exp, m // block_m, n // block_n, k // block_k)
+    kernel = functools.partial(
+        _experts_kernel, n_planes=bw_n, radix=radix, mb=grid[1], kb=grid[3],
+        block_n=block_n, activation=activation, has_scale_n=has_scale_n)
+
+    def token_block(h, j, cnt):
+        # past the routed tokens, stay on the last block that holds one
+        return jnp.minimum(j, jnp.maximum(cnt[h] - 1, 0) // block_n)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # plane-block mask and routed counts: scalar-prefetched into SMEM
+        num_scalar_prefetch=2,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((None, bw_n, block_m, block_k),
+                         lambda h, i, j, kk, msk, cnt: (h, 0, i, kk)),
+            pl.BlockSpec((None, block_k, block_n),
+                         lambda h, i, j, kk, msk, cnt:
+                         (h, kk, token_block(h, j, cnt))),
+            pl.BlockSpec((None, block_m, 1),
+                         lambda h, i, j, kk, msk, cnt: (h, i, 0)),
+            pl.BlockSpec((None, 1, block_n),
+                         lambda h, i, j, kk, msk, cnt: (h, 0, j)),
+        ],
+        out_specs=pl.BlockSpec((None, block_m, block_n),
+                               lambda h, i, j, kk, msk, cnt: (h, i, j)),
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_exp, m, n), jnp.dtype(out_dtype)),
+        interpret=interpret,
+        name="bw_gemm_experts",
+    )(_flat_mask(mask), counts.astype(jnp.int32), digits, b,
+      scale.astype(jnp.float32), scale_n.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------

@@ -139,6 +139,13 @@ def encode_planes(a, encoding: str = "ent", bits: int = 8):
     return kref.encode_planes_ref(a, encoding, bits)
 
 
+# planning encodes through one compiled computation: run op by op, the
+# encoder's int32 temporaries take several times the planes' bytes (a
+# 98304 x 2304 LM head ran out of a v5e's memory that way)
+_encode_planes_jit = jax.jit(kref.encode_planes_ref,
+                             static_argnames=("encoding", "bits"))
+
+
 def _check_operand_k(k: int, planned_k: int) -> None:
     """Real validation (asserts vanish under ``python -O``)."""
     if k != planned_k:
@@ -419,10 +426,11 @@ def plan_operand(a_int8, encoding: str = "ent", block_m: int = 128,
         # high-plane digit count so those rows pack into few blocks.  Score
         # over the top min(2, BW) planes: narrow encodings (e.g. 2-bit
         # operands have a single radix-4 plane) must not index past plane 0.
-        d0 = kref.encode_planes_ref(a, encoding, bits)
+        d0 = _encode_planes_jit(a, encoding=encoding, bits=bits)
         hi = np.zeros(a.shape[0], dtype=np.int64)
         for p in range(min(2, d0.shape[0])):
             hi = hi * 1000 + np.asarray((d0[-(p + 1)] != 0).sum(axis=1))
+        del d0
         row_perm = np.argsort(-hi, kind="stable").astype(np.int32)
     else:
         row_perm = np.arange(a.shape[0], dtype=np.int32)
@@ -434,7 +442,7 @@ def plan_operand(a_int8, encoding: str = "ent", block_m: int = 128,
             a_sorted, block_m=block_m, block_k=block_k,
             interpret=_interpret())
     else:
-        digits = kref.encode_planes_ref(a_sorted, encoding, bits)
+        digits = _encode_planes_jit(a_sorted, encoding=encoding, bits=bits)
         mask = plane_block_mask(digits, block_m, block_k)
     schedule = build_schedule(np.asarray(mask), enc.radix(encoding), order)
     return PlannedOperand(digits, mask, row_perm, inv_perm, m, k,
@@ -1052,6 +1060,52 @@ def planned_dense_apply(plan: dict, x, spec, n_out: int, *, bias=None,
     return y.reshape(*lead, n_out).astype(out_dtype)
 
 
+def planned_experts_apply(plan: dict, x, spec, n_out: int, counts=None, *,
+                          activation=None, out_dtype=jnp.float32,
+                          block_n: Optional[int] = None,
+                          interpret: Optional[bool] = None):
+    """y[h] = act((x[h] @ w[h])_int * s_x * s_w) for each held expert h,
+    in one grouped ``bw_gemm_experts`` call.
+
+    plan: a layer's stacked expert plan, leaves [H, ...] (plan_params on
+    a [.., H, K, N] expert weight).  x: [H, T, K] each expert's gathered
+    tokens, its ``counts[h]`` routed ones first (None: all T).  Activations
+    are quantized as in planned_dense_apply.  Returns [H, T, n_out]."""
+    spec = QuantSpec.coerce(spec)
+    if interpret is None:
+        interpret = _interpret()
+    digits, mask = plan["digits"], plan["mask"]
+    n_exp, bw_n, m_pad, k_pad = digits.shape
+    if bw_n != spec.num_digits:
+        raise ValueError(
+            f"expert plan has {bw_n} digit planes but spec "
+            f"{spec.encoding!r}/{spec.bits}b implies {spec.num_digits}")
+    block_m, block_k = m_pad // mask.shape[2], k_pad // mask.shape[3]
+    _, t, k = x.shape
+    per_token = spec.act_quant == "per_token"
+    qx, sx = quantlib.quantize_for_spec(
+        jnp.asarray(x).astype(jnp.float32), spec,
+        axis=-1 if per_token else None)
+    if block_n is None:
+        block_n = select_block_sizes(n_out, k, t, spec)[2]
+    bt = _pad_to(_pad_to(jnp.swapaxes(qx, 1, 2), block_k, 1), block_n, 2)
+    sx_cols = None
+    scale_rows = plan["sw_rows"]
+    if per_token:                        # one scale per token: kernel N
+        sx_cols = _pad_to(jnp.swapaxes(sx, 1, 2), block_n, 2)
+    else:
+        scale_rows = scale_rows * sx
+    if counts is None:
+        counts = jnp.full((n_exp,), t, jnp.int32)
+    out = _bw.bw_gemm_experts(
+        digits, bt, mask, scale_rows, counts, sx_cols, block_m=block_m,
+        block_n=block_n, block_k=block_k, radix=spec.radix,
+        interpret=bool(interpret), activation=activation,
+        out_dtype=jnp.float32)
+    y = jnp.take_along_axis(out, plan["inv_perm"][..., None], axis=1)
+    return jnp.swapaxes(y[:, :n_out, :t], 1, 2).astype(out_dtype)
+
+
 def quantized_dense(x, w, spec, *, bias=None, activation=None,
                     out_dtype=jnp.float32,
                     block_n: Optional[int] = None,
@@ -1084,12 +1138,36 @@ _NO_PLAN_KEYS = frozenset({
 })
 
 
+def _plan_stack(w, spec, order: str) -> dict:
+    """Plans of every [K, N] matrix of a stacked weight ``w`` [..., K, N],
+    stacked with the same leading axes (so jax.lax.scan slices them
+    alongside the weights).  Each plan moves to host memory as it is
+    built and the stack is assembled there: stacking on the device would
+    hold every plan's digit planes twice at the peak."""
+    lead = w.shape[:-2]
+    plans = [jax.tree.map(np.asarray, plan_dense_weight(
+        w[idx], spec, use_cache=False, order=order))
+        for idx in np.ndindex(*lead)]
+    # schedules have data-dependent lengths: pad to the longest with exact
+    # no-op entries so the stack scans cleanly
+    max_steps = max(p["schedule"].shape[0] for p in plans)
+    for p in plans:
+        p["schedule"] = pad_schedule(p["schedule"], max_steps)
+    return jax.tree.map(
+        lambda *xs: jnp.asarray(np.stack(xs).reshape(lead + xs[0].shape)),
+        *plans)
+
+
 def plan_params(params, spec, should_plan=None, order: Optional[str] = None):
     """Attach a 'w_plan' record next to every dense weight in a param tree.
 
     2-D weights get a single plan; 3-D weights (layer-stacked for scan) get
     per-layer plans stacked on axis 0 so jax.lax.scan slices them alongside
-    the weights.  spec: QuantSpec (or legacy int plane budget).  Returns
+    the weights.  The expert weights of an MoE layer (``w_up``,
+    ``w_gate``, ``w_down`` beside its ``router``: [held, K, N], or
+    [layers, held, K, N] stacked) get a plan per (layer, expert) as
+    ``<name>_plan``, stacked the same way, for ``planned_experts_apply``.
+    spec: QuantSpec (or legacy int plane budget).  Returns
     (new_params, planned_count).  The original tree is not mutated;
     non-dict leaves and non-dense weights pass through.
 
@@ -1116,6 +1194,13 @@ def plan_params(params, spec, should_plan=None, order: Optional[str] = None):
         if not isinstance(node, dict):
             return node
         out = {k: walk(v, path + (k,)) for k, v in node.items()}
+        if "router" in node:             # an MoE layer: plan its experts
+            for name in _EXPERT_KEYS:
+                w = node.get(name)
+                if getattr(w, "ndim", 0) >= 3 and \
+                        should_plan(path + (name,), w):
+                    out[name + "_plan"] = _plan_stack(w, spec, order)
+                    count += int(np.prod(w.shape[:-2]))
         w = node.get("w")
         ndim = getattr(w, "ndim", 0)
         if ndim not in (2, 3) or not should_plan(path, w):
@@ -1124,43 +1209,35 @@ def plan_params(params, spec, should_plan=None, order: Optional[str] = None):
             out["w_plan"] = plan_dense_weight(w, spec, order=order)
             count += 1
         else:                  # [L, K, N] stacked for the layer scan
-            # each layer's plan moves to host memory as it is built and the
-            # stack is assembled there: stacking on the device would hold
-            # every layer's digit planes twice at the peak
-            plans = [jax.tree.map(np.asarray, plan_dense_weight(
-                w[i], spec, use_cache=False, order=order))
-                for i in range(w.shape[0])]
-            # per-layer schedules have data-dependent lengths: pad to the
-            # longest with exact no-op entries so the stack scans cleanly
-            max_steps = max(p["schedule"].shape[0] for p in plans)
-            for p in plans:
-                p["schedule"] = pad_schedule(p["schedule"], max_steps)
-            out["w_plan"] = jax.tree.map(
-                lambda *xs: jnp.asarray(np.stack(xs)), *plans)
+            out["w_plan"] = _plan_stack(w, spec, order)
             count += w.shape[0]
         return out
 
     return walk(params, ()), count
 
 
+# the expert weights of an MoE layer (models.moe.EXPERT_WEIGHTS)
+_EXPERT_KEYS = ("w_up", "w_gate", "w_down")
+
+
 def plan_tree_density(params) -> Optional[float]:
-    """Aggregate plane-block density over every 'w_plan' record in a
-    planned param tree (plane-block-count weighted); None when the tree
-    holds no plans.  This is the measured-density input to the
-    schedule-aware GemmEngine.cost / serving tier estimates."""
+    """Aggregate plane-block density over every plan record ('w_plan',
+    and the experts' '<name>_plan') in a planned param tree
+    (plane-block-count weighted); None when the tree holds no plans.
+    This is the measured-density input to the schedule-aware
+    GemmEngine.cost / serving tier estimates."""
     nnz = total = 0
 
     def walk(node):
         nonlocal nnz, total
         if not isinstance(node, dict):
             return
-        plan = node.get("w_plan")
-        if isinstance(plan, dict) and "mask" in plan:
-            mask = np.asarray(plan["mask"])
-            nnz += int(mask.sum())
-            total += int(mask.size)
         for key, v in node.items():
-            if key != "w_plan":
+            if key.endswith("_plan") and isinstance(v, dict) and "mask" in v:
+                mask = np.asarray(v["mask"])
+                nnz += int(mask.sum())
+                total += int(mask.size)
+            else:
                 walk(v)
 
     walk(params)

@@ -2,6 +2,13 @@
 computation for long sequences, and a single-token decode path over a
 preallocated KV cache stored sequence-minor, [L, B, n_kv, D, S], and
 updated in place one column per token.
+
+Layers may differ in kind (``ModelConfig.layer_pattern``): a sliding-
+window layer masks keys at or before ``p - window`` for a query at ``p``,
+and each layer has its own RoPE frequencies and cos/sin scale (YaRN on
+full layers).  ``layer_kinds`` gives those as per-layer arrays, which
+the layer scans carry as inputs; a config whose layers are alike has
+none, and its path is the uniform one.
 """
 from __future__ import annotations
 
@@ -14,9 +21,36 @@ from repro.parallel.sharding import box, constrain
 from . import layers as L
 
 __all__ = ["attn_init", "attn_apply", "attn_decode", "attend_kv_cache",
-           "init_kv_cache", "write_kv_column"]
+           "init_kv_cache", "write_kv_column", "layer_kinds", "NO_WINDOW"]
 
 NEG_INF = -1e30
+NO_WINDOW = 1 << 30     # the window of a full layer: every key it may see
+
+
+def layer_kinds(cfg):
+    """Per-layer attention tables, computed once from the config, or None
+    when every layer is alike: ``inv_freq`` float32 [L, head_dim // 2]
+    and ``rope_scale`` float32 [L] (the default RoPE of ``rope_theta`` on
+    sliding layers, YaRN on full ones when ``yarn_factor`` is set), and
+    ``window`` int32 [L] (``sliding_window`` on sliding layers,
+    ``NO_WINDOW`` on full ones)."""
+    if not cfg.has_layer_kinds:
+        return None
+    hd = cfg.head_dim
+    default = (L.rope_inv_freq(hd, cfg.rope_theta), 1.0)
+    full = default if cfg.yarn_factor <= 0 else L.yarn_inv_freq(
+        hd, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_max_position)
+    kinds = [cfg.layer_kind(i) for i in range(cfg.n_layers)]
+    if set(kinds) - {"S", "F"}:
+        raise ValueError(f"layer_pattern {cfg.layer_pattern!r}: layers are "
+                         f"'S' (sliding) or 'F' (full)")
+    if "S" in kinds and cfg.sliding_window <= 0:
+        raise ValueError("sliding layers need sliding_window > 0")
+    rope = [default if k == "S" else full for k in kinds]
+    return {"inv_freq": np.stack([f for f, _ in rope]).astype(np.float32),
+            "rope_scale": np.asarray([s for _, s in rope], np.float32),
+            "window": np.asarray([cfg.sliding_window if k == "S"
+                                  else NO_WINDOW for k in kinds], np.int32)}
 
 
 def attn_init(key, cfg, param_dtype=jnp.float32):
@@ -38,7 +72,7 @@ def attn_init(key, cfg, param_dtype=jnp.float32):
     return p
 
 
-def _project_qkv(p, x, cfg, positions, dtype):
+def _project_qkv(p, x, cfg, positions, dtype, kind=None):
     b, t, _ = x.shape
     hd = cfg.head_dim
     q = L.dense_apply(p["wq"], x, dtype, cfg.quant_spec())
@@ -47,7 +81,11 @@ def _project_qkv(p, x, cfg, positions, dtype):
     q = q.reshape(b, t, cfg.n_heads, hd)
     k = k.reshape(b, t, cfg.n_kv_heads, hd)
     v = v.reshape(b, t, cfg.n_kv_heads, hd)
-    q, k = L.rope(q, k, positions, hd, cfg.rope_theta)
+    if kind is None:
+        q, k = L.rope(q, k, positions, hd, cfg.rope_theta)
+    else:
+        q, k = L.rope(q, k, positions, hd, inv_freq=kind["inv_freq"],
+                      scale=kind["rope_scale"])
     q = constrain(q, "batch", "seq_inner", "heads", "head_dim")
     k = constrain(k, "batch", "seq_inner", "kv_heads", "head_dim")
     v = constrain(v, "batch", "seq_inner", "kv_heads", "head_dim")
@@ -62,8 +100,9 @@ def _repeat_kv(k, n_heads):
     return jnp.repeat(k, n_heads // n_kv, axis=2)
 
 
-def _dense_causal(q, k, v, q_offset: int = 0):
-    """Plain causal attention; q: [B,T,H,D], k/v already head-repeated."""
+def _dense_causal(q, k, v, q_offset: int = 0, window=None):
+    """Plain causal attention; q: [B,T,H,D], k/v already head-repeated.
+    With ``window``, a query at p sees keys p - window < j <= p."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -71,13 +110,17 @@ def _dense_causal(q, k, v, q_offset: int = 0):
     scores = scores / np.sqrt(d)
     qi = jnp.arange(tq)[:, None] + q_offset
     ki = jnp.arange(tk)[None, :]
-    scores = jnp.where(ki <= qi, scores, NEG_INF)
+    seen = ki <= qi
+    if window is not None:
+        seen = seen & (ki > qi - window)
+    scores = jnp.where(seen, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _chunked_causal(q, k, v, chunk_q: int, chunk_kv: int):
-    """Flash-style blockwise causal attention with online softmax.
+def _chunked_causal(q, k, v, chunk_q: int, chunk_kv: int, window=None):
+    """Flash-style blockwise causal attention with online softmax (and,
+    with ``window``, the sliding-window mask of ``_dense_causal``).
 
     Memory is O(chunk_q * chunk_kv) per (batch, head) instead of O(T^2).
     Fully-masked kv blocks (kv_start > q_end) still occupy the scan but
@@ -100,7 +143,10 @@ def _chunked_causal(q, k, v, chunk_q: int, chunk_kv: int):
                            preferred_element_type=jnp.float32) * scale
             qpos = qi * chunk_q + jnp.arange(chunk_q)[:, None]
             kpos = ki_idx * chunk_kv + jnp.arange(chunk_kv)[None, :]
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
+            seen = kpos <= qpos
+            if window is not None:
+                seen = seen & (kpos > qpos - window)
+            s = jnp.where(seen, s, NEG_INF)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             alpha = jnp.exp(m - m_new)
@@ -125,16 +171,19 @@ def _chunked_causal(q, k, v, chunk_q: int, chunk_kv: int):
     return out.astype(q.dtype)
 
 
-def attn_apply(p, x, cfg, positions, dtype=jnp.bfloat16):
-    """Full-sequence causal attention (train / prefill)."""
+def attn_apply(p, x, cfg, positions, dtype=jnp.bfloat16, kind=None):
+    """Full-sequence causal attention (train / prefill); ``kind`` is this
+    layer's slice of ``layer_kinds``."""
     b, t, _ = x.shape
-    q, k, v = _project_qkv(p, x, cfg, positions, dtype)
+    q, k, v = _project_qkv(p, x, cfg, positions, dtype, kind)
     k = _repeat_kv(k, cfg.n_heads)
     v = _repeat_kv(v, cfg.n_heads)
+    window = None if kind is None else kind["window"]
     if t > cfg.attn_chunk and t % cfg.attn_chunk == 0:
-        out = _chunked_causal(q, k, v, min(cfg.attn_chunk, t), cfg.attn_chunk)
+        out = _chunked_causal(q, k, v, min(cfg.attn_chunk, t), cfg.attn_chunk,
+                              window)
     else:
-        out = _dense_causal(q, k, v)
+        out = _dense_causal(q, k, v, window=window)
     out = constrain(out, "batch", "seq_inner", "heads", "head_dim")
     out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
     return L.dense_apply(p["wo"], out, dtype, cfg.quant_spec()), (k, v)
@@ -181,11 +230,13 @@ def write_kv_column(cache, col, layer, pos):
     return flat.reshape(cache.shape)
 
 
-def attend_kv_cache(q, cache_k, cache_v, pos, dtype=jnp.bfloat16):
+def attend_kv_cache(q, cache_k, cache_v, pos, dtype=jnp.bfloat16,
+                    window=None):
     """One query token per slot against a layer's cache.
 
     q: [B, 1, H, D]; cache_k / cache_v: [B, n_kv, D, S]; pos: [B].
-    Positions past ``pos[b]`` are masked.  GQA is a grouped einsum, not a
+    Positions past ``pos[b]`` are masked, and with ``window`` those at or
+    before ``pos[b] - window`` too.  GQA is a grouped einsum, not a
     ``repeat_kv`` of the cache: repeating a (possibly seq-sharded) cache
     n_heads/n_kv-fold forces an 8x resident blow-up and a reshard under
     GSPMD (observed: +200 GB collectives/step on qwen decode_32k).
@@ -195,7 +246,10 @@ def attend_kv_cache(q, cache_k, cache_v, pos, dtype=jnp.bfloat16):
     qg = q.reshape(b, n_kv, h // n_kv, hd)
     scores = jnp.einsum("bkgd,bkds->bkgs", qg, cache_k,
                         preferred_element_type=jnp.float32) / np.sqrt(hd)
-    valid = jnp.arange(s)[None, None, None, :] <= pos[:, None, None, None]
+    j = jnp.arange(s)[None, None, None, :]
+    valid = j <= pos[:, None, None, None]
+    if window is not None:
+        valid = valid & (j > (pos - window)[:, None, None, None])
     scores = jnp.where(valid, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
     out = jnp.einsum("bkgs,bkds->bkgd", probs, cache_v)
@@ -203,14 +257,15 @@ def attend_kv_cache(q, cache_k, cache_v, pos, dtype=jnp.bfloat16):
 
 
 def attn_decode(p, x, cfg, cache_k, cache_v, layer, pos,
-                dtype=jnp.bfloat16):
+                dtype=jnp.bfloat16, kind=None):
     """Single-token decode of one layer against the stacked cache.
 
     x: [B, 1, d]; cache_k / cache_v: [L, B, n_kv, D, S]; layer: this
-    layer's index; pos: [B] current positions.  Writes the token's K/V
+    layer's index; pos: [B] current positions; kind: this layer's slice
+    of ``layer_kinds`` (None for uniform layers).  Writes the token's K/V
     column, then attends over the layer's slice of the updated stack.
     Returns (out [B, 1, d], cache_k, cache_v)."""
-    q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None], dtype)
+    q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None], dtype, kind)
     with jax.named_scope("kv_write"):
         cache_k = write_kv_column(cache_k, k_new[:, 0], layer, pos)
         cache_v = write_kv_column(cache_v, v_new[:, 0], layer, pos)
@@ -218,6 +273,6 @@ def attn_decode(p, x, cfg, cache_k, cache_v, layer, pos,
         out = attend_kv_cache(
             q, jax.lax.dynamic_index_in_dim(cache_k, layer, keepdims=False),
             jax.lax.dynamic_index_in_dim(cache_v, layer, keepdims=False),
-            pos, dtype)
+            pos, dtype, None if kind is None else kind["window"])
     return (L.dense_apply(p["wo"], out, dtype, cfg.quant_spec()),
             cache_k, cache_v)

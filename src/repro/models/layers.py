@@ -14,6 +14,7 @@ shim at the bottom of this module.
 from __future__ import annotations
 
 import dataclasses
+import math
 import warnings
 from typing import Optional, Tuple
 
@@ -29,7 +30,8 @@ from repro.engine.spec import QuantSpec
 __all__ = [
     "dense_init", "dense_apply", "rmsnorm_init", "rmsnorm_apply",
     "layernorm_init", "layernorm_apply", "embed_init", "embed_apply",
-    "rope", "activation", "QuantState", "QuantSpec",
+    "rope", "rope_inv_freq", "yarn_inv_freq", "activation", "QuantState",
+    "QuantSpec",
     "set_quant_impl", "QUANT_IMPLS",
 ]
 
@@ -155,13 +157,56 @@ def embed_logits(p, x, dtype=jnp.bfloat16):
 # RoPE + activations
 # ---------------------------------------------------------------------------
 
-def rope(q, k, positions, head_dim: int, theta: float = 1e4):
-    """Rotary embeddings.  q,k: [B, T, H, D]; positions: [B, T] int32."""
+def rope_inv_freq(head_dim: int, theta: float) -> np.ndarray:
+    """The default RoPE's inverse frequencies, float32 [head_dim // 2]."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,T,half]
+    return 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0, attention_factor: float = 0.0):
+    """YaRN's inverse frequencies and cos/sin scale (transformers'
+    ``_compute_yarn_parameters``): frequencies below the
+    ``beta_slow`` rotations over the original context are interpolated by
+    1 / factor, those above ``beta_fast`` kept, with a linear ramp between
+    floor and ceil of the two correction dimensions.  Returns
+    (float32 [head_dim // 2], scale); a scale of 0 becomes
+    0.1 ln(factor) + 1."""
+    dim = head_dim
+
+    def correction_dim(rotations):
+        return dim * math.log(original_max_position /
+                              (rotations * 2 * math.pi)) / \
+            (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extrapolation = (1 - ramp).astype(np.float32)
+    inv = (1.0 / (factor * pos_freqs)) * (1 - extrapolation) + \
+        (1.0 / pos_freqs) * extrapolation
+    scale = attention_factor or (0.1 * math.log(factor) + 1.0
+                                 if factor > 1 else 1.0)
+    return inv.astype(np.float32), float(scale)
+
+
+def rope(q, k, positions, head_dim: int, theta: float = 1e4,
+         inv_freq=None, scale=None):
+    """Rotary embeddings.  q,k: [B, T, H, D]; positions: [B, T] int32.
+    ``inv_freq`` [D // 2] and ``scale`` (of cos and sin) replace the
+    default frequencies of ``theta`` where a layer has its own (YaRN)."""
+    if inv_freq is None:
+        inv_freq = rope_inv_freq(head_dim, theta)
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
 
     def rot(x):
         x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

@@ -1,4 +1,19 @@
-"""Mixture-of-Experts FFN with capacity-bounded scatter dispatch.
+"""Mixture-of-Experts FFN: a dropless layer over the experts a chip holds,
+and the training path's capacity-bounded scatter dispatch.
+
+``moe_held_apply`` serves decode and prefill.  It is told which experts
+it holds (``ModelConfig.experts_first`` / ``experts_held``), routes every
+token over all ``n_experts`` (float32 softmax, top-k, renormalised over
+the k), keeps the routes that land on its own experts, and gathers each
+held expert's tokens into a buffer of capacity T: an expert takes at
+most one route per token, so nothing is ever dropped.  The three expert
+GEMMs run as one grouped call each over the held experts (the
+digit-plane kernel ``bw_gemm_experts`` on planned weights), and the
+gates are applied at the combine.  The result is the held experts' part
+of the layer; the shares of a layer's experts over chips add up to the
+whole layer.
+
+``moe_apply`` is the training path (all experts held):
 
 Top-k routing, Switch-style load-balancing auxiliary loss, and an
 O(tokens * d) scatter/gather dispatch (no [tokens, E, C] one-hot einsum).
@@ -25,12 +40,25 @@ import numpy as np
 from repro.parallel.sharding import box, constrain
 from . import layers as L
 
-__all__ = ["moe_init", "moe_apply"]
+__all__ = ["moe_init", "moe_apply", "moe_held_apply", "route",
+           "EXPERT_WEIGHTS"]
+
+# the expert weights of a layer, [held, K, N] each ("w_gate" when gated)
+EXPERT_WEIGHTS = ("w_up", "w_gate", "w_down")
 
 
 def moe_init(key, cfg, param_dtype=jnp.float32):
+    """Router over all ``n_experts``, and the held experts' weights.
+
+    Each expert draws from its own key (``split(key_w, n_experts)``), so
+    a chip's share is the slice of the whole layer's weights that it
+    holds, drawn without drawing the rest."""
     ks = jax.random.split(key, 4)
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    first, held = cfg.experts_first, cfg.held_experts
+    if first < 0 or first + held > e:
+        raise ValueError(f"experts [{first}, {first + held}) are not among "
+                         f"the layer's {e}")
     exp_axis = "expert" if cfg.moe_shard == "expert" else None
     mlp_axis = "mlp" if cfg.moe_shard == "mlp" else None
     # FSDP the d_model dim of expert weights in BOTH shard modes: for
@@ -39,19 +67,77 @@ def moe_init(key, cfg, param_dtype=jnp.float32):
     emb_axis = "embed"
 
     def w(k, shape, axes):
-        return box(L.truncated_normal(k, shape, 1.0, param_dtype)
-                   / np.sqrt(shape[1]), axes)
+        keys = jax.random.split(k, e)[first:first + held]
+        return box(jax.vmap(lambda ki: L.truncated_normal(
+            ki, shape, 1.0, param_dtype))(keys), axes)
 
     p = {
         "router": {"w": box(L.truncated_normal(ks[0], (d, e), 1.0,
                                                param_dtype), ("embed_nofsdp",
                                                               None))},
-        "w_up": w(ks[1], (e, d, f), (exp_axis, emb_axis, mlp_axis)),
-        "w_down": w(ks[2], (e, f, d), (exp_axis, mlp_axis, emb_axis)),
+        "w_up": w(ks[1], (d, f), (exp_axis, emb_axis, mlp_axis)),
+        "w_down": w(ks[2], (f, d), (exp_axis, mlp_axis, emb_axis)),
     }
     if cfg.gated_mlp:
-        p["w_gate"] = w(ks[3], (e, d, f), (exp_axis, emb_axis, mlp_axis))
+        p["w_gate"] = w(ks[3], (d, f), (exp_axis, emb_axis, mlp_axis))
     return p
+
+
+def route(router_w, xf, k: int):
+    """float32 router over all experts: softmax, top-k, renormalised over
+    the k.  xf [T, d] -> (gate [T, k] float32, experts [T, k] int32)."""
+    logits = jnp.dot(xf.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gate, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9), eidx
+
+
+def _expert_gemm(p, name, x, count, cfg, dtype, activation=None):
+    """x [H, T, K] through each held expert's ``name`` weight: one grouped
+    digit-plane kernel call where the weights are planned, else each
+    expert through ``dense_apply`` under the config's QuantSpec (bf16
+    without one)."""
+    plan = p.get(name + "_plan")
+    spec = cfg.quant_spec()
+    if plan is not None and spec is not None:
+        from repro.kernels import ops
+        return ops.planned_experts_apply(
+            plan, x, spec, p[name].shape[-1], count, activation=activation,
+            out_dtype=dtype)
+    return jax.vmap(lambda w, xh: L.dense_apply(
+        {"w": w}, xh, dtype, spec, activation=activation))(p[name], x)
+
+
+def moe_held_apply(p, x, cfg, dtype=jnp.bfloat16):
+    """x: [B, T, d] -> (y [B, T, d], counts int32 [2]): the held experts'
+    part of the layer, dropless, and (token-routes that landed on held
+    experts, held experts with at least one route)."""
+    b, t, d = x.shape
+    first, held = cfg.experts_first, cfg.held_experts
+    xf = x.reshape(-1, d)
+    gate, eidx = route(p["router"]["w"], xf, cfg.experts_per_token)
+    # [T, k, H]: route r of token t lands on held expert h
+    hit = (eidx - first)[..., None] == jnp.arange(held)
+    weight = jnp.where(hit, gate[..., None], 0.0).sum(1)          # [T, H]
+    routed = hit.any(1).T                                         # [H, T]
+    count = routed.sum(1, dtype=jnp.int32)                        # [H]
+    # each held expert's tokens, its routed ones first: capacity T
+    order = jnp.argsort(~routed, axis=1, stable=True)             # [H, T]
+    buf = jnp.take(xf, order, axis=0)                             # [H, T, d]
+    if cfg.gated_mlp:
+        h = L.activation(cfg.act)(
+            _expert_gemm(p, "w_gate", buf, count, cfg, dtype)) * \
+            _expert_gemm(p, "w_up", buf, count, cfg, dtype)
+    else:
+        h = _expert_gemm(p, "w_up", buf, count, cfg, dtype,
+                         activation=cfg.act)
+    out = _expert_gemm(p, "w_down", h, count, cfg, dtype)         # [H, T, d]
+    # combine: each token's rows back from every held expert, by gate
+    back = jnp.take_along_axis(out, jnp.argsort(order, axis=1)[..., None],
+                               axis=1)
+    y = jnp.einsum("htd,th->td", back.astype(jnp.float32), weight)
+    counts = jnp.stack([count.sum(), (count > 0).sum(dtype=jnp.int32)])
+    return y.reshape(b, t, d).astype(dtype), counts
 
 
 def _dispatch(xf, eidx, gate, e: int, k: int, cap: int, dtype):

@@ -1,9 +1,17 @@
 """Decoder-only transformer LM (dense / MoE / VLM-stub) with scanned layers.
 
 Covers olmoe, grok-1, phi-3-vision (backbone + patch-embedding stub),
-minicpm, nemotron-4, qwen1.5, granite.  Layers are stacked on a leading
-'layers' axis and applied with jax.lax.scan (+ optional remat) so the HLO
-stays compact at 80+ layers.
+minicpm, nemotron-4, qwen1.5, granite, mellum2.  Layers are stacked on a
+leading 'layers' axis and applied with jax.lax.scan (+ optional remat) so
+the HLO stays compact at 80+ layers.  Where layers differ in kind (window
+and full attention, per-layer RoPE), ``attention.layer_kinds`` gives the
+per-layer tables as arrays, which ride in the scans' inputs beside the
+layer parameters; a config whose layers are alike scans its parameters
+alone.
+
+Decode and prefill of an MoE config run the dropless held-expert layer
+(``moe.moe_held_apply``); the full forward (training) runs it too where
+the config holds a share of the experts, else the capacity dispatch.
 """
 from __future__ import annotations
 
@@ -70,11 +78,15 @@ def block_init(key, cfg, param_dtype=jnp.float32):
     return p
 
 
-def block_apply(p, x, cfg, positions, dtype=jnp.bfloat16):
+def block_apply(p, x, cfg, positions, dtype=jnp.bfloat16, kind=None):
     h, _ = A.attn_apply(p["attn"], norm_apply(cfg, p["ln1"], x), cfg,
-                        positions, dtype)
+                        positions, dtype, kind)
     x = x + h
-    if cfg.n_experts:
+    if cfg.n_experts and cfg.experts_held:
+        h, _ = M.moe_held_apply(p["moe"], norm_apply(cfg, p["ln2"], x), cfg,
+                                dtype)
+        aux = jnp.zeros((), jnp.float32)
+    elif cfg.n_experts:
         h, aux = M.moe_apply(p["moe"], norm_apply(cfg, p["ln2"], x), cfg,
                              dtype)
     else:
@@ -83,17 +95,23 @@ def block_apply(p, x, cfg, positions, dtype=jnp.bfloat16):
     return x + h, aux
 
 
-def block_decode(p, x, cfg, ck, cv, layer, pos, dtype=jnp.bfloat16):
-    """One layer of a decode step; ck / cv are the stacked caches."""
+def block_decode(p, x, cfg, ck, cv, layer, pos, dtype=jnp.bfloat16,
+                 kind=None):
+    """One layer of a decode step; ck / cv are the stacked caches.
+    Returns (x, ck, cv, counts): the held-expert layer's routes and active
+    experts for an MoE config, else None."""
     h, ck, cv = A.attn_decode(p["attn"], norm_apply(cfg, p["ln1"], x), cfg,
-                              ck, cv, layer, pos, dtype)
+                              ck, cv, layer, pos, dtype, kind)
     x = x + h
+    counts = None
     if cfg.n_experts:
-        h, _ = M.moe_apply(p["moe"], norm_apply(cfg, p["ln2"], x), cfg, dtype)
+        with jax.named_scope("moe"):
+            h, counts = M.moe_held_apply(
+                p["moe"], norm_apply(cfg, p["ln2"], x), cfg, dtype)
     else:
         with jax.named_scope("mlp"):
             h = mlp_apply(p["mlp"], norm_apply(cfg, p["ln2"], x), cfg, dtype)
-    return x + h, ck, cv
+    return x + h, ck, cv, counts
 
 
 def stack_layer_params(key, n_layers: int, init_fn):
@@ -127,17 +145,29 @@ def lm_init(key, cfg, param_dtype=None):
     return params
 
 
-def _run_blocks(params, x, cfg, positions, dtype):
-    blocks = params["blocks"]
+def _layer_inputs(params, cfg):
+    """The layer scans' inputs: the stacked layer parameters, with the
+    per-layer attention tables beside them where layers differ."""
+    kinds = A.layer_kinds(cfg)
+    return params["blocks"] if kinds is None else (params["blocks"], kinds)
 
-    def body(carry, layer_params):
+
+def _split_layer(xs, cfg):
+    """(layer parameters, kind) of one scan step's inputs."""
+    return (xs, None) if not cfg.has_layer_kinds else xs
+
+
+def _run_blocks(params, x, cfg, positions, dtype):
+    def body(carry, xs):
         h, aux = carry
-        h2, a = block_apply(layer_params, h, cfg, positions, dtype)
+        layer_params, kind = _split_layer(xs, cfg)
+        h2, a = block_apply(layer_params, h, cfg, positions, dtype, kind)
         return (h2, aux + a), None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
     (x, aux), _ = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                               blocks, unroll=cfg.scan_unroll)
+                               _layer_inputs(params, cfg),
+                               unroll=cfg.scan_unroll)
     return x, aux
 
 
@@ -200,15 +230,16 @@ def lm_prefill(params, tokens, cfg, max_len: int, frontend_embeds=None):
     b, t, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
 
-    def body(h, layer_params):
+    def body(h, xs):
+        layer_params, kind = _split_layer(xs, cfg)
         hn = norm_apply(cfg, layer_params["ln1"], h)
         attn_out, (k, v) = A.attn_apply(layer_params["attn"], hn, cfg,
-                                        positions, dtype)
+                                        positions, dtype, kind)
         h = h + attn_out
         if cfg.n_experts:
-            m, _ = M.moe_apply(layer_params["moe"],
-                               norm_apply(cfg, layer_params["ln2"], h), cfg,
-                               dtype)
+            m, _ = M.moe_held_apply(layer_params["moe"],
+                                    norm_apply(cfg, layer_params["ln2"], h),
+                                    cfg, dtype)
         else:
             m = mlp_apply(layer_params["mlp"],
                           norm_apply(cfg, layer_params["ln2"], h), cfg, dtype)
@@ -224,31 +255,35 @@ def lm_prefill(params, tokens, cfg, max_len: int, frontend_embeds=None):
                        "v": jnp.moveaxis(vc, 1, 3)}
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, caches = jax.lax.scan(body_fn, x, params["blocks"],
+    x, caches = jax.lax.scan(body_fn, x, _layer_inputs(params, cfg),
                              unroll=cfg.scan_unroll)
     logits = _logits(params, x[:, -1:, :], cfg, dtype)
     return logits, caches
 
 
-def lm_decode_step(params, tokens, pos, caches, cfg):
+def lm_decode_step(params, tokens, pos, caches, cfg, moe_counts=False):
     """One decode step.  tokens [B, 1]; pos [B]; caches from init/prefill.
 
     The stacked caches ride in the layer scan's carry with the layer
     index, and each layer writes its K/V column into them, so a donated
     cache is updated in place.
-    Returns (logits [B, 1, V], updated caches).
+    Returns (logits [B, 1, V], updated caches), and with ``moe_counts``
+    (an MoE config) also int32 [L, 2]: per layer, the token-routes that
+    landed on held experts and the held experts with a route.
     """
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed_apply(params["embed"], tokens, dtype)
     x = constrain(x, "batch", None, None)
 
-    def body(carry, layer_params):
+    def body(carry, xs):
         h, layer, ck, cv = carry
-        h, ck, cv = block_decode(layer_params, h, cfg, ck, cv, layer, pos,
-                                 dtype)
-        return (h, layer + 1, ck, cv), None
+        layer_params, kind = _split_layer(xs, cfg)
+        h, ck, cv, counts = block_decode(layer_params, h, cfg, ck, cv, layer,
+                                         pos, dtype, kind)
+        return (h, layer + 1, ck, cv), counts
 
-    (x, _, ck, cv), _ = jax.lax.scan(
-        body, (x, jnp.int32(0), caches["k"], caches["v"]), params["blocks"],
-        unroll=cfg.scan_unroll)
-    return _logits(params, x, cfg, dtype), {"k": ck, "v": cv}
+    (x, _, ck, cv), counts = jax.lax.scan(
+        body, (x, jnp.int32(0), caches["k"], caches["v"]),
+        _layer_inputs(params, cfg), unroll=cfg.scan_unroll)
+    out = (_logits(params, x, cfg, dtype), {"k": ck, "v": cv})
+    return out + (counts,) if moe_counts else out

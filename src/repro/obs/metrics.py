@@ -356,6 +356,15 @@ GLOSSARY = {
         "help": "Cache positions the decode step reads (batch x "
                 "max_len), summed over engine steps; counts with obs "
                 "disabled."},
+    "repro_moe_routes_held_total": {
+        "type": "counter",
+        "help": "Token-routes that landed on the experts this chip holds, "
+                "summed over layers and engine steps (read with the "
+                "sampled tokens); counts with obs disabled."},
+    "repro_moe_experts_active_total": {
+        "type": "counter",
+        "help": "Held experts with at least one route, summed over "
+                "layers and engine steps; counts with obs disabled."},
     "repro_serve_step_alias_bytes": {
         "type": "gauge",
         "help": "Bytes of the compiled decode step's outputs that alias "

@@ -23,6 +23,7 @@ Correctness fixes over the legacy loop:
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import List, Optional
 
@@ -44,7 +45,7 @@ from .request import QUEUED, ServeRequest
 from .scheduler import Scheduler
 from .slots import SlotAllocator
 
-__all__ = ["ServeEngine", "RESET_STATE_FAMILIES"]
+__all__ = ["ServeEngine", "RESET_STATE_FAMILIES", "LOOKAHEAD_FAMILIES"]
 
 _REG = obs_metrics.get_registry()
 _M_STEPS = _REG.counter("repro_serve_engine_steps_total")
@@ -56,12 +57,39 @@ _M_TOK_RECOVERED = _REG.counter("repro_serve_tokens_recovered_total")
 _M_SLOTS_BOUND = _REG.counter("repro_serve_slot_steps_bound_total").labels()
 _M_KV_LIVE = _REG.counter("repro_serve_kv_positions_live_total").labels()
 _M_KV_READ = _REG.counter("repro_serve_kv_positions_read_total").labels()
+_M_MOE_ROUTES = _REG.counter("repro_moe_routes_held_total").labels()
+_M_MOE_ACTIVE = _REG.counter("repro_moe_experts_active_total").labels()
 _M_ALIAS_BYTES = _REG.gauge("repro_serve_step_alias_bytes")
 _M_TEMP_BYTES = _REG.gauge("repro_serve_step_temp_bytes")
 
 # Families whose decode state is a recurrence (no position-masked cache):
 # their per-slot state row must be re-initialized when a slot is reused.
 RESET_STATE_FAMILIES = ("rwkv", "hybrid")
+# Families whose engine dispatches the next step before reading back the
+# one in flight (``ServeEngine(lookahead=True)``): a decoder-only cache
+# masked by position, so a step that runs for a slot released or rebound
+# meanwhile writes only rows its next occupant rewrites before it reads.
+LOOKAHEAD_FAMILIES = ("dense", "moe", "vlm")
+
+
+@jax.jit
+def _feed(cur, prev):
+    """The tokens a step dispatched ahead takes: ``cur`` from the host,
+    or where it is -1 the token the step before sampled (``prev``, still
+    on the device)."""
+    return jnp.where(cur < 0, prev, cur)
+
+
+@dataclasses.dataclass
+class _Dispatched:
+    """A step on its way: its sampled tokens and expert counts (device
+    arrays, not yet read; counts None without experts), the
+    ``SlotAllocator.live()`` it computes for, and its counter values."""
+    nxt: jax.Array
+    counts: Optional[jax.Array]
+    live: np.ndarray
+    bound: int
+    kv_live: int
 
 
 @jax.jit
@@ -117,11 +145,18 @@ class ServeEngine:
     attached to the param tree, so the jit'd serve step scans/slices them
     like any other parameter and each quantized matmul executes the Pallas
     bw_gemm kernel (interpret mode off-TPU) instead of the jnp oracle.
+
+    With ``lookahead`` (the default; families in ``LOOKAHEAD_FAMILIES``
+    and the engine's own step only) each ``step`` dispatches the next
+    step before it reads back the one in flight, feeding the tokens that
+    step samples on the device, so the host's work between steps runs
+    while the device computes.  A request admitted while a step is in
+    flight joins the step after it; the tokens served are the same.
     """
 
     def __init__(self, cfg, batch: int, max_len: int, seed: int = 0,
                  quant=None, on_too_long: str = "error",
-                 audit: bool = False):
+                 audit: bool = False, lookahead: bool = True):
         if isinstance(quant, QuantSpec):
             spec = quant if quant.enabled else None
         elif isinstance(quant, L.QuantState):
@@ -148,6 +183,9 @@ class ServeEngine:
             self._build(cfg, spec, seed)
         self.slots = SlotAllocator(batch, max_len, audit=audit)
         self.steps = 0
+        self.lookahead = lookahead and \
+            self.api.family in LOOKAHEAD_FAMILIES
+        self._ahead: Optional[_Dispatched] = None
         # checkpoint/restore tallies (the async server folds the per-run
         # deltas into its failover stats)
         self.ckpt_stats = {"snapshots": 0, "restored": 0,
@@ -381,30 +419,74 @@ class ServeEngine:
         The engine's first call is ``serve.compile`` in place of
         ``serve.dispatch``: JAX traces and compiles the step there.  The
         engine's own step takes the state by donation, so the previous
-        state's arrays are deleted once it is dispatched."""
+        state's arrays are deleted once it is dispatched.  A step with
+        experts also returns its per-layer expert counts, read back with
+        the tokens in one host read and added to
+        ``repro_moe_routes_held_total`` and
+        ``repro_moe_experts_active_total``.
+
+        With lookahead the step this call completes was most often
+        dispatched by the call before; this call dispatches the next one
+        (fed by ``SlotAllocator.next_inputs``) before it reads back.  The
+        counters count the step completed."""
         slots = self.slots
         with obs_trace.region("serve.step"):
             if obs_trace.enabled():
                 _M_STEPS.inc()
-            pos = slots.pos
-            bound = [i for i, _ in slots.bound()]
-            _M_SLOTS_BOUND.inc(len(bound))
-            _M_KV_LIVE.inc(int(pos[bound].sum()) + len(bound))
-            _M_KV_READ.inc(self.batch * self.max_len)
-            with obs_trace.region("serve.upload"):
-                cur, pos = jnp.asarray(slots.cur), jnp.asarray(pos)
             in_place = self.step_fn is self._built_step_fn
-            step_fn = self._step_in_place if in_place else self.step_fn
+            run, self._ahead = self._ahead, None
+            inputs = []             # (cur, pos, live) of each dispatch
+            if run is None:
+                inputs.append((slots.cur, slots.pos, slots.live()))
+            if self.lookahead and in_place:
+                ahead = slots.next_inputs(
+                    inputs[0][2] if run is None else run.live)
+                if (ahead[2] >= 0).any():
+                    inputs.append(ahead)
+            with obs_trace.region("serve.upload"):
+                uploaded = [(jnp.asarray(cur), jnp.asarray(pos))
+                            for cur, pos, _ in inputs]
             with obs_trace.region("serve.dispatch" if self.steps
                                   else "serve.compile"):
-                nxt, self.state = step_fn(self.params, cur, pos, self.state)
-                if in_place and not self.steps:
-                    self._record_step_memory(cur, pos)
+                for (_, pos, live), (cur_d, pos_d) in zip(inputs, uploaded):
+                    if run is None:
+                        run = self._dispatch(cur_d, pos_d, pos, live, None,
+                                             in_place)
+                    else:
+                        self._ahead = self._dispatch(cur_d, pos_d, pos,
+                                                     live, run.nxt, in_place)
             self.steps += 1
             with obs_trace.region("serve.readback"):
-                nxt = np.asarray(nxt)
+                if run.counts is not None:
+                    nxt, counts = jax.device_get((run.nxt, run.counts))
+                else:
+                    nxt, counts = np.asarray(run.nxt), None
             with obs_trace.region("serve.advance"):
-                return slots.advance(nxt, now)
+                _M_SLOTS_BOUND.inc(run.bound)
+                _M_KV_LIVE.inc(run.kv_live)
+                _M_KV_READ.inc(self.batch * self.max_len)
+                if counts is not None:
+                    _M_MOE_ROUTES.inc(int(counts[:, 0].sum()))
+                    _M_MOE_ACTIVE.inc(int(counts[:, 1].sum()))
+                return slots.advance(nxt, now, live=run.live)
+
+    def _dispatch(self, cur, pos, pos_host: np.ndarray, live: np.ndarray,
+                  prev, in_place: bool) -> _Dispatched:
+        """Dispatch one step over the state with the uploaded ``cur`` and
+        ``pos``; where ``cur`` is -1 the step takes ``prev``, the sampled
+        tokens of the step before, on the device."""
+        if prev is not None:
+            cur = _feed(cur, prev)
+        step_fn = self._step_in_place if in_place else self.step_fn
+        out = step_fn(self.params, cur, pos, self.state)
+        self.state = out[1]
+        if in_place and not self.steps and prev is None:
+            self._record_step_memory(cur, pos)
+        bound = live >= 0
+        return _Dispatched(nxt=out[0], counts=out[2] if len(out) > 2
+                           else None, live=live, bound=int(bound.sum()),
+                           kv_live=int(pos_host[bound].sum())
+                           + int(bound.sum()))
 
     def _record_step_memory(self, cur, pos) -> None:
         """Put the compiled step's aliased (updated in place) and

@@ -93,9 +93,12 @@ class TierWorker:
                  admission: str = "fcfs", on_too_long: str = "reject",
                  audit: bool = False):
         self.tier = tier
+        # a worker's engine steps in lock step with its pump: the virtual
+        # clock, the journal and a drain's snapshots read every step
+        # complete, so no step is dispatched ahead
         self.engine = ServeEngine(cfg, tier.batch, max_len, seed=seed,
                                   quant=tier.spec, on_too_long=on_too_long,
-                                  audit=audit)
+                                  audit=audit, lookahead=False)
         self.scheduler = Scheduler(admission, max_len=max_len,
                                    on_too_long=on_too_long)
         self.finished: List[ServeRequest] = []

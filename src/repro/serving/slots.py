@@ -91,6 +91,46 @@ class SlotAllocator:
             return False
         return int(self.cursor[slot]) >= len(self._forced[slot]) - 1
 
+    def live(self) -> np.ndarray:
+        """Each slot's binding number (``generation``) where a request is
+        bound, else -1: the slots whose tokens a step dispatched now
+        computes.  ``advance`` takes it back to apply that step's tokens
+        to those bindings only."""
+        return np.array([self.generation[i] if r is not None else -1
+                         for i, r in enumerate(self._reqs)], np.int64)
+
+    def next_inputs(self, live: np.ndarray) -> tuple:
+        """The inputs of the step after one still in flight, before that
+        step's tokens are read back: (cur, pos, live) as they will be once
+        ``advance(tokens, live=live)`` has run for the step in flight,
+        whose ``live()`` was ``live``.  A slot that samples its next
+        token in the step in flight reads -1 in ``cur``: it takes that
+        token on the device.  A binding made after the step in flight was
+        dispatched joins with its own first token; a request that
+        finishes in the step in flight is left out.  Host state is not
+        changed."""
+        cur, pos = self.cur.copy(), self.pos.copy()
+        nxt = np.full(self.n_slots, -1, np.int64)
+        for i, req in enumerate(self._reqs):
+            if req is None:
+                continue
+            gen = self.generation[i]
+            if live[i] != gen:
+                nxt[i] = gen            # bound since: not yet stepped
+                continue
+            pos[i] += 1
+            c = int(self.cursor[i]) + 1
+            forced = self._forced[i]
+            if c < len(forced):
+                cur[i, 0] = forced[c]
+            elif len(req.out) + 1 >= req.max_tokens or \
+                    pos[i] >= self.max_len - 1:
+                continue                # finishes in the step in flight
+            else:
+                cur[i, 0] = -1
+            nxt[i] = gen
+        return cur, pos, nxt
+
     def backlog_tokens(self) -> int:
         """Tokens still owed by bound requests (forced-prefix remainder +
         decode).  The forced prefix is prompt + committed output, so a
@@ -182,12 +222,17 @@ class SlotAllocator:
                 if r is not None]
 
     def advance(self, next_tokens: np.ndarray,
-                now: Optional[float] = None) -> List[ServeRequest]:
+                now: Optional[float] = None,
+                live: Optional[np.ndarray] = None) -> List[ServeRequest]:
         """Consume one engine step's sampled tokens; returns requests that
-        finished (and released their slot) this step."""
+        finished (and released their slot) this step.  ``live``, the
+        step's ``live()`` when it was dispatched, limits the tokens to the
+        bindings the step computed for: a slot bound or evicted since is
+        left as it is."""
         finished: List[ServeRequest] = []
         for i, req in enumerate(self._reqs):
-            if req is None:
+            if req is None or \
+                    (live is not None and live[i] != self.generation[i]):
                 continue
             if self.trace is not None:
                 self.trace.append(SlotEvent(int(self.generation[i]), i,

@@ -28,6 +28,7 @@ from . import optimizer as opt
 from . import compress as comp
 
 __all__ = ["TrainState", "make_train_step", "make_serve_step",
+           "routes_experts",
            "abstract_train_state", "train_state_shardings",
            "batch_specs", "batch_shardings", "init_train_state",
            "decode_state_shardings", "abstract_decode_state"]
@@ -189,18 +190,37 @@ def decode_state_shardings(axes_tree, mesh: Mesh, rules: sh.AxisRules):
     return sh.named_sharding_tree(axes_tree, mesh, rules)
 
 
+def routes_experts(cfg) -> bool:
+    """Whether ``cfg``'s serve step also returns its expert counts."""
+    from repro.models import transformer
+    return bool(cfg.n_experts) and \
+        get_api(cfg).decode_step is transformer.lm_decode_step
+
+
 def make_serve_step(cfg) -> Callable:
     """serve_step(params, tokens, pos, state) -> (next_tokens, state).
 
     One decode step: embeds the new token, attends over the cache /
     recurrent state, greedily samples.  Lowered for decode_* cells.
+    For a decoder with experts (``routes_experts``) the step returns
+    (next_tokens, state, counts): counts int32 [L, 2] holds each layer's
+    token-routes to held experts and its held experts with a route, so
+    the caller reads them with the tokens.
     """
     api = get_api(cfg)
+    routed = routes_experts(cfg)
 
     def serve_step(params, tokens, pos, state):
-        logits, new_state = api.decode_step(params, tokens, pos, state, cfg)
+        if routed:
+            logits, new_state, counts = api.decode_step(
+                params, tokens, pos, state, cfg, moe_counts=True)
+        else:
+            logits, new_state = api.decode_step(params, tokens, pos, state,
+                                                cfg)
         with jax.named_scope("sample"):
             nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        if routed:
+            return nxt[:, None], new_state, counts
         return nxt[:, None], new_state
 
     return serve_step

@@ -41,10 +41,10 @@ def test_extrapolate_clamps_negative_body():
 
 def test_registry_cells_complete():
     cells = list(registry.all_cells(include_skips=True))
-    assert len(cells) == 40
+    assert len(cells) == 44                       # 11 archs x 4 shapes
     runnable = [c for c in cells if c[2]]
     skipped = [c for c in cells if not c[2]]
-    assert len(skipped) == 8                      # long_500k x full-attn
+    assert len(skipped) == 9                      # long_500k x full-attn
     assert all(s == "long_500k" for _, s, ok in skipped if not ok)
     assert {"rwkv6-3b", "hymba-1.5b"} == {
         a for a, s, ok in runnable if s == "long_500k"}

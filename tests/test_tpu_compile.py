@@ -97,6 +97,39 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape):
         SPEC.num_digits * shape[0] * shape[1]
 
 
+# (M, K) of Mellum2's expert weights W^T: gate/up [896, 2304] and down
+# [2304, 896], eight held experts, 64 tokens padded to 128
+EXPERT_SHAPES = ((896, 2304), (2304, 896))
+
+
+@pytest.mark.parametrize("shape", EXPERT_SHAPES,
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+def test_expert_kernel_compiles_for_v5e(one_chip, shape):
+    """The grouped expert kernel (the expert a grid axis) compiles, and
+    its custom call carries the name the device trace matches."""
+    import re
+    ops_ = _operands(one_chip, *shape)
+    held = 8
+
+    def s(x, lead):
+        return jax.ShapeDtypeStruct(lead + x.shape, x.dtype,
+                                    sharding=one_chip)
+    args = [s(ops_[n], (held,)) for n in ("digits", "b", "mask", "scale",
+                                          "scale_n")]
+    counts = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+
+    def fn(d, b, mask, sc, sn, cnt):
+        return bw.bw_gemm_experts(d, b, mask, sc, cnt, sn, activation=None,
+                                  **ops_["blocks"])
+    compiled = jax.jit(fn).lower(*args, counts).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"\bbw_gemm_experts(\.\d+)? = ", text)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= \
+        held * SPEC.num_digits * shape[0] * shape[1]
+
+
 @pytest.mark.parametrize("quant", [None, SPEC], ids=["bf16", "pallas_fused"])
 def test_decode_step_updates_kv_cache_in_place(one_chip, monkeypatch, quant):
     """The decode step, compiled with its state donated, updates the KV
