@@ -337,7 +337,8 @@ def four_chips() -> None:
             devices = len(y.sharding.device_set)
             ok = np.allclose(got, want, rtol=1e-6, atol=1e-6) and \
                 devices == 4
-            print(f"[sharded] mesh {shards[0]}x{shards[1]} {reduce}: max "
+            print(f"[sharded] mesh {shards[0]}x{shards[1]} {reduce}, tile "
+                  f"({splan.block_m}, {splan.block_k}): max "
                   f"|sharded - device 0| {err:.3g}, output on {devices} "
                   f"devices, {'ok' if ok else 'MISMATCH'}", flush=True)
             if not ok:

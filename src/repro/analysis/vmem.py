@@ -66,22 +66,21 @@ def vmem_footprint(route: str, m: int, k: int, n: int, *, block_m: int,
                          f"got {route!r}")
     m_pad = _pad_up(m, block_m)
     parts = {}
-    if route == "dense":
-        # BlockSpec-resident tiles: all BW planes of the A block, the B
-        # block, and the int32 out/acc block (fused adds the acc scratch
-        # on top of the float out block; same byte count either way)
-        parts["digit_blocks"] = n_planes * block_m * block_k
-        parts["b_block"] = block_k * block_n
-        parts["acc_block"] = block_m * block_n * 4
+    if route in ("dense", "sparse"):
+        # BlockSpec tiles, each held twice by the Pallas pipeline (the
+        # next step's fetch lands while this step reads): all BW planes of
+        # the A block (dense) or ONE plane (the v2 compacted schedule),
+        # the B block and the out block; the fused kernels' int32
+        # accumulator is a scratch held once, the unfused ones accumulate
+        # in the out block itself
+        planes = n_planes if route == "dense" else 1
+        parts["digit_blocks"] = 2 * planes * block_m * block_k
+        parts["b_block"] = 2 * block_k * block_n
         if fused:
-            parts["out_block"] = block_m * block_n * out_bytes
-    elif route == "sparse":
-        # v2 compacted schedule: ONE digit plane block per step
-        parts["digit_blocks"] = block_m * block_k
-        parts["b_block"] = block_k * block_n
-        parts["acc_block"] = block_m * block_n * 4
-        if fused:
-            parts["out_block"] = block_m * block_n * out_bytes
+            parts["acc_block"] = block_m * block_n * 4
+            parts["out_block"] = 2 * block_m * block_n * out_bytes
+        else:
+            parts["acc_block"] = 2 * block_m * block_n * 4
     else:                                  # pipelined (v3)
         # scratch_shapes of bw_gemm_sparse[_fused]_pipelined
         parts["acc_panel"] = m_pad * block_n * 4

@@ -355,15 +355,22 @@ def candidate_configs(m: int, k: int, n: int) -> List[dict]:
 
     Blocks stay MXU-aligned (multiples of 128) and never exceed the padded
     problem dims by more than one block (bigger would be pure padding).
+    ``block_k`` runs up to the whole K and ``block_m`` up to 1024 rows,
+    so the sweep reaches the weight-streaming tiles that
+    ``ops.select_block_sizes`` gives a decode GEMM.
     """
-    def sizes(dim, options=(128, 256, 512)):
-        limit = -(-dim // 128) * 128      # dim rounded up to 128
-        picked = [s for s in options if s <= limit]
+    def up(dim):
+        return -(-dim // 128) * 128       # dim rounded up to 128
+
+    def sizes(dim, options):
+        picked = sorted({s for s in options if s <= up(dim)})
         return picked or [128]
 
+    # K whole, halved or in thirds, wherever that leaves 128-multiples
+    splits = tuple(up(k) // d for d in (1, 2, 3) if up(k) % (128 * d) == 0)
     out = []
-    for bm in sizes(m, (128, 256)):
-        for bk in sizes(k):
+    for bm in sizes(m, (128, 256, 384, 512, 768, 1024)):
+        for bk in sizes(k, (128, 256, 512) + splits):
             for bn in sizes(n, (128, 256)):
                 for dispatch, order, pipelined in ROUTE_CANDIDATES:
                     out.append({"block_m": bm, "block_k": bk, "block_n": bn,

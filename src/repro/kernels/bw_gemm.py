@@ -24,11 +24,14 @@ per partial product.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.obs import metrics as obs_metrics
 
 __all__ = ["bw_gemm", "bw_gemm_fused", "bw_gemm_experts", "bw_gemm_sparse",
            "bw_gemm_sparse_fused", "bw_gemm_sparse_pipelined",
@@ -68,6 +71,20 @@ EPILOGUE_ACTIVATIONS = {
     "gelu": jax.nn.gelu,
     "relu2": lambda x: jnp.square(jax.nn.relu(x)),
 }
+
+
+# set when a dense or expert kernel is traced: which tile each weight took
+_M_TILE_BYTES = obs_metrics.get_registry().gauge("repro_bw_gemm_tile_bytes")
+_M_GRID_STEPS = obs_metrics.get_registry().gauge("repro_bw_gemm_grid_steps")
+
+
+def _record_tile(kernel: str, grid, *, n_planes: int, m: int, k: int,
+                 block_m: int, block_k: int) -> None:
+    """Record the tile a dense-grid kernel streams: digit bytes a grid
+    step and steps a call, labelled by the weight's kernel M and K."""
+    labels = dict(kernel=kernel, m=m, k=k)
+    _M_TILE_BYTES.labels(**labels).set(n_planes * block_m * block_k)
+    _M_GRID_STEPS.labels(**labels).set(math.prod(grid))
 
 
 def _check_dims(fn: str, m: int, k: int, k2: int, n: int, block_m: int,
@@ -194,6 +211,8 @@ def bw_gemm(digits, b, mask, *, block_m: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda i, j, kk, msk: (i, j)),
     )
+    _record_tile("bw_gemm", grid, n_planes=bw_n, m=m, k=k,
+                 block_m=block_m, block_k=block_k)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -307,6 +326,8 @@ def bw_gemm_fused(digits, b, mask, scale, bias=None, scale_n=None, *,
                                lambda i, j, kk, msk: (i, j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
     )
+    _record_tile("bw_gemm_fused", grid, n_planes=bw_n, m=m, k=k,
+                 block_m=block_m, block_k=block_k)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -424,6 +445,8 @@ def bw_gemm_experts(digits, b, mask, scale, counts, scale_n=None, *,
                                lambda h, i, j, kk, msk, cnt: (h, i, j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
     )
+    _record_tile("bw_gemm_experts", grid, n_planes=bw_n, m=m, k=k,
+                 block_m=block_m, block_k=block_k)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

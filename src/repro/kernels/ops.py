@@ -187,20 +187,78 @@ _BLOCK_TABLE = (
 )
 
 
+# Weight-streaming decode GEMMs: at most one block of tokens on N and a
+# weight whose digit planes fill _STREAM_MIN_BYTES or more.  Such a GEMM
+# streams its planes once a call, and the table's (128, 256) tile pays a
+# Pallas grid step's fixed cost (about 0.5 us on a v5e) every 128 KiB.
+# The rule takes each shard's K whole (rounded up to 128), split into
+# equal parts only where 128 rows of it would overflow _STREAM_TILE_BYTES,
+# and then as many rows as keep one step's digit tile within it, with at
+# least _STREAM_MIN_STEPS grid steps where the shard's M has them, so the
+# pipeline hides the first fetch.  Blocks divide each shard's slab of the
+# dims rounded up to 128 x the shard grid (``parallel.plan`` pads the
+# block grid to divide it), so neither axis pads further than the
+# table's under the same grid, and a sharded K stays split.  The
+# constants come from a sweep of the chip cells' decode shapes on a v5e
+# (PERF.md section 6): at the tiles the rule gives, every shape ran within
+# 2.5% of its best tile; larger tiles lost up to 5%, and a floor of 8
+# steps would have cost the expert kernel 7% (its call multiplies the
+# steps by its experts).
+_STREAM_MAX_N = 128
+_STREAM_MIN_BYTES = 4 * 2 ** 20      # 32 of the table's 128 KiB tiles
+_STREAM_TILE_BYTES = 5 * 2 ** 19     # 2.5 MiB
+_STREAM_MIN_STEPS = 4
+
+
+def _streaming_blocks(m: int, k: int, n: int, planes: int,
+                      shards=(1, 1)):
+    """(block_m, block_k, block_n) of the weight-streaming rule for an
+    [M, K] weight of ``planes`` digit planes times N tokens, planned for
+    an ``(s_data, s_model)`` shard grid (K split ``s_data`` ways, M
+    ``s_model`` ways), or None where the rule does not apply."""
+    s_data, s_model = shards
+    k_s = -(-k // (128 * s_data)) * 128        # one shard's slab of K
+    m_s = -(-m // (128 * s_model)) * 128       # ... and of M
+    if n > _STREAM_MAX_N or \
+            planes * m_s * s_model * k_s * s_data < _STREAM_MIN_BYTES:
+        return None
+    block_k = max((bk for bk in range(128, k_s + 1, 128)
+                   if k_s % bk == 0
+                   and planes * 128 * bk <= _STREAM_TILE_BYTES), default=128)
+    block_m = max((bm for bm in range(128, m_s + 1, 128)
+                   if m_s % bm == 0
+                   and planes * bm * block_k <= _STREAM_TILE_BYTES
+                   and (m_s // bm) * (k_s // block_k)
+                   >= _STREAM_MIN_STEPS), default=128)
+    return block_m, block_k, _STREAM_MAX_N
+
+
 def select_block_sizes(m: int, k: int, n: int,
-                       spec: Optional[QuantSpec] = None):
+                       spec: Optional[QuantSpec] = None, shards=None):
     """(block_m, block_k, block_n) for a logical [M, K] x [K, N] GEMM.
 
     Resolution order: (1) a measured winner from the autotune cache for
-    this (shape, spec-plan) key, (2) the static dispatch table — with an
+    this (shape, spec-plan) key, (2) the weight-streaming rule for a
+    decode GEMM (N within one 128 block, a weight of megabytes:
+    ``_streaming_blocks``), (3) the static dispatch table — with an
     AutotuneCacheMissWarning when an explicitly configured cache lacks the
     shape.  A spec's explicit block_m/block_k/block_n overrides win
-    component-wise over both.
+    component-wise over all three.
+
+    shards: the ``(s_data, s_model)`` mesh shard grid a plan is split
+    over (K on ``data``, M on ``model``); the rule sizes its tiles to one
+    shard's slab.
     """
+    from repro.parallel.collectives import normalize_shards
     from . import autotune
     hit = autotune.get_cache().lookup(m, k, n, spec)
+    planes = spec.num_digits if spec is not None else enc.num_digits("ent", 8)
+    shards = normalize_shards(shards)
     if hit is not None:
         sel = (hit["block_m"], hit["block_k"], hit["block_n"])
+    elif (streaming := _streaming_blocks(m, k, n, planes, shards)) \
+            is not None:
+        sel = streaming
     else:
         sel = _BLOCK_TABLE[-1][1]
         for (mn_m, mn_k, mn_n), blocks in _BLOCK_TABLE:
@@ -762,12 +820,12 @@ def plan_for(w, spec, order: str = "m_major",
             "step); under tracing use the jnp oracle path instead")
     spec = QuantSpec.coerce(spec)
     k, n = w.shape
-    block_m, block_k, _ = select_block_sizes(n, k, 128, spec)
     if shards is not None:
         from repro.parallel.collectives import normalize_shards
         shards = normalize_shards(shards)
         if shards == (1, 1):
             shards = None
+    block_m, block_k, _ = select_block_sizes(n, k, 128, spec, shards)
     params = spec.plan_key() + (int(block_m), int(block_k), k, n, order,
                                 shards)
 

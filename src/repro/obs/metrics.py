@@ -442,6 +442,19 @@ GLOSSARY = {
     "repro_serve_brownout_level": {
         "type": "gauge",
         "help": "Current brownout degradation level (0 = healthy)."},
+    "repro_bw_gemm_tile_bytes": {
+        "type": "gauge",
+        "help": "Digit-plane bytes one grid step of a dense-grid GEMM "
+                "kernel fetches (all planes of one (block_m, block_k) "
+                "tile; labels kernel=, m=, k=: the weight's kernel M and "
+                "K, padded), set when the kernel is traced."},
+    "repro_bw_gemm_grid_steps": {
+        "type": "gauge",
+        "help": "Grid steps of one call of a dense-grid GEMM kernel "
+                "(experts x M-blocks x N-blocks x K-blocks for the "
+                "grouped expert kernel; labels as "
+                "repro_bw_gemm_tile_bytes), set when the kernel is "
+                "traced."},
     "repro_autotune_cache_load_errors_total": {
         "type": "counter",
         "help": "Autotune cache files that failed to parse and fell "

@@ -14,6 +14,9 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
+import pytest
+
 _SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -90,3 +93,86 @@ def test_sharded_apply_parity_all_meshes(tmp_path):
     assert r.returncode == 0, r.stdout + "\n" + r.stderr
     assert "ALL_OK 9" in r.stdout, r.stdout
     assert r.stdout.count("PARITY_OK") == 9, r.stdout
+
+
+# ---------------------------------------------------------------------------
+# the weight-streaming tiles of ``ops.select_block_sizes`` on a sharded plan
+# ---------------------------------------------------------------------------
+
+STREAM_SPEC = "planes=3,encoding=ent,impl=pallas_fused,act_quant=per_token"
+
+
+def _up(x, m):
+    return -(-x // m) * m
+
+
+@pytest.mark.parametrize("shards", [(2, 2), (4, 1), (1, 4), (4, 2)],
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+def test_streaming_tiles_split_over_the_shard_grid(shards):
+    """minicpm-2b's MLP weight (the four-chip check of ``chip_smoke.py``,
+    53 MB of planes) planned for a mesh: the rule's tiles pad K and M no
+    further than the (128, 256) table tile under the same shard grid,
+    and every shard holds live plane blocks, so splitting K over 'data'
+    still splits the work."""
+    from repro.engine import QuantSpec
+    from repro.kernels import ops
+    from repro.parallel.plan import plan_sharded_weight
+    spec = QuantSpec.parse(STREAM_SPEC)
+    k, n = 2304, 5760
+    w = (np.random.default_rng(0).standard_t(4, size=(k, n)) * 0.02
+         ).astype(np.float32)
+    s_data, s_model = shards
+    splan = plan_sharded_weight(w, spec, shards)
+    assert (splan.block_m, splan.block_k) == \
+        ops.select_block_sizes(n, k, 128, spec, shards)[:2]
+    assert (splan.block_m, splan.block_k) != (128, 256)   # the rule's
+    _, mb, kb = np.asarray(splan.plan["mask"]).shape
+    assert kb * splan.block_k <= _up(k, 256 * s_data)
+    assert mb * splan.block_m <= _up(n, 128 * s_model)
+    for i in range(s_model):
+        for j in range(s_data):
+            assert splan.shard_mask(i, j).any(), (i, j)
+
+
+_STREAM_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+
+    import jax, jax.numpy as jnp
+    import numpy as np
+
+    from repro.engine import QuantSpec
+    from repro.kernels import ops
+    from repro.parallel.apply import make_gemm_mesh, sharded_planned_apply
+    from repro.parallel.plan import plan_sharded_weight
+
+    K, M, BATCH = 2304, 1024, 16       # 9 MB of planes: the rule's tiles
+    spec = QuantSpec.parse("%s")
+    rng = np.random.default_rng(1)
+    w = (rng.standard_t(4, size=(K, M)) * 0.02).astype(np.float32)
+    x = jnp.asarray(rng.normal(0, 1, size=(BATCH, K)).astype(np.float32))
+    plan = ops.plan_dense_weight(w, spec)
+    want = np.asarray(ops.planned_dense_apply(
+        plan, x, spec, M, activation="silu", fused=False, dispatch="auto"))
+    for shards, reduce in (((2, 2), "psum"), ((4, 2), "psum_scatter")):
+        splan = plan_sharded_weight(w, spec, shards)
+        assert (splan.block_m, splan.block_k) == \\
+            ops.select_block_sizes(M, K, 128, spec, shards)[:2]
+        got = np.asarray(sharded_planned_apply(
+            splan, x, spec, M, activation="silu", dispatch="auto",
+            mesh=make_gemm_mesh(shards), reduce=reduce))
+        assert np.allclose(got, want, rtol=1e-6, atol=1e-6), (
+            shards, float(np.abs(got - want).max()))
+        print("PARITY_OK", shards, splan.block_m, splan.block_k)
+""" % STREAM_SPEC)
+
+
+def test_sharded_apply_parity_at_streaming_tiles(tmp_path):
+    script = tmp_path / "sharded_stream.py"
+    script.write_text(_STREAM_SCRIPT)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    r = subprocess.run([sys.executable, str(script)], env=env,
+                       capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0, r.stdout + "\n" + r.stderr
+    assert r.stdout.count("PARITY_OK") == 2, r.stdout

@@ -22,9 +22,11 @@ from repro.kernels import ops
 SPEC = QuantSpec.parse("planes=3,encoding=ent,impl=pallas_fused,"
                        "act_quant=per_token")
 N_TOKENS = 128
-# (M, K) of the planned weight W^T: up/gate [d_ff, d_model] and
-# down [d_model, d_ff]
-SHAPES = ((5760, 2304), (2304, 5760))
+# (M, K) of the planned weight W^T: minicpm-2b's up/gate [d_ff, d_model]
+# and down [d_model, d_ff]; Mellum2's attention projections (q, k/v, o)
+# and its untied head [vocab, d_model]
+SHAPES = ((5760, 2304), (2304, 5760), (4096, 2304), (512, 2304),
+          (2304, 4096), (98304, 2304))
 
 
 @pytest.fixture(scope="module")
@@ -84,8 +86,12 @@ KERNELS = {
 }
 
 
-@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: f"{s[0]}x{s[1]}")
-@pytest.mark.parametrize("kernel", sorted(KERNELS))
+@pytest.mark.parametrize("kernel,shape", [
+    (kernel, shape) for shape in SHAPES for kernel in sorted(KERNELS)
+    # the pipelined route keeps an (M, block_n) accumulator panel in
+    # VMEM, which cannot hold the head's 98304 rows at any tile
+    if not (kernel.endswith("pipelined") and shape[0] > 32768)],
+    ids=lambda v: f"{v[0]}x{v[1]}" if isinstance(v, tuple) else v)
 def test_kernel_compiles_for_v5e(one_chip, kernel, shape):
     ops_ = _operands(one_chip, *shape)
     fn, names = KERNELS[kernel](ops_)
@@ -128,6 +134,30 @@ def test_expert_kernel_compiles_for_v5e(one_chip, shape):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= \
         held * SPEC.num_digits * shape[0] * shape[1]
+
+
+@pytest.mark.parametrize("shards", [(2, 2), (4, 1), (1, 4)],
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+def test_shard_kernel_compiles_for_v5e(one_chip, shards):
+    """One shard of minicpm-2b's MLP weight planned for a mesh, as the
+    four-chip check of ``chip_smoke.py`` plans it: the unfused dense
+    kernel each device runs (``parallel.apply``) compiles at the tile the
+    weight-streaming rule gives one shard's slab."""
+    s_data, s_model = shards
+    m, k = 5760, 2304
+    bm, bk, bn = ops.select_block_sizes(m, k, N_TOKENS, SPEC, shards)
+    m_s, k_s = -(-m // (bm * s_model)) * bm, -(-k // (bk * s_data)) * bk
+    bw_n = SPEC.num_digits
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(d, b, mask):
+        return bw.bw_gemm(d, b, mask, block_m=bm, block_n=bn, block_k=bk)
+    compiled = jax.jit(fn).lower(
+        s((bw_n, m_s, k_s), jnp.int8), s((k_s, N_TOKENS), jnp.int8),
+        s((bw_n, m_s // bm, k_s // bk), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("quant", [None, SPEC], ids=["bf16", "pallas_fused"])
