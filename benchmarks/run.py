@@ -169,11 +169,39 @@ def fig14_equal_area():
 # Kernels: interpret-mode exactness + block-skip density (TPU-native layer)
 # --------------------------------------------------------------------------
 
+def _planned_kernel(kernel, planned, b, *rows, block_n: int = 128, **kw):
+    """The raw ``repro.kernels.bw_gemm`` kernel ``kernel`` on a
+    ``plan_operand`` result x int8 B [K, N] (interpret mode): B padded to
+    the plan's blocks, the per-row ``rows`` (scale, bias) put in planned
+    order, the result un-permuted and sliced back to [M, N]."""
+    import numpy as np
+    import jax.numpy as jnp
+    from repro.core import encodings as enc
+    from repro.kernels import bw_gemm as bwk
+    k, n = b.shape
+    m_pad, k_pad = planned.digits.shape[1:]
+    bt = jnp.pad(jnp.asarray(b, jnp.int8),
+                 ((0, k_pad - k), (0, -n % block_n)))
+    perm = np.asarray(planned.row_perm)
+    rows = [None if v is None else jnp.asarray(
+        np.pad(np.asarray(v, np.float32).reshape(-1),
+               (0, m_pad - planned.m))[perm].reshape(-1, 1)) for v in rows]
+    if kernel in (bwk.bw_gemm, bwk.bw_gemm_fused):
+        table = planned.mask
+        kw["radix"] = enc.radix(planned.encoding)
+    else:
+        table = jnp.asarray(planned.schedule)
+    out = kernel(planned.digits, bt, table, *rows, block_m=planned.block_m,
+                 block_n=block_n, block_k=planned.block_k, interpret=True,
+                 **kw)
+    return np.asarray(out)[np.asarray(planned.inv_perm)][:planned.m, :n]
+
+
 def kernel_bw_gemm():
     import numpy as np
     import jax.numpy as jnp
     from repro.core import quant
-    from repro.kernels import ops
+    from repro.kernels import bw_gemm as bwk, ops
     rng = np.random.default_rng(0)
     # LLM-like weights, plane-bounded to 3 EN-T planes: plane 3 becomes
     # structurally empty, so >= 25% of MXU passes are skipped by mask.
@@ -182,8 +210,7 @@ def kernel_bw_gemm():
     a = np.asarray(qw)
     b = rng.integers(-128, 128, size=(256, 128)).astype(np.int8)
     planned = ops.plan_operand(a, block_m=128, block_k=128)
-    out = np.asarray(ops.bw_gemm(planned, jnp.asarray(b), block_n=128,
-                                 interpret=True))
+    out = _planned_kernel(bwk.bw_gemm, planned, b)
     want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
     density = ops.plane_density(planned.digits, 128, 128)
     return {"exact": bool((out == want).all()),
@@ -201,7 +228,7 @@ def kernel_bw_gemm_fused():
     import jax
     import jax.numpy as jnp
     from repro.core import quant
-    from repro.kernels import ops
+    from repro.kernels import bw_gemm as bwk, ops
     rng = np.random.default_rng(0)
     x = rng.normal(0, 1, size=(128, 256)).astype(np.float32)
     w = (rng.standard_t(4, size=(256, 192)) * 0.02).astype(np.float32)
@@ -213,7 +240,7 @@ def kernel_bw_gemm_fused():
     qx, sx = quant.quantize_to_planes(jnp.asarray(x), 3)
     qw, sw = quant.quantize_to_planes(jnp.asarray(w), 3, axis=0)
     planned = ops.plan_operand(np.asarray(qw).T)
-    acc = np.asarray(ops.bw_gemm(planned, np.asarray(qx).T, interpret=True))
+    acc = _planned_kernel(bwk.bw_gemm, planned, np.asarray(qx).T)
     want = acc.T.astype(np.float32) * np.asarray(sx * sw)
     want = np.asarray(jax.nn.silu(jnp.asarray(want + bias)))
     return {"allclose": bool(np.allclose(got, want, rtol=1e-5, atol=1e-5)),
@@ -399,7 +426,7 @@ def kernel_bw_gemm_sparse():
     import jax.numpy as jnp
     from repro.core import quant
     from repro.engine import QuantSpec, get_engine
-    from repro.kernels import ops
+    from repro.kernels import bw_gemm as bwk, ops
     rng = np.random.default_rng(0)
     m, k, n = 256, 256, 128
     b = rng.integers(-128, 128, size=(k, n)).astype(np.int8)
@@ -413,12 +440,10 @@ def kernel_bw_gemm_sparse():
         qw, _ = quant.quantize_to_planes(jnp.asarray(w), planes=planes)
         a = np.asarray(qw).astype(np.int8)
         planned = ops.plan_operand(a, block_m=128, block_k=128)
-        dense = np.asarray(ops.bw_gemm_fused(
-            planned, jnp.asarray(b), scale, bias, activation="silu",
-            interpret=True))
-        sparse = np.asarray(ops.bw_gemm_sparse_fused(
-            planned, jnp.asarray(b), scale, bias, activation="silu",
-            interpret=True))
+        dense = _planned_kernel(bwk.bw_gemm_fused, planned, b, scale, bias,
+                                activation="silu")
+        sparse = _planned_kernel(bwk.bw_gemm_sparse_fused, planned, b,
+                                 scale, bias, activation="silu")
         density = planned.density()
         spec = QuantSpec(planes=planes, block_m=128, block_k=128)
         cd = dense_eng.cost(m, k, n, spec, density=density)
@@ -440,8 +465,7 @@ def kernel_bw_gemm_sparse():
     adv[:128, :128] = rng.choice(np.int8([64, -64]), size=(128, 128))
     planned = ops.plan_operand(adv, block_m=128, block_k=128)
     want = (adv.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
-    got = np.asarray(ops.bw_gemm_sparse(planned, jnp.asarray(b),
-                                        interpret=True))
+    got = _planned_kernel(bwk.bw_gemm_sparse, planned, b)
     st = ops.schedule_stats(planned.schedule, planned.mask)
     out["adversarial_high_plane"] = {
         "exact": bool((got == want).all()),
@@ -468,7 +492,7 @@ def kernel_bw_gemm_pipelined():
     import jax.numpy as jnp
     from repro.core import quant
     from repro.engine import QuantSpec, get_engine
-    from repro.kernels import ops
+    from repro.kernels import bw_gemm as bwk, ops
     rng = np.random.default_rng(0)
     m, k, n = 256, 256, 128
     b = rng.integers(-128, 128, size=(k, n)).astype(np.int8)
@@ -482,15 +506,12 @@ def kernel_bw_gemm_pipelined():
         a = np.asarray(qw).astype(np.int8)
         pm = ops.plan_operand(a, block_m=128, block_k=128, order="m_major")
         pk = ops.plan_operand(a, block_m=128, block_k=128, order="k_major")
-        v2 = np.asarray(ops.bw_gemm_sparse_fused(
-            pm, jnp.asarray(b), scale, bias, activation="silu",
-            interpret=True))
-        pipe_m = np.asarray(ops.bw_gemm_sparse_fused_pipelined(
-            pm, jnp.asarray(b), scale, bias, activation="silu",
-            interpret=True))
-        pipe_k = np.asarray(ops.bw_gemm_sparse_fused_pipelined(
-            pk, jnp.asarray(b), scale, bias, activation="silu",
-            interpret=True))
+        v2 = _planned_kernel(bwk.bw_gemm_sparse_fused, pm, b, scale, bias,
+                             activation="silu")
+        pipe_m = _planned_kernel(bwk.bw_gemm_sparse_fused_pipelined, pm, b,
+                                 scale, bias, activation="silu")
+        pipe_k = _planned_kernel(bwk.bw_gemm_sparse_fused_pipelined, pk, b,
+                                 scale, bias, activation="silu")
         spec = QuantSpec(planes=planes, block_m=128, block_k=128)
         # measured (schedule-exact) overlap-aware counters per order
         cost_k = eng.cost(m, k, n, spec, plan=_plan_record(pk))

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.core import quant
 from repro.core.sparsity import avg_num_pps
-from repro.kernels import ops
+from repro.kernels import bw_gemm as bwk, ops
 
 rng = np.random.default_rng(0)
 
@@ -31,9 +31,14 @@ for planes in (4, 3, 2):
     qw, sw = quant.quantize_to_planes(jnp.asarray(w), planes)
     qx, sx = quant.quantize_to_planes(jnp.asarray(x), 4)
     planned = ops.plan_operand(np.asarray(qw), block_m=128, block_k=128)
-    acc = np.asarray(ops.bw_gemm(planned, qx, interpret=True))
+    blocks = dict(block_m=128, block_k=128, interpret=True)
+    # kernel rows come out in the plan's (magnitude-sorted) order
+    acc = np.asarray(bwk.bw_gemm(planned.digits, qx, planned.mask,
+                                 **blocks))[planned.inv_perm]
     # the compacted sparse schedule elides the skipped blocks' DMA too
-    acc_sparse = np.asarray(ops.bw_gemm_sparse(planned, qx, interpret=True))
+    acc_sparse = np.asarray(bwk.bw_gemm_sparse(
+        planned.digits, qx, jnp.asarray(planned.schedule),
+        **blocks))[planned.inv_perm]
     assert (acc_sparse == acc).all()       # bit-identical dispatch
     got = acc.astype(np.float32) * float(sw) * float(sx)
     rel = np.abs(got - want).mean() / np.abs(want).mean()

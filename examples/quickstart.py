@@ -42,13 +42,16 @@ for k, v in hw.efficiency_ratios().items():
 
 # --- 4. Pallas kernel ---------------------------------------------------------
 import jax.numpy as jnp
-from repro.kernels import ops
+from repro.kernels import bw_gemm as bwk, ops
 
 aw = (np.random.default_rng(1).standard_t(4, (256, 256)) * 12) \
     .clip(-128, 127).astype(np.int8)
 bw = np.random.default_rng(2).integers(-128, 128, (256, 128)).astype(np.int8)
-planned = ops.plan_operand(aw)
-out = np.asarray(ops.bw_gemm(planned, jnp.asarray(bw), interpret=True))
+planned = ops.plan_operand(aw)          # rows sorted by high-plane use
+out = np.asarray(bwk.bw_gemm(planned.digits, jnp.asarray(bw), planned.mask,
+                             block_m=planned.block_m,
+                             block_k=planned.block_k, interpret=True))
+out = out[planned.inv_perm]             # back to the operand's row order
 want = (aw.astype(np.int64) @ bw.astype(np.int64)).astype(np.int32)
 print(f"\nbw_gemm kernel exact: {(out == want).all()}  "
       f"MXU passes kept: {float(np.asarray(planned.mask).mean()):.0%}")

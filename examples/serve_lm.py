@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import ARCHS, get_config
+from repro.engine import get_engine
 from repro.models.api import get_api
 from repro.parallel.sharding import unbox
 from repro.train.steps import make_serve_step
@@ -39,11 +40,12 @@ def main():
         print(f"quant spec: {spec}")
     api = get_api(cfg)
     params = unbox(api.init(jax.random.PRNGKey(0), cfg))
-    if spec is not None and spec.impl in ("pallas", "pallas_fused"):
+    gemm = get_engine(spec.impl) if spec is not None else None
+    if gemm is not None and gemm.uses_plans:
         # pre-plan the dense weights so the jit'd serve step runs the
         # Pallas kernel (instead of its int8-dot cost lowering)
         from repro.kernels import ops
-        params, planned = ops.plan_params(params, spec)
+        params, planned = ops.plan_params(params, spec, order=gemm.order)
         print(f"pre-planned {planned} dense weights for the kernel path")
     b = args.batch
     max_len = args.prompt_len + args.tokens + 1

@@ -37,7 +37,7 @@ from .schedule import verify_schedule
 from .dma import check_dma_hazards
 from .vmem import (DEFAULT_VMEM_BUDGET, check_vmem, clamp_suggestion,
                    filter_vmem_configs, vmem_budget, vmem_footprint)
-from .cost import ENGINE_ROUTES, crosscheck_cost, symbolic_counters
+from .cost import crosscheck_cost, symbolic_counters
 from .ckpt import verify_snapshot
 
 __all__ = [
@@ -47,7 +47,7 @@ __all__ = [
     "verify_sharded_plan",
     "DEFAULT_VMEM_BUDGET", "vmem_budget", "vmem_footprint", "check_vmem",
     "clamp_suggestion", "filter_vmem_configs",
-    "ENGINE_ROUTES", "symbolic_counters", "crosscheck_cost",
+    "symbolic_counters", "crosscheck_cost",
     "verify_snapshot",
 ]
 

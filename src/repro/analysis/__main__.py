@@ -135,16 +135,20 @@ def audit_config_registry(report: Report,
 def audit_ci_plans(report: Report) -> None:
     import numpy as np
 
+    from repro.engine import engine_names, get_engine
     from repro.engine.spec import QuantSpec
     from repro.kernels import ops
     from repro.kernels.autotune import CI_SHAPES
 
     spec = QuantSpec(planes=3)
     rng = np.random.default_rng(0)
+    kernel_engines = [get_engine(name) for name in engine_names()
+                      if get_engine(name).uses_plans]
     for m, k, n in CI_SHAPES:
         w = (rng.standard_t(4, size=(k, m)) * 0.02).astype(np.float32)
-        for order, impls in (("m_major", ("pallas_fused", "pallas_sparse")),
-                             ("k_major", ("pallas_pipelined",))):
+        for order in ops.SCHEDULE_ORDERS:
+            # each kernel engine's cost model against the plans it applies
+            impls = [e.name for e in kernel_engines if e.order == order]
             planned, _sw = ops.plan_for(w, spec, order=order)
             sub = Report(f"plan {m}x{k}x{n} {order}")
             verify_plan(planned, spec.radix, order, report=sub)

@@ -21,17 +21,9 @@ import numpy as np
 
 from .diagnostics import Report
 
-__all__ = ["ENGINE_ROUTES", "symbolic_counters", "crosscheck_cost"]
+__all__ = ["symbolic_counters", "crosscheck_cost"]
 
 _WEIGHT, _LAST, _BFETCH = 3, 5, 8
-
-# kernel engine name -> the dispatch route its cost model prices
-ENGINE_ROUTES = {
-    "pallas": "dense",
-    "pallas_fused": "dense",
-    "pallas_sparse": "sparse",
-    "pallas_pipelined": "pipelined",
-}
 
 
 def symbolic_counters(route: str, n: int, *, block_m: int, block_k: int,
@@ -101,16 +93,18 @@ def crosscheck_cost(impl: str, m: int, k: int, n: int, spec, plan, *,
     error naming both values — the cost model may not disagree with the
     schedule it claims to price.
     """
-    from repro.engine.registry import get_engine
+    from repro.engine.registry import engine_names, get_engine
 
     report = report if report is not None else Report(f"cost {impl}")
-    route = ENGINE_ROUTES.get(impl)
+    engine = get_engine(impl)
+    route = engine.route
     if route is None:
+        kernel_engines = [name for name in engine_names()
+                          if get_engine(name).route is not None]
         report.add("COST_MODEL_DRIFT",
                    f"impl {impl!r} has no schedule-backed cost model to "
-                   f"cross-check (kernel engines: {list(ENGINE_ROUTES)})")
+                   f"cross-check (kernel engines: {kernel_engines})")
         return report
-    engine = get_engine(impl)
     got = engine.cost(m, k, n, spec, plan=plan)
     bm, bk, bn, mb, kb, _nb = engine._geometry(m, k, n, spec, plan)
     sched = plan["schedule"] if isinstance(plan, dict) \

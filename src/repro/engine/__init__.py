@@ -14,9 +14,9 @@ per-request impls, autotuning, and multi-backend serving).
 from .spec import (QuantSpec, IMPLS, ACT_QUANT_POLICIES,  # noqa: F401
                    normalize_impl, spec_from_flags)
 from .registry import (GemmEngine, register, get_engine,  # noqa: F401
-                       engine_names, active_planes)
+                       engine_names, engine_for_route, active_planes)
 
 __all__ = ["QuantSpec", "IMPLS", "ACT_QUANT_POLICIES", "normalize_impl",
            "spec_from_flags",
            "GemmEngine", "register", "get_engine", "engine_names",
-           "active_planes"]
+           "engine_for_route", "active_planes"]

@@ -55,7 +55,7 @@ from repro.core import quant as quantlib
 from .spec import IMPLS, QuantSpec
 
 __all__ = ["GemmEngine", "register", "get_engine", "engine_names",
-           "active_planes", "TRACED_INT8_ROUTE"]
+           "engine_for_route", "active_planes", "TRACED_INT8_ROUTE"]
 
 # repro_gemm_dispatch_total route label of a kernel engine called under
 # tracing without a plan record (lowered to one int8 dot, no kernel)
@@ -84,6 +84,16 @@ def get_engine(name: str) -> "GemmEngine":
 
 def engine_names() -> tuple:
     return tuple(_REGISTRY)
+
+
+def engine_for_route(route: str) -> "GemmEngine":
+    """The fused kernel engine whose cost model prices the GEMM ``route``
+    ('dense' | 'sparse' | 'pipelined'): the engine a measurement of that
+    route calibrates."""
+    for engine in _REGISTRY.values():
+        if engine.uses_plans and engine.fused and engine.route == route:
+            return engine
+    raise ValueError(f"no fused kernel engine prices route {route!r}")
 
 
 def active_planes(spec: QuantSpec) -> int:
@@ -186,6 +196,7 @@ class GemmEngine:
 
     name: str = ""
     uses_plans: bool = False      # consumes pre-planned weight records
+    route: Optional[str] = None   # the kernel route its cost model prices
 
     def plan(self, w, spec: QuantSpec):
         """Pre-plan a dense weight [K, N] for repeated application.
@@ -342,6 +353,7 @@ class PallasEngine(GemmEngine):
     name = "pallas"
     uses_plans = True
     fused = False
+    route = "dense"
     dispatch = "dense"           # sparse-schedule routing (pallas_sparse)
     order = "m_major"            # schedule visit order the plans carry
 
@@ -461,6 +473,7 @@ class PallasSparseEngine(PallasFusedEngine):
     one step."""
 
     name = "pallas_sparse"
+    route = "sparse"
     dispatch = "auto"
 
     @staticmethod
@@ -535,6 +548,7 @@ class PallasPipelinedEngine(PallasSparseEngine):
     """
 
     name = "pallas_pipelined"
+    route = "pipelined"
     order = "k_major"
 
     def _cost1(self, m, k, n, spec, *, density=None, plan=None):

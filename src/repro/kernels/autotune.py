@@ -96,12 +96,6 @@ _M_VMEM_REJECTED = obs_metrics.get_registry().counter(
 _M_LOAD_ERRORS = obs_metrics.get_registry().counter(
     "repro_autotune_cache_load_errors_total")
 
-# dispatch route -> the GemmEngine impl whose cost model prices it (the
-# calibration pairing key)
-ROUTE_IMPLS = {"dense": "pallas_fused", "sparse": "pallas_sparse",
-               "pipelined": "pallas_pipelined"}
-
-
 def current_backend() -> str:
     """The measuring-backend tag for this process.
 
@@ -424,11 +418,8 @@ def autotune_gemm(m: int, k: int, n: int, spec=None, a=None, b=None, *,
     b = jnp.asarray(b, jnp.int8)
     encoding = spec.encoding if spec is not None else "ent"
     bits = spec.bits if spec is not None else 8
-    scale = np.ones((m,), np.float32)
-
-    runners = {"dense": ops.bw_gemm_fused,
-               "sparse": ops.bw_gemm_sparse_fused,
-               "pipelined": ops.bw_gemm_sparse_fused_pipelined}
+    x = b.T.astype(jnp.float32)          # the tokens the sweep multiplies
+    sw = jnp.ones((m,), jnp.float32)
     # hard VMEM gate: candidates whose resident footprint cannot fit a TPU
     # core are never measured (interpret mode would happily "win" with a
     # config that OOMs on hardware); the filter never empties the pool
@@ -443,7 +434,7 @@ def autotune_gemm(m: int, k: int, n: int, spec=None, a=None, b=None, *,
     # key — the CostCalibrator turns these into per-impl drift ratios
     calibrator = get_calibrator()
     cal_spec = spec if spec is not None else QuantSpec(planes=3)
-    from repro.engine import get_engine
+    from repro.engine import engine_for_route
     sweep_sp = obs_trace.span("autotune.sweep", m=m, k=k, n=n,
                               candidates=len(candidates),
                               vmem_rejected=len(all_configs)
@@ -455,11 +446,13 @@ def autotune_gemm(m: int, k: int, n: int, spec=None, a=None, b=None, *,
                                        block_m=config["block_m"],
                                        block_k=config["block_k"],
                                        bits=bits, order=config["order"])
-            run = runners[config["dispatch"]]
 
-            def fn(planned=planned, run=run, bn=config["block_n"]):
-                return run(planned, b, scale, block_n=bn,
-                           interpret=interpret)
+            # the candidate through the served apply path, its route forced
+            def fn(plan=ops._plan_record(planned, sw), config=config):
+                return ops.planned_dense_apply(
+                    plan, x, cal_spec, m, block_n=config["block_n"],
+                    interpret=interpret, dispatch=config["dispatch"],
+                    order=config["order"], verify=False)
 
             with obs_trace.span("autotune.measure", **config):
                 secs = _measure(fn, iters=iters)
@@ -470,10 +463,11 @@ def autotune_gemm(m: int, k: int, n: int, spec=None, a=None, b=None, *,
             # metrics would scatter them across buckets
             proxy = planned.schedule.shape[0] / max(planned.mask.size, 1)
             results.append((secs, config, proxy))
-            impl = ROUTE_IMPLS[config["dispatch"]]
+            engine = engine_for_route(config["dispatch"])
+            impl = engine.name
             # serving orientation: tokens on M, output channels on N —
             # the transpose of this sweep's (m=rows, n=tokens)
-            predicted = get_engine(impl).predict_seconds(
+            predicted = engine.predict_seconds(
                 n, k, m, cal_spec, plan=planned)
             if predicted > 0 and secs > 0:
                 calibrator.record(impl, predicted, secs, shape=(m, k, n),

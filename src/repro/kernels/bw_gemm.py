@@ -100,7 +100,8 @@ def _check_dims(fn: str, m: int, k: int, k2: int, n: int, block_m: int,
         if dim % blk:
             raise ValueError(
                 f"{fn}: {name}={dim} is not a multiple of {bname}={blk}; "
-                f"pad the operands first (the ops.* wrappers do this)")
+                f"pad the operands first (ops.planned_dense_apply does "
+                f"this)")
 
 
 def _check_mask(fn: str, mask, bw_n: int, mb: int, kb: int) -> None:
@@ -262,45 +263,32 @@ def _fused_kernel(mask_ref, d_ref, b_ref, scale_ref, scale_n_ref, bias_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "block_m", "block_n", "block_k", "radix", "interpret", "activation",
-    "epilogue_axis", "out_dtype"))
+    "out_dtype"))
 def bw_gemm_fused(digits, b, mask, scale, bias=None, scale_n=None, *,
                   block_m: int = 128, block_n: int = 128, block_k: int = 256,
                   radix: int = 4, interpret: bool = False, activation=None,
-                  epilogue_axis: str = "m", out_dtype=jnp.float32):
+                  out_dtype=jnp.float32):
     """C = act((sum_bw (digits[bw] @ B) * radix**bw) * scales + bias).
 
     digits: int8 [BW, M, K] encoded planes of the multiplicand.
     b:      int8 [K, N].
     mask:   bool [BW, M//block_m, K//block_k] plane-block occupancy.
-    scale:  f32 [M, 1] (epilogue_axis='m', per-row: weight channels on M as
-            in the planned-weight layout) or [1, N] (epilogue_axis='n').
-    bias:   optional f32, same shape rules as scale.
-    scale_n: optional second scale vector on the *other* axis -- [1, N] when
-            epilogue_axis='m'.  This is how per-token activation scales
-            reach the fused epilogue: the planned-weight layout puts tokens
-            on the kernel N axis, so a per-token act scale is a per-column
-            vector multiplied into the per-channel row scale in-kernel.
+    scale:  f32 [M, 1] per-row scale: weight channels on M, as in the
+            planned-weight layout.
+    bias:   optional f32 [M, 1].
+    scale_n: optional f32 [1, N] per-column scale.  This is how per-token
+            activation scales reach the fused epilogue: the planned-weight
+            layout puts tokens on the kernel N axis, so a per-token act
+            scale is a per-column vector multiplied into the per-channel
+            row scale in-kernel.
     """
     bw_n, m, k = digits.shape
     k2, n = b.shape
     _check_dims("bw_gemm_fused", m, k, k2, n, block_m, block_n, block_k)
     _check_mask("bw_gemm_fused", mask, bw_n, m // block_m, k // block_k)
-    if epilogue_axis not in ("m", "n"):
-        raise ValueError(f"bw_gemm_fused: epilogue_axis must be 'm' or "
-                         f"'n', got {epilogue_axis!r}")
-    if epilogue_axis == "m":
-        _check_epilogue("bw_gemm_fused", activation, scale, (m, 1),
-                        scale_n, n)
-        vec_spec = pl.BlockSpec((block_m, 1), lambda i, j, kk, msk: (i, 0))
-        col_spec = pl.BlockSpec((1, block_n), lambda i, j, kk, msk: (0, j))
-    else:
-        if scale_n is not None:
-            raise ValueError("bw_gemm_fused: scale_n only supports "
-                             "epilogue_axis='m'")
-        _check_epilogue("bw_gemm_fused", activation, scale, (1, n),
-                        scale_n, n)
-        vec_spec = pl.BlockSpec((1, block_n), lambda i, j, kk, msk: (0, j))
-        col_spec = vec_spec
+    _check_epilogue("bw_gemm_fused", activation, scale, (m, 1), scale_n, n)
+    vec_spec = pl.BlockSpec((block_m, 1), lambda i, j, kk, msk: (i, 0))
+    col_spec = pl.BlockSpec((1, block_n), lambda i, j, kk, msk: (0, j))
     has_scale_n = scale_n is not None
     if not has_scale_n:                 # placeholder so arity is static
         scale_n = jnp.ones((1, n), jnp.float32)
@@ -568,8 +556,8 @@ def bw_gemm_sparse_fused(digits, b, schedule, scale, bias=None, scale_n=None,
                          activation=None, out_dtype=jnp.float32):
     """Sparse-schedule bw_gemm with the fused dequant epilogue.
 
-    Arguments mirror bw_gemm_fused with epilogue_axis='m' (the planned-
-    weight layout: weight channels on the kernel M axis, tokens on N), but
+    Arguments mirror bw_gemm_fused (the planned-weight layout: weight
+    channels on the kernel M axis, tokens on N), but
     the occupancy mask is replaced by the compacted schedule and the plane
     loop by one scheduled (plane, m-block, k-block) step per grid
     iteration.

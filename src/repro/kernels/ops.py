@@ -1,6 +1,8 @@
-"""Jitted public wrappers around the Pallas kernels: padding, plane
-encoding, occupancy masks, weight planning (magnitude-ordered row
-permutation) and the quantised-linear entry point used by the models.
+"""The kernels layer's entry points: plane encoding, occupancy masks,
+schedules, weight planning (magnitude-ordered row permutation) and the
+one apply path from a plan record to a digit-plane kernel
+(``planned_dense_apply``, ``planned_experts_apply``), whose activation
+side, kernel call and unfused epilogue ``parallel.apply`` shares.
 
 Every spec-level entry point (``plan_for`` / ``plan_dense_weight`` /
 ``planned_dense_apply`` / ``quantized_dense`` / ``plan_params`` /
@@ -13,15 +15,15 @@ default-grid spec.  The per-parameter plan cache keys on (weight,
 spec.plan_key()), so the same weight planned under two specs holds two
 independent entries.
 
-On non-TPU backends the wrappers run the kernels in interpret mode (the
+On non-TPU backends the entry points run the kernels in interpret mode (the
 kernel body executes in Python on CPU) so every code path is testable here;
 on TPU the same calls compile to MXU programs.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import hashlib
+import math
 import os
 import weakref
 from typing import Optional
@@ -56,15 +58,15 @@ _M_DISPATCH = obs_metrics.get_registry().counter(
     "repro_gemm_dispatch_total")
 
 __all__ = ["PlannedOperand", "encode_planes", "plane_block_mask",
-           "plan_operand", "bw_gemm", "quant_gemm", "plane_density",
-           "select_block_sizes", "bw_gemm_fused", "quant_gemm_fused",
+           "plan_operand", "quant_gemm", "plane_density",
+           "select_block_sizes", "quant_gemm_fused",
            "plan_for", "plan_cache_stats", "plan_cache_clear",
            "quantized_dense", "plan_dense_weight", "planned_dense_apply",
+           "planned_experts_apply", "resolve_route",
            "plan_params", "build_schedule", "pad_schedule",
-           "schedule_stats", "bw_gemm_sparse", "bw_gemm_sparse_fused",
-           "bw_gemm_sparse_pipelined", "bw_gemm_sparse_fused_pipelined",
-           "SPARSE_DENSITY_THRESHOLD", "SCHEDULE_ORDERS", "DISPATCHES",
-           "verification_enabled", "ENV_VERIFY", "count_dispatch"]
+           "schedule_stats", "SPARSE_DENSITY_THRESHOLD", "SCHEDULE_ORDERS",
+           "DISPATCHES", "verification_enabled", "ENV_VERIFY",
+           "count_dispatch"]
 
 
 def _interpret() -> bool:
@@ -146,27 +148,12 @@ _encode_planes_jit = jax.jit(kref.encode_planes_ref,
                              static_argnames=("encoding", "bits"))
 
 
-def _check_operand_k(k: int, planned_k: int) -> None:
-    """Real validation (asserts vanish under ``python -O``)."""
-    if k != planned_k:
-        raise ValueError(
-            f"b has K={k} rows but the planned operand was built with "
-            f"K={planned_k}; re-plan the weight or fix the activation "
-            f"reshape")
-
-
 def _check_gemm_k(k: int, k2: int) -> None:
+    """Real validation (asserts vanish under ``python -O``)."""
     if k != k2:
         raise ValueError(
             f"inner-dim mismatch: a has K={k} columns but b has K={k2} "
             f"rows")
-
-
-def _check_has_schedule(planned: "PlannedOperand") -> None:
-    if planned.schedule is None:
-        raise ValueError(
-            "plan has no schedule; build it with plan_operand / "
-            "build_schedule before calling a sparse kernel")
 
 
 # ---------------------------------------------------------------------------
@@ -507,150 +494,6 @@ def plan_operand(a_int8, encoding: str = "ent", block_m: int = 128,
                           block_m, block_k, encoding, schedule, order)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret",
-                                             "block_m", "block_k", "radix"))
-def _bw_gemm_padded(planned_digits, mask, b, inv_perm, *, block_n,
-                    interpret, block_m, block_k, radix):
-    out = _bw.bw_gemm(planned_digits, b, mask, block_m=block_m,
-                      block_n=block_n, block_k=block_k, radix=radix,
-                      interpret=interpret)
-    return out[inv_perm]
-
-
-def bw_gemm(planned: PlannedOperand, b, *, block_n: int = 128,
-            interpret: Optional[bool] = None):
-    """C = A @ B with A pre-planned.  b: int8 [K, N] -> int32 [M, N]."""
-    if interpret is None:
-        interpret = _interpret()
-    k, n = b.shape
-    _check_operand_k(k, planned.k)
-    b = _pad_to(_pad_to(jnp.asarray(b, jnp.int8), planned.block_k, 0),
-                block_n, 1)
-    out = _bw_gemm_padded(
-        planned.digits, planned.mask, b, jnp.asarray(planned.inv_perm),
-        block_n=block_n, interpret=bool(interpret),
-        block_m=planned.block_m, block_k=planned.block_k,
-        radix=enc.radix(planned.encoding))
-    return out[:planned.m, :n]
-
-
-def bw_gemm_sparse(planned: PlannedOperand, b, *, block_n: int = 128,
-                   interpret: Optional[bool] = None):
-    """C = A @ B through the compacted-schedule kernel (scalar prefetch).
-
-    Bit-identical to bw_gemm on the same plan; an all-zero plane-block
-    costs neither a DMA nor a grid step.  b: int8 [K, N] -> int32 [M, N].
-    """
-    if interpret is None:
-        interpret = _interpret()
-    k, n = b.shape
-    _check_operand_k(k, planned.k)
-    _check_has_schedule(planned)
-    # the v2 out-BlockSpec accumulates only across *consecutive* revisits;
-    # a k_major plan would silently clobber partial sums on real TPUs
-    # (interpret mode hides it), so refuse it here, not just in dispatch
-    if planned.order != "m_major":
-        raise ValueError(
-            f"bw_gemm_sparse requires an m_major plan, got "
-            f"{planned.order!r} (use bw_gemm_sparse_pipelined)")
-    b = _pad_to(_pad_to(jnp.asarray(b, jnp.int8), planned.block_k, 0),
-                block_n, 1)
-    out = _bw.bw_gemm_sparse(
-        planned.digits, b, jnp.asarray(planned.schedule),
-        block_m=planned.block_m, block_n=block_n, block_k=planned.block_k,
-        interpret=bool(interpret))
-    return out[jnp.asarray(planned.inv_perm)][:planned.m, :n]
-
-
-def bw_gemm_sparse_fused(planned: PlannedOperand, b, scale, bias=None, *,
-                         activation=None, block_n: int = 128,
-                         out_dtype=jnp.float32,
-                         interpret: Optional[bool] = None):
-    """bw_gemm_fused through the compacted-schedule kernel.
-
-    Same contract as bw_gemm_fused: scale/bias are per-row vectors of
-    length M in the operand's original row order.
-    """
-    if interpret is None:
-        interpret = _interpret()
-    k, n = b.shape
-    _check_operand_k(k, planned.k)
-    _check_has_schedule(planned)
-    # see bw_gemm_sparse: v2 accumulation is only legal on m_major plans
-    if planned.order != "m_major":
-        raise ValueError(
-            f"bw_gemm_sparse_fused requires an m_major plan, got "
-            f"{planned.order!r} (use bw_gemm_sparse_fused_pipelined)")
-    m_pad = planned.digits.shape[1]
-    row_perm = jnp.asarray(planned.row_perm)
-    scale_rows = _channel_rows(scale, planned.m, m_pad, row_perm)
-    bias_rows = None
-    if bias is not None:
-        bias_rows = _channel_rows(bias, planned.m, m_pad, row_perm)
-    b = _pad_to(_pad_to(jnp.asarray(b, jnp.int8), planned.block_k, 0),
-                block_n, 1)
-    out = _bw.bw_gemm_sparse_fused(
-        planned.digits, b, jnp.asarray(planned.schedule), scale_rows,
-        bias_rows, block_m=planned.block_m, block_n=block_n,
-        block_k=planned.block_k, interpret=bool(interpret),
-        activation=activation, out_dtype=out_dtype)
-    return out[jnp.asarray(planned.inv_perm)][:planned.m, :n]
-
-
-def bw_gemm_sparse_pipelined(planned: PlannedOperand, b, *,
-                             block_n: int = 128,
-                             interpret: Optional[bool] = None):
-    """C = A @ B through the double-buffered pipelined kernel.
-
-    Bit-identical to bw_gemm_sparse on the same plan in either schedule
-    order; step s+1's plane gather overlaps step s's MXU pass and
-    consecutive same-k steps reuse the resident B block without a DMA.
-    """
-    if interpret is None:
-        interpret = _interpret()
-    k, n = b.shape
-    _check_operand_k(k, planned.k)
-    _check_has_schedule(planned)
-    b = _pad_to(_pad_to(jnp.asarray(b, jnp.int8), planned.block_k, 0),
-                block_n, 1)
-    out = _bw.bw_gemm_sparse_pipelined(
-        planned.digits, b, jnp.asarray(planned.schedule),
-        block_m=planned.block_m, block_n=block_n, block_k=planned.block_k,
-        interpret=bool(interpret))
-    return out[jnp.asarray(planned.inv_perm)][:planned.m, :n]
-
-
-def bw_gemm_sparse_fused_pipelined(planned: PlannedOperand, b, scale,
-                                   bias=None, *, activation=None,
-                                   block_n: int = 128,
-                                   out_dtype=jnp.float32,
-                                   interpret: Optional[bool] = None):
-    """bw_gemm_sparse_fused through the double-buffered pipelined kernel.
-
-    Same contract as bw_gemm_fused: scale/bias are per-row vectors of
-    length M in the operand's original row order.
-    """
-    if interpret is None:
-        interpret = _interpret()
-    k, n = b.shape
-    _check_operand_k(k, planned.k)
-    _check_has_schedule(planned)
-    m_pad = planned.digits.shape[1]
-    row_perm = jnp.asarray(planned.row_perm)
-    scale_rows = _channel_rows(scale, planned.m, m_pad, row_perm)
-    bias_rows = None
-    if bias is not None:
-        bias_rows = _channel_rows(bias, planned.m, m_pad, row_perm)
-    b = _pad_to(_pad_to(jnp.asarray(b, jnp.int8), planned.block_k, 0),
-                block_n, 1)
-    out = _bw.bw_gemm_sparse_fused_pipelined(
-        planned.digits, b, jnp.asarray(planned.schedule), scale_rows,
-        bias_rows, block_m=planned.block_m, block_n=block_n,
-        block_k=planned.block_k, interpret=bool(interpret),
-        activation=activation, out_dtype=out_dtype)
-    return out[jnp.asarray(planned.inv_perm)][:planned.m, :n]
-
-
 def quant_gemm(a, b, *, block_m: int = 128, block_n: int = 128,
                block_k: int = 256, interpret: Optional[bool] = None):
     """Baseline int8 GEMM (pads to block multiples, slices back)."""
@@ -664,35 +507,6 @@ def quant_gemm(a, b, *, block_m: int = 128, block_n: int = 128,
     out = _qg.quant_gemm(a, b, block_m=block_m, block_n=block_n,
                          block_k=block_k, interpret=bool(interpret))
     return out[:m, :n]
-
-
-def bw_gemm_fused(planned: PlannedOperand, b, scale, bias=None, *,
-                  activation=None, block_n: int = 128,
-                  out_dtype=jnp.float32, interpret: Optional[bool] = None):
-    """C = act((A @ B)_int * scale + bias) with A pre-planned.
-
-    b: int8 [K, N].  scale/bias: per-row vectors of length M (the planned
-    operand's original row order -- permutation into planned order and the
-    padding are handled here).  Returns float [M, N].
-    """
-    if interpret is None:
-        interpret = _interpret()
-    k, n = b.shape
-    _check_operand_k(k, planned.k)
-    m_pad = planned.digits.shape[1]
-    row_perm = jnp.asarray(planned.row_perm)
-    scale_rows = _channel_rows(scale, planned.m, m_pad, row_perm)
-    bias_rows = None
-    if bias is not None:
-        bias_rows = _channel_rows(bias, planned.m, m_pad, row_perm)
-    b = _pad_to(_pad_to(jnp.asarray(b, jnp.int8), planned.block_k, 0),
-                block_n, 1)
-    out = _bw.bw_gemm_fused(
-        planned.digits, b, planned.mask, scale_rows, bias_rows,
-        block_m=planned.block_m, block_n=block_n, block_k=planned.block_k,
-        radix=enc.radix(planned.encoding), interpret=bool(interpret),
-        activation=activation, epilogue_axis="m", out_dtype=out_dtype)
-    return out[jnp.asarray(planned.inv_perm)][:planned.m, :n]
 
 
 def quant_gemm_fused(a, b, scale, bias=None, *, activation=None,
@@ -889,73 +703,95 @@ def plan_dense_weight(w, spec, use_cache: bool = True,
         if _verify_enabled(verify):
             _verify_planned(planned)
         sw = jnp.asarray(sw, jnp.float32)
-    n = w.shape[1]
+    return _plan_record(planned, sw)
+
+
+def _plan_record(planned: PlannedOperand, sw) -> dict:
+    """The plan record of a PlannedOperand whose M rows carry the
+    per-channel weight scales ``sw`` ([M] or [1, M])."""
     m_pad = planned.digits.shape[1]
     row_perm = jnp.asarray(planned.row_perm)
     return {
         "digits": planned.digits,                     # int8 [BW, M_pad, K_pad]
         "mask": planned.mask,                         # bool [BW, M/bm, K/bk]
-        "schedule": jnp.asarray(planned.schedule),    # int32 [L, 6]
+        "schedule": jnp.asarray(planned.schedule),    # int32 [L, 9]
         "row_perm": row_perm,                         # int32 [M_pad]
         "inv_perm": jnp.asarray(planned.inv_perm),    # int32 [M_pad]
-        "sw_rows": _channel_rows(sw.reshape(-1), n, m_pad, row_perm),
+        "sw_rows": _channel_rows(jnp.reshape(sw, -1), planned.m, m_pad,
+                                 row_perm),
     }
 
 
-def _resolve_dispatch(dispatch: str, plan: dict, spec, n_out: int, k: int,
-                      batch: int, order: str) -> str:
-    """Resolve to a concrete kernel route: 'dense'|'sparse'|'pipelined'.
+def resolve_route(dispatch: str, order: str, density: float) -> str:
+    """The GEMM route of a plan: 'dense' | 'sparse' | 'pipelined'.
 
-    The decision is *static* (shape-derived, jit/scan-safe): the schedule
-    length L counts nnz blocks + per-empty-row sentinels (+ stack padding),
-    so L / mask.size is a sound density proxy.  'auto' consults the
-    measured autotune cache for a per-(shape, density-bucket) winner and
-    falls back to the SPARSE_DENSITY_THRESHOLD heuristic on a miss —
-    sparse routes become 'sparse' (the v2 scalar-prefetch kernels) for
-    m_major schedules and 'pipelined' for k_major ones, whose
-    non-consecutive output revisits only the pipelined kernels support.
+    The one routing rule of the single-device and the sharded apply.
+    dispatch: an explicit route, which is honoured, or 'auto'.  order:
+    the plan's schedule visit order (SCHEDULE_ORDERS); the v2 'sparse'
+    kernels need an m_major schedule, because a k_major one revisits
+    output blocks non-consecutively.  density: the plan's plane-block
+    density; 'auto' takes the plan's sparse route ('sparse' on m_major,
+    'pipelined' on k_major) at SPARSE_DENSITY_THRESHOLD or below, and
+    'dense' above it.  Static (shape-derived), so jit/scan-safe.
     """
     if order not in SCHEDULE_ORDERS:
         raise ValueError(f"order must be one of {SCHEDULE_ORDERS}, "
                          f"got {order!r}")
-    if dispatch == "dense" or plan.get("schedule") is None:
-        return "dense"
-    if dispatch == "sparse":
-        if order == "k_major":
-            raise ValueError(
-                "dispatch='sparse' (the v2 kernels) requires an m_major "
-                "schedule: k_major revisits output blocks non-consecutively"
-                " — use dispatch='pipelined' (or 'auto')")
-        return "sparse"
-    if dispatch == "pipelined":
-        return "pipelined"
-    if dispatch != "auto":
+    if dispatch not in DISPATCHES:
         raise ValueError(f"dispatch must be one of {DISPATCHES}, "
                          f"got {dispatch!r}")
-    sparse_route = "pipelined" if order == "k_major" else "sparse"
-    density = plan["schedule"].shape[0] / max(plan["mask"].size, 1)
+    if dispatch == "sparse" and order == "k_major":
+        raise ValueError(
+            "dispatch='sparse' (the v2 kernels) requires an m_major "
+            "schedule: k_major revisits output blocks non-consecutively"
+            " — use dispatch='pipelined' (or 'auto')")
+    if dispatch != "auto":
+        return dispatch
+    if density > SPARSE_DENSITY_THRESHOLD:
+        return "dense"
+    return "pipelined" if order == "k_major" else "sparse"
+
+
+def _autotuned_route(spec, n_out: int, k: int, batch: int, order: str,
+                     density: float) -> Optional[str]:
+    """The route a measured autotune winner gives an 'auto' dispatch for
+    this (shape, density bucket), or None where the cache has none that
+    transfers to a plan of this order."""
     from . import autotune
     hit = autotune.get_cache().lookup(n_out, k, batch, spec, density=density)
-    if hit is not None and hit.get("dispatch") in ("sparse", "dense",
-                                                   "pipelined"):
-        won = hit["dispatch"]
-        if won == "dense":
-            return "dense"
-        # a measured sparse-route winner only transfers when it was
-        # measured under *this plan's* schedule order (a k_major-measured
-        # pipelined win says nothing about an m_major schedule's walk);
-        # pre-tag entries (order absent) are trusted as order-agnostic
-        if hit.get("order") in (None, order):
-            if won == "pipelined":
-                return "pipelined"
-            if order == "m_major":                    # won == "sparse"
-                return "sparse"
-        elif won in ("sparse", "pipelined") and order == "k_major":
-            # a sparse-route win that cannot run v2 on this plan: the
-            # nearest legal sparse route is still measured-informed
+    won = hit.get("dispatch") if hit is not None else None
+    if won == "dense":
+        return "dense"
+    if won not in ("sparse", "pipelined"):
+        return None
+    # a measured sparse-route winner only transfers when it was measured
+    # under *this plan's* schedule order (a k_major-measured pipelined win
+    # says nothing about an m_major schedule's walk); pre-tag entries
+    # (order absent) are trusted as order-agnostic
+    if hit.get("order") in (None, order):
+        if won == "pipelined":
             return "pipelined"
-        # otherwise the ranking does not transfer: fall through
-    return sparse_route if density <= SPARSE_DENSITY_THRESHOLD else "dense"
+        return "sparse" if order == "m_major" else None
+    # a sparse-route win that cannot run v2 on this plan: the nearest
+    # legal sparse route is still measured-informed
+    return "pipelined" if order == "k_major" else None
+
+
+def _resolve_dispatch(dispatch: str, plan: dict, spec, n_out: int, k: int,
+                      batch: int, order: str) -> str:
+    """The route planned_dense_apply takes: ``resolve_route`` on the
+    plan's density proxy, where 'auto' defers to a measured autotune
+    winner first.  The proxy is the schedule length L (nnz blocks +
+    per-empty-row sentinels + stack padding) over mask.size, which the
+    shapes give, so the choice stays static under jit/scan."""
+    if plan.get("schedule") is None:
+        return resolve_route("dense", order, 1.0)
+    density = plan["schedule"].shape[0] / max(plan["mask"].size, 1)
+    route = resolve_route(dispatch, order, density)
+    if dispatch == "auto":
+        route = _autotuned_route(spec, n_out, k, batch, order,
+                                 density) or route
+    return route
 
 
 def _maybe_verify_plan(plan: dict, spec, order: str,
@@ -979,6 +815,93 @@ def _maybe_verify_plan(plan: dict, spec, order: str,
         {"schedule": sched, "mask": mask}, spec.radix,
         order).raise_if_errors()
     _mark_schedule_verified(sched)
+
+
+def _check_planes(what: str, bw_n: int, spec: QuantSpec) -> None:
+    if bw_n != spec.num_digits:
+        raise ValueError(
+            f"{what} has {bw_n} digit planes but spec "
+            f"{spec.encoding!r}/{spec.bits}b implies {spec.num_digits}; "
+            f"was the plan built under a different spec?")
+
+
+# ---------------------------------------------------------------------------
+# The apply core: every planned GEMM (planned_dense_apply,
+# planned_experts_apply, parallel.apply.sharded_planned_apply) takes its
+# activations, its kernel and its unfused epilogue from here
+# ---------------------------------------------------------------------------
+
+def _activations(x, spec: QuantSpec, n_out: int, k_pad: int,
+                 block_n: Optional[int], groups: int = 0):
+    """The B operand of a planned GEMM: x [*G, ..., K] quantized under
+    ``spec.act_quant``, its tokens on the kernel N axis.
+
+    groups: leading axes G kept apart (an expert's tokens); the rest of
+    x's leading axes flatten into the tokens.  Returns (bt int8
+    [*G, K_pad, N_pad] zero-padded, sx the activation scales, sx_cols
+    the per-token scales as a [*G, 1, N_pad] row for the kernel epilogue
+    (None per tensor), block_n: the given one, else the block-size
+    rule's for this GEMM)."""
+    k = x.shape[-1]
+    per_token = spec.act_quant == "per_token"
+    qx, sx = quantlib.quantize_for_spec(
+        jnp.asarray(x).astype(jnp.float32), spec,
+        axis=-1 if per_token else None)
+    x2 = qx.reshape(*x.shape[:groups], -1, k)
+    if block_n is None:
+        block_n = select_block_sizes(n_out, k, x2.shape[-2], spec)[2]
+    bt = _pad_to(_pad_to(jnp.swapaxes(x2, -1, -2), k_pad, -2), block_n, -1)
+    sx_cols = None
+    if per_token:                        # one scale per token: kernel N
+        cols = jnp.swapaxes(sx, -1, -2) if groups else sx.reshape(1, -1)
+        sx_cols = _pad_to(cols, block_n, -1)
+    return bt, sx, sx_cols, block_n
+
+
+def _gemm(route: str, fused: bool, digits, b, mask, schedule, *,
+          block_m: int, block_n: int, block_k: int, radix: int,
+          interpret: bool, scale=None, bias=None, scale_n=None,
+          activation=None):
+    """The digit-plane kernel of ``route`` (resolve_route) on padded
+    operands: the dense kernels read the occupancy ``mask``, the sparse
+    and pipelined ones the ``schedule``.  Unfused: the int32 accumulator
+    [M_pad, N_pad] in planned row order.  Fused: act(acc * scale *
+    scale_n + bias) in float32, with scale/bias [M_pad, 1] in planned row
+    order and scale_n the [1, N_pad] per-token row or None."""
+    blocks = dict(block_m=block_m, block_n=block_n, block_k=block_k,
+                  interpret=interpret)
+    if not fused:
+        if route == "pipelined":
+            return _bw.bw_gemm_sparse_pipelined(digits, b, schedule,
+                                                **blocks)
+        if route == "sparse":
+            return _bw.bw_gemm_sparse(digits, b, schedule, **blocks)
+        return _bw.bw_gemm(digits, b, mask, radix=radix, **blocks)
+    epilogue = dict(activation=activation, out_dtype=jnp.float32)
+    if route == "pipelined":
+        return _bw.bw_gemm_sparse_fused_pipelined(
+            digits, b, schedule, scale, bias, scale_n, **blocks, **epilogue)
+    if route == "sparse":
+        return _bw.bw_gemm_sparse_fused(
+            digits, b, schedule, scale, bias, scale_n, **blocks, **epilogue)
+    return _bw.bw_gemm_fused(digits, b, mask, scale, bias, scale_n,
+                             radix=radix, **blocks, **epilogue)
+
+
+def _unfused_epilogue(acc, plan: dict, sx, spec: QuantSpec, n_out: int,
+                      batch: int, bias, activation):
+    """act((acc * s_w * s_x) + bias) [batch, n_out] from an unfused
+    kernel's int32 accumulator in planned row order."""
+    acc = acc[plan["inv_perm"]][:n_out, :batch]
+    sw = plan["sw_rows"][plan["inv_perm"]][:n_out]     # original order
+    per_token = spec.act_quant == "per_token"
+    s = sw * (sx.reshape(1, -1) if per_token else sx)
+    y = (acc.astype(jnp.float32) * s).T
+    if bias is not None:
+        y = y + jnp.asarray(bias, jnp.float32)
+    if activation is not None:
+        y = _bw.EPILOGUE_ACTIVATIONS[activation](y)
+    return y
 
 
 def planned_dense_apply(plan: dict, x, spec, n_out: int, *, bias=None,
@@ -1024,31 +947,15 @@ def planned_dense_apply(plan: dict, x, spec, n_out: int, *, bias=None,
         interpret = _interpret()
     digits, mask = plan["digits"], plan["mask"]
     bw_n, m_pad, k_pad = digits.shape
-    if bw_n != spec.num_digits:
-        raise ValueError(
-            f"plan record has {bw_n} digit planes but spec "
-            f"{spec.encoding!r}/{spec.bits}b implies {spec.num_digits}; "
-            f"was the plan built under a different spec?")
+    _check_planes("plan record", bw_n, spec)
     # verify only after the spec/plan compatibility check: a plan applied
     # under a foreign spec should fail with the specific message above,
     # not with the verifier's radix-mismatch diagnostics
     _maybe_verify_plan(plan, spec, order, verify)
-    block_m = m_pad // mask.shape[1]
-    block_k = k_pad // mask.shape[2]
     k = x.shape[-1]
     lead = x.shape[:-1]
-    per_token = spec.act_quant == "per_token"
-    qx, sx = quantlib.quantize_for_spec(
-        jnp.asarray(x).astype(jnp.float32), spec,
-        axis=-1 if per_token else None)
-    x2 = qx.reshape(-1, k)
-    batch = x2.shape[0]
-    if block_n is None:
-        block_n = select_block_sizes(n_out, k, batch, spec)[2]
-    bt = _pad_to(_pad_to(x2.T, block_k, 0), block_n, 1)
-    sx_cols = None
-    if per_token:                        # one scale per activation row ->
-        sx_cols = _pad_to(sx.reshape(1, -1), block_n, 1)  # kernel N axis
+    batch = math.prod(lead)
+    bt, sx, sx_cols, block_n = _activations(x, spec, n_out, k_pad, block_n)
     route = _resolve_dispatch(dispatch, plan, spec, n_out, k, batch, order)
     # chaos seam: one branch when no plan is armed; fires only on eager
     # (or trace-time) calls — a jit'd serve step never re-enters here
@@ -1063,58 +970,26 @@ def planned_dense_apply(plan: dict, x, spec, n_out: int, *, bias=None,
                             m=int(n_out), k=int(k), n=int(batch))
     else:
         sp = obs_trace.NULL_SPAN
+    blocks = dict(block_m=m_pad // mask.shape[1], block_n=block_n,
+                  block_k=k_pad // mask.shape[2], radix=spec.radix,
+                  interpret=bool(interpret))
     with sp:
         if fused:
-            scale_rows = plan["sw_rows"] if per_token \
+            scale_rows = plan["sw_rows"] if sx_cols is not None \
                 else plan["sw_rows"] * sx
             bias_rows = None
             if bias is not None:
                 bias_rows = _channel_rows(bias, n_out, m_pad,
                                           plan["row_perm"])
-            if route == "pipelined":
-                out = _bw.bw_gemm_sparse_fused_pipelined(
-                    digits, bt, plan["schedule"], scale_rows, bias_rows,
-                    sx_cols, block_m=block_m, block_n=block_n,
-                    block_k=block_k, interpret=bool(interpret),
-                    activation=activation, out_dtype=jnp.float32)
-            elif route == "sparse":
-                out = _bw.bw_gemm_sparse_fused(
-                    digits, bt, plan["schedule"], scale_rows, bias_rows,
-                    sx_cols, block_m=block_m, block_n=block_n,
-                    block_k=block_k, interpret=bool(interpret),
-                    activation=activation, out_dtype=jnp.float32)
-            else:
-                out = _bw.bw_gemm_fused(
-                    digits, bt, mask, scale_rows, bias_rows, sx_cols,
-                    block_m=block_m, block_n=block_n, block_k=block_k,
-                    radix=spec.radix, interpret=bool(interpret),
-                    activation=activation, epilogue_axis="m",
-                    out_dtype=jnp.float32)
+            out = _gemm(route, True, digits, bt, mask, plan.get("schedule"),
+                        scale=scale_rows, bias=bias_rows, scale_n=sx_cols,
+                        activation=activation, **blocks)
             y = out[plan["inv_perm"]][:n_out, :batch].T
         else:
-            if route == "pipelined":
-                acc = _bw.bw_gemm_sparse_pipelined(
-                    digits, bt, plan["schedule"], block_m=block_m,
-                    block_n=block_n, block_k=block_k,
-                    interpret=bool(interpret))
-            elif route == "sparse":
-                acc = _bw.bw_gemm_sparse(
-                    digits, bt, plan["schedule"], block_m=block_m,
-                    block_n=block_n, block_k=block_k,
-                    interpret=bool(interpret))
-            else:
-                acc = _bw.bw_gemm(
-                    digits, bt, mask, block_m=block_m, block_n=block_n,
-                    block_k=block_k, radix=spec.radix,
-                    interpret=bool(interpret))
-            acc = acc[plan["inv_perm"]][:n_out, :batch]
-            sw = plan["sw_rows"][plan["inv_perm"]][:n_out]  # orig order
-            s = sw * (sx.reshape(1, -1) if per_token else sx)
-            y = (acc.astype(jnp.float32) * s).T
-            if bias is not None:
-                y = y + jnp.asarray(bias, jnp.float32)
-            if activation is not None:
-                y = _bw.EPILOGUE_ACTIVATIONS[activation](y)
+            acc = _gemm(route, False, digits, bt, mask, plan.get("schedule"),
+                        **blocks)
+            y = _unfused_epilogue(acc, plan, sx, spec, n_out, batch, bias,
+                                  activation)
     return y.reshape(*lead, n_out).astype(out_dtype)
 
 
@@ -1134,24 +1009,13 @@ def planned_experts_apply(plan: dict, x, spec, n_out: int, counts=None, *,
         interpret = _interpret()
     digits, mask = plan["digits"], plan["mask"]
     n_exp, bw_n, m_pad, k_pad = digits.shape
-    if bw_n != spec.num_digits:
-        raise ValueError(
-            f"expert plan has {bw_n} digit planes but spec "
-            f"{spec.encoding!r}/{spec.bits}b implies {spec.num_digits}")
+    _check_planes("expert plan", bw_n, spec)
     block_m, block_k = m_pad // mask.shape[2], k_pad // mask.shape[3]
-    _, t, k = x.shape
-    per_token = spec.act_quant == "per_token"
-    qx, sx = quantlib.quantize_for_spec(
-        jnp.asarray(x).astype(jnp.float32), spec,
-        axis=-1 if per_token else None)
-    if block_n is None:
-        block_n = select_block_sizes(n_out, k, t, spec)[2]
-    bt = _pad_to(_pad_to(jnp.swapaxes(qx, 1, 2), block_k, 1), block_n, 2)
-    sx_cols = None
+    t = x.shape[1]
+    bt, sx, sx_cols, block_n = _activations(x, spec, n_out, k_pad, block_n,
+                                            groups=1)
     scale_rows = plan["sw_rows"]
-    if per_token:                        # one scale per token: kernel N
-        sx_cols = _pad_to(jnp.swapaxes(sx, 1, 2), block_n, 2)
-    else:
+    if sx_cols is None:                  # per tensor: fold the act scale
         scale_rows = scale_rows * sx
     if counts is None:
         counts = jnp.full((n_exp,), t, jnp.int32)
@@ -1216,7 +1080,7 @@ def _plan_stack(w, spec, order: str) -> dict:
         *plans)
 
 
-def plan_params(params, spec, should_plan=None, order: Optional[str] = None):
+def plan_params(params, spec, should_plan=None, order: str = "m_major"):
     """Attach a 'w_plan' record next to every dense weight in a param tree.
 
     2-D weights get a single plan; 3-D weights (layer-stacked for scan) get
@@ -1233,15 +1097,10 @@ def plan_params(params, spec, should_plan=None, order: Optional[str] = None):
     get plans.  The default plans every dense "w" except dicts named in
     _NO_PLAN_KEYS (known raw-matmul consumers like the MoE router).
 
-    order: schedule visit order; None derives it from the spec's engine
-    (the pallas_pipelined engine plans k_major schedules for B-block
-    reuse, everything else m_major) so the plans match the order the
-    engine's apply() will dispatch under.
+    order: schedule visit order (SCHEDULE_ORDERS): the ``order`` of the
+    engine that will apply the plans.
     """
     spec = QuantSpec.coerce(spec)
-    if order is None:
-        order = "k_major" if spec is not None and \
-            spec.impl == "pallas_pipelined" else "m_major"
     count = 0
     if should_plan is None:
         def should_plan(path, _w):

@@ -22,6 +22,7 @@ grid), and the epilogue's inverse row permutation is global.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -29,11 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import chaos as _chaos
-from repro.core import quant as quantlib
 from repro.engine.spec import QuantSpec
-from repro.kernels.bw_gemm import (EPILOGUE_ACTIVATIONS, bw_gemm,
-                                   bw_gemm_sparse,
-                                   bw_gemm_sparse_pipelined)
 from repro.kernels import ops
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -59,32 +56,6 @@ def make_gemm_mesh(shards):
     s_data, s_model = (shards.shards if isinstance(shards, ShardedPlan)
                        else shards)
     return meshlib.make_mesh((s_data, s_model), (AXIS_DATA, AXIS_MODEL))
-
-
-def _resolve_route(splan: ShardedPlan, dispatch: str) -> str:
-    """Static shard-kernel routing, mirroring ops._resolve_dispatch rules.
-
-    One route for every shard (shard_map bodies must agree across
-    devices), picked from the *mean* shard density; the v2 sparse
-    kernels stay m_major-only, k_major plans take the pipelined kernels.
-    """
-    sparse_route = "pipelined" if splan.order == "k_major" else "sparse"
-    if dispatch == "dense":
-        return "dense"
-    if dispatch == "sparse":
-        if splan.order == "k_major":
-            raise ValueError(
-                "dispatch='sparse' (the v2 kernels) requires m_major "
-                "shard schedules — use dispatch='pipelined' (or 'auto')")
-        return "sparse"
-    if dispatch == "pipelined":
-        return "pipelined"
-    if dispatch != "auto":
-        raise ValueError(f"dispatch must be one of {ops.DISPATCHES}, "
-                         f"got {dispatch!r}")
-    density = float(splan.densities.mean())
-    return (sparse_route if density <= ops.SPARSE_DENSITY_THRESHOLD
-            else "dense")
 
 
 def sharded_planned_apply(splan: ShardedPlan, x, spec, n_out: int, *,
@@ -117,11 +88,7 @@ def sharded_planned_apply(splan: ShardedPlan, x, spec, n_out: int, *,
     plan = splan.plan
     digits, mask = plan["digits"], plan["mask"]
     bw_n, m_pad, k_pad = digits.shape
-    if bw_n != spec.num_digits:
-        raise ValueError(
-            f"sharded plan has {bw_n} digit planes but spec "
-            f"{spec.encoding!r}/{spec.bits}b implies {spec.num_digits}; "
-            f"was the plan built under a different spec?")
+    ops._check_planes("sharded plan", bw_n, spec)
     if spec.radix != splan.radix:
         raise ValueError(f"sharded plan was built with radix "
                          f"{splan.radix} but the spec implies "
@@ -133,17 +100,11 @@ def sharded_planned_apply(splan: ShardedPlan, x, spec, n_out: int, *,
             f"K={splan.k}; re-plan the weight or fix the reshape")
     s_data, s_model = splan.shards
     lead = x.shape[:-1]
-    per_token = spec.act_quant == "per_token"
-    with obs_trace.span("parallel.quantize", cat="parallel",
-                        k=int(k), per_token=per_token):
-        qx, sx = quantlib.quantize_for_spec(
-            jnp.asarray(x).astype(jnp.float32), spec,
-            axis=-1 if per_token else None)
-    x2 = qx.reshape(-1, k)
-    batch = x2.shape[0]
-    if block_n is None:
-        block_n = ops.select_block_sizes(n_out, k, batch, spec)[2]
-    bt = ops._pad_to(jnp.pad(x2.T, ((0, k_pad - k), (0, 0))), block_n, 1)
+    batch = math.prod(lead)
+    with obs_trace.span("parallel.quantize", cat="parallel", k=int(k),
+                        per_token=spec.act_quant == "per_token"):
+        bt, sx, _, block_n = ops._activations(x, spec, n_out, k_pad,
+                                              block_n)
     n_cols = bt.shape[1]
     if reduce not in REDUCES:
         raise ValueError(f"reduce must be one of {REDUCES}, got {reduce!r}")
@@ -153,7 +114,10 @@ def sharded_planned_apply(splan: ShardedPlan, x, spec, n_out: int, *,
         raise ValueError(
             f"psum_scatter needs the padded token axis ({n_cols}) to "
             f"divide by s_data={s_data}; use reduce='psum'")
-    route = _resolve_route(splan, dispatch)
+    # one route for every shard (shard_map bodies must agree across
+    # devices), picked from the mean shard density
+    route = ops.resolve_route(dispatch, splan.order,
+                              float(splan.densities.mean()))
     if mesh is None:
         mesh = make_gemm_mesh(splan)
     if (mesh.shape.get(AXIS_DATA), mesh.shape.get(AXIS_MODEL)) != \
@@ -161,24 +125,14 @@ def sharded_planned_apply(splan: ShardedPlan, x, spec, n_out: int, *,
         raise ValueError(
             f"mesh {dict(mesh.shape)} does not match the plan's shard "
             f"grid (data={s_data}, model={s_model})")
-    block_m, block_k = splan.block_m, splan.block_k
-    radix, interpret = splan.radix, bool(interpret)
+    blocks = dict(block_m=splan.block_m, block_n=block_n,
+                  block_k=splan.block_k, radix=splan.radix,
+                  interpret=bool(interpret))
     scheds = jnp.asarray(splan.schedules)
 
     def shard_body(d_l, m_l, s_l, b_l):
         sched = s_l.reshape(s_l.shape[-2], s_l.shape[-1])
-        if route == "pipelined":
-            acc = bw_gemm_sparse_pipelined(
-                d_l, b_l, sched, block_m=block_m, block_n=block_n,
-                block_k=block_k, interpret=interpret)
-        elif route == "sparse":
-            acc = bw_gemm_sparse(
-                d_l, b_l, sched, block_m=block_m, block_n=block_n,
-                block_k=block_k, interpret=interpret)
-        else:
-            acc = bw_gemm(
-                d_l, b_l, m_l, block_m=block_m, block_n=block_n,
-                block_k=block_k, radix=radix, interpret=interpret)
+        acc = ops._gemm(route, False, d_l, b_l, m_l, sched, **blocks)
         if scatter:
             return jax.lax.psum_scatter(acc, AXIS_DATA,
                                         scatter_dimension=1, tiled=True)
@@ -207,12 +161,6 @@ def sharded_planned_apply(splan: ShardedPlan, x, spec, n_out: int, *,
         )(digits, mask, scheds, bt)
     with obs_trace.span("parallel.epilogue", cat="parallel",
                         n_out=int(n_out), batch=int(batch)):
-        acc = acc[plan["inv_perm"]][:n_out, :batch]
-        sw = plan["sw_rows"][plan["inv_perm"]][:n_out]
-        s = sw * (sx.reshape(1, -1) if per_token else sx)
-        y = (acc.astype(jnp.float32) * s).T
-        if bias is not None:
-            y = y + jnp.asarray(bias, jnp.float32)
-        if activation is not None:
-            y = EPILOGUE_ACTIVATIONS[activation](y)
+        y = ops._unfused_epilogue(acc, plan, sx, spec, n_out, batch, bias,
+                                  activation)
         return y.reshape(*lead, n_out).astype(out_dtype)

@@ -41,7 +41,7 @@ def run(mesh_shape, m: int, k: int, batch: int, planes: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.engine import QuantSpec, get_engine
+    from repro.engine import QuantSpec, engine_for_route
     from repro.kernels import ops
     from repro.parallel.apply import make_gemm_mesh, sharded_planned_apply
     from repro.parallel.plan import plan_sharded_weight
@@ -87,12 +87,14 @@ def run(mesh_shape, m: int, k: int, batch: int, planes: int,
         out["parity"][order] = bool(
             np.allclose(got, want, rtol=1e-6, atol=1e-6))
         out["density"][order] = round(splan.density(), 4)
-        # serving orientation (tokens on M, output channels on N) — the
-        # same per-device reduce traffic TierRouter prices
-        impl = "pallas_pipelined" if order == "k_major" else "pallas_sparse"
-        cost = get_engine(impl).cost(batch, k, m, spec,
-                                     density=splan.density(),
-                                     shards=(s_data, s_model))
+        # the engine of the route sharded() takes, in serving orientation
+        # (tokens on M, output channels on N) — the same per-device
+        # reduce traffic TierRouter prices
+        route = ops.resolve_route("auto", order,
+                                  float(splan.densities.mean()))
+        cost = engine_for_route(route).cost(batch, k, m, spec,
+                                            density=splan.density(),
+                                            shards=(s_data, s_model))
         out["collective_bytes"][order] = int(cost["collective_bytes"])
         out["timing"][order] = {
             "single_s": round(t_single, 4),

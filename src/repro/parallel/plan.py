@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import encodings as enc
-from repro.engine.spec import QuantSpec
 from repro.kernels import ops
 
 from .collectives import normalize_shards
@@ -198,7 +197,7 @@ def shard_plan(plan, shards, *, radix: Optional[int] = None,
     return splan
 
 
-def plan_sharded_weight(w, spec, shards, order: Optional[str] = None,
+def plan_sharded_weight(w, spec, shards, order: str = "m_major",
                         verify: Optional[bool] = None) -> ShardedPlan:
     """Quantize + plan + shard a dense float weight [K, N].
 
@@ -206,9 +205,6 @@ def plan_sharded_weight(w, spec, shards, order: Optional[str] = None,
     plan cache (keyed with the shard grid — the same weight planned for
     two meshes holds two entries) and the always-on verification seam.
     """
-    spec = QuantSpec.coerce(spec)
-    if order is None:
-        order = "k_major" if spec.impl == "pallas_pipelined" else "m_major"
     planned, _sw = ops.plan_for(w, spec, order=order, verify=verify,
                                 shards=normalize_shards(shards))
     return planned.sharded

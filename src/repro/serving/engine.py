@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.engine import QuantSpec
+from repro.engine import QuantSpec, get_engine
 from repro.models import layers as L
 from repro.models.api import get_api
 from repro.obs import metrics as obs_metrics
@@ -137,10 +137,10 @@ class ServeEngine:
     jit'd serve step closes over it — engines with different specs coexist
     in one process without interfering.
 
-    With a kernel impl ("pallas" / "pallas_fused" / "pallas_sparse") the
-    engine serves through the kernel execution path: every dense weight is
-    pre-planned
-    once at init (encode -> digit planes -> occupancy mask ->
+    With an impl whose GemmEngine ``uses_plans`` (the Pallas kernel
+    engines) the engine serves through the kernel execution path: every
+    dense weight is pre-planned once at init, in that GemmEngine's
+    schedule order (encode -> digit planes -> occupancy mask ->
     magnitude-ordered channel permutation) and the plan records are
     attached to the param tree, so the jit'd serve step scans/slices them
     like any other parameter and each quantized matmul executes the Pallas
@@ -205,9 +205,8 @@ class ServeEngine:
             self._state0 = jax.tree.map(jnp.copy, self.state) \
                 if self.api.family in RESET_STATE_FAMILIES else None
             jax.block_until_ready((self.state, self._state0))
-        self._kernel_path = spec is not None and \
-            spec.impl in ("pallas", "pallas_fused", "pallas_sparse",
-                          "pallas_pipelined")
+        gemm = get_engine(spec.impl) if spec is not None else None
+        self._kernel_path = gemm is not None and gemm.uses_plans
         # measured plane-block density of the planned weights (the
         # schedule-aware cost input); None off the kernel path
         self.plan_density = None
@@ -219,7 +218,8 @@ class ServeEngine:
             # matmul executes the Pallas kernel.
             from repro.kernels import ops
             with obs_trace.region("serve.plan_params"):
-                self.params, planned = ops.plan_params(self.params, spec)
+                self.params, planned = ops.plan_params(
+                    self.params, spec, order=gemm.order)
                 jax.block_until_ready(self.params)
             self.plan_density = ops.plan_tree_density(self.params)
             self.quant.plan_stats = {

@@ -8,12 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _planned import planned_kernel
 from _propcheck import assert_cross_context_close
 from repro.core import encodings as enc
 from repro.core import quant as quantlib
 from repro.engine import (ACT_QUANT_POLICIES, IMPLS, QuantSpec,
                           engine_names, get_engine, spec_from_flags)
-from repro.kernels import ops
+from repro.kernels import bw_gemm as bwk, ops
 
 
 # ---------------------------------------------------------------------------
@@ -206,8 +207,7 @@ def test_bw_gemm_roundtrips_every_encoding_bit_exactly(encoding, rng):
     b = rng.integers(-128, 128, size=(64, 32)).astype(np.int8)
     planned = ops.plan_operand(a, encoding=encoding, block_m=64,
                                block_k=64)
-    got = np.asarray(ops.bw_gemm(planned, jnp.asarray(b), block_n=128,
-                                 interpret=True))
+    got = planned_kernel(bwk.bw_gemm, planned, b)
     want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
     np.testing.assert_array_equal(got, want)
 

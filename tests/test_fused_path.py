@@ -5,9 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _planned import planned_kernel
 from repro.core import bw_ref, quant as quantlib
 from repro.engine import QuantSpec
-from repro.kernels import ops
+from repro.kernels import bw_gemm as bwk, ops
 from repro.kernels.bw_gemm import EPILOGUE_ACTIVATIONS
 
 
@@ -79,8 +80,7 @@ def test_bw_gemm_fused_int_accumulator_exact(rng):
     b = rng.integers(-128, 128, size=(128, 64)).astype(np.int8)
     planned = ops.plan_operand(a, block_m=128, block_k=128)
     ones = np.ones((128,), np.float32)
-    got = np.asarray(ops.bw_gemm_fused(planned, jnp.asarray(b),
-                                       jnp.asarray(ones), interpret=True))
+    got = planned_kernel(bwk.bw_gemm_fused, planned, b, ones)
     want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
     np.testing.assert_array_equal(got.astype(np.int64), want)
 
@@ -169,8 +169,7 @@ def test_plan_operand_single_plane_regression(rng):
     assert planned.digits.shape[0] == 1
     # the plan must still be exact
     b = rng.integers(-128, 128, size=(32, 8)).astype(np.int8)
-    got = np.asarray(ops.bw_gemm(planned, jnp.asarray(b), block_n=128,
-                                 interpret=True))
+    got = planned_kernel(bwk.bw_gemm, planned, b)
     want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
     np.testing.assert_array_equal(got, want)
 

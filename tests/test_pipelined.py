@@ -17,6 +17,7 @@ try:
     from hypothesis import given, settings, strategies as hst
 except ImportError:                     # offline: deterministic fallback
     from _propcheck import given, settings, strategies as hst
+from _planned import planned_kernel
 from _propcheck import assert_cross_context_close
 
 from repro.core import quant as quantlib
@@ -196,13 +197,11 @@ def test_pipelined_fused_bit_matches_v2_fused(rng):
     pm = ops.plan_operand(a, block_m=128, block_k=128, order="m_major")
     pk = ops.plan_operand(a, block_m=128, block_k=128, order="k_major")
     for act in (None, "silu"):
-        v2 = np.asarray(ops.bw_gemm_sparse_fused(
-            pm, jnp.asarray(b), scale, bias, activation=act,
-            interpret=True))
+        v2 = planned_kernel(bwk.bw_gemm_sparse_fused, pm, b, scale, bias,
+                            activation=act)
         for planned in (pm, pk):
-            got = np.asarray(ops.bw_gemm_sparse_fused_pipelined(
-                planned, jnp.asarray(b), scale, bias, activation=act,
-                interpret=True))
+            got = planned_kernel(bwk.bw_gemm_sparse_fused_pipelined, planned,
+                                 b, scale, bias, activation=act)
             np.testing.assert_array_equal(got, v2)
 
 
@@ -245,17 +244,20 @@ def test_sparse_dispatch_rejects_k_major_schedule(rng):
 
 
 def test_v2_eager_wrappers_reject_k_major_plans(rng):
-    """The public eager wrappers must refuse a k_major PlannedOperand too:
-    on a real TPU the v2 out-BlockSpec would silently clobber partial sums
-    on non-consecutive revisits (interpret mode hides it)."""
-    a = _llmish(rng, 256, 256)
-    pk = ops.plan_operand(a, block_m=128, block_k=128, order="k_major")
-    b = jnp.zeros((256, 128), jnp.int8)
+    """Every way to the v2 kernels refuses a k_major plan, fused and
+    unfused, single-device and sharded (the one routing rule): on a real
+    TPU the v2 out-BlockSpec would silently clobber partial sums on
+    non-consecutive revisits (interpret mode hides it)."""
+    x = jnp.asarray(rng.normal(0, 1, size=(4, 96)).astype(np.float32))
+    w = jnp.asarray(rng.normal(0, 0.05, size=(96, 64)).astype(np.float32))
+    spec = QuantSpec(planes=3, impl="pallas_pipelined")
+    plan = ops.plan_dense_weight(w, spec, order="k_major")
+    for fused in (True, False):
+        with pytest.raises(ValueError, match="m_major"):
+            ops.planned_dense_apply(plan, x, spec, 64, fused=fused,
+                                    dispatch="sparse", order="k_major")
     with pytest.raises(ValueError, match="m_major"):
-        ops.bw_gemm_sparse(pk, b, interpret=True)
-    with pytest.raises(ValueError, match="m_major"):
-        ops.bw_gemm_sparse_fused(pk, b, np.ones(256, np.float32),
-                                 interpret=True)
+        ops.resolve_route("sparse", "k_major", 0.0)
 
 
 def test_auto_dispatch_ignores_nontransferable_winner(rng):
@@ -332,7 +334,8 @@ def test_pipelined_dispatch_inside_jit_and_scan(rng):
     spec = QuantSpec(planes=3, impl="pallas_pipelined",
                      act_quant="per_token")
     stacked = jnp.asarray(np.stack([w, np.zeros_like(w), w * 3]))
-    params, count = ops.plan_params({"lyr": {"w": stacked}}, spec)
+    params, count = ops.plan_params({"lyr": {"w": stacked}}, spec,
+                                    order="k_major")
     assert count == 3
     wp = params["lyr"]["w_plan"]
     assert wp["schedule"].ndim == 3      # [layers, L, 9], equal L

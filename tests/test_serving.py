@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
-from repro.engine import QuantSpec
+from repro.engine import QuantSpec, engine_names, get_engine
 from repro.serving import (AsyncServer, DECODE, DONE, PREFILL, QUEUED,
                            REJECTED, Scheduler, ServeEngine, ServeRequest,
                            SlotAllocator, Tier, TierRouter, default_tiers,
@@ -283,3 +283,52 @@ def test_async_server_realtime_mode_matches_virtual_outputs():
     assert {r.rid: r.out for r in virt_reqs} == \
         {r.rid: r.out for r in real_reqs}
     assert threading.active_count() < 10        # worker threads joined
+
+
+# ---------------------------------------------------------------------------
+# the GEMM engine decides what the serving engine plans
+# ---------------------------------------------------------------------------
+
+def _plan_records(node):
+    """Every 'w_plan' record of a param tree."""
+    if not isinstance(node, dict):
+        return []
+    found = [node["w_plan"]] if "w_plan" in node else []
+    for key, value in node.items():
+        if key != "w_plan":
+            found += _plan_records(value)
+    return found
+
+
+@pytest.mark.parametrize("impl", engine_names())
+def test_engine_plans_exactly_when_the_gemm_engine_uses_plans(impl):
+    """ServeEngine attaches 'w_plan' records exactly when the impl's
+    GemmEngine ``uses_plans``, with schedules in that engine's ``order``
+    (rebuilt here from each record's mask in both orders: at these widths
+    the orders differ)."""
+    from repro.kernels import ops
+    gemm = get_engine(impl)
+    cfg = get_config("minicpm-2b", smoke=True).replace(
+        n_layers=2, d_model=256, n_heads=4, n_kv_heads=4, d_ff=512,
+        vocab_size=1024)
+    spec = QuantSpec(planes=3, impl=impl)
+    eng = ServeEngine(cfg, batch=2, max_len=16, quant=spec)
+    records = _plan_records(eng.params)
+    if not gemm.uses_plans:
+        assert records == [] and eng.plan_density is None
+        return
+    assert records and eng.quant.plan_stats["planned_weights"] > 0
+    orders_differ = False
+    for rec in records:
+        masks = np.asarray(rec["mask"]).reshape(
+            (-1,) + np.asarray(rec["mask"]).shape[-3:])
+        scheds = np.asarray(rec["schedule"]).reshape(
+            (-1,) + np.asarray(rec["schedule"]).shape[-2:])
+        for mask, sched in zip(masks, scheds):
+            want = {order: ops.pad_schedule(
+                        ops.build_schedule(mask, spec.radix, order),
+                        sched.shape[0])
+                    for order in ops.SCHEDULE_ORDERS}
+            np.testing.assert_array_equal(sched, want[gemm.order])
+            orders_differ |= not np.array_equal(*want.values())
+    assert orders_differ

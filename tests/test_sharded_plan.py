@@ -260,3 +260,66 @@ def test_require_devices_names_failing_axis():
         require_devices(8, shape=(2, 2), axes=("data", "model"))
     # the trivial mesh always fits
     require_devices(1, shape=(1, 1), axes=("data", "model"))
+
+
+# ---------------------------------------------------------------------------
+# one routing rule: the single-device and the sharded apply agree
+# ---------------------------------------------------------------------------
+
+class _Routed(Exception):
+    """Raised at the kernel seam with the route a path handed it."""
+
+
+def _route_taken(monkeypatch, call):
+    """The route ``call`` hands ``ops._gemm`` (the one kernel seam of both
+    apply paths), or the ValueError it raises before reaching it."""
+    def seam(route, *args, **kwargs):
+        raise _Routed(route)
+    monkeypatch.setattr(ops, "_gemm", seam)
+    try:
+        call()
+    except _Routed as r:
+        return r.args[0]
+    except ValueError as e:
+        return f"ValueError: {e}"
+    raise AssertionError("the call never reached a kernel")
+
+
+@pytest.mark.parametrize("density", ("low", "high"))
+@pytest.mark.parametrize("order", ops.SCHEDULE_ORDERS)
+@pytest.mark.parametrize("dispatch", ops.DISPATCHES)
+def test_single_device_and_sharded_resolve_the_same_route(
+        dispatch, order, density, monkeypatch):
+    """For every dispatch x schedule order, on a sparse plan (one digit
+    plane) and a dense one (all four), ``planned_dense_apply`` and
+    ``sharded_planned_apply`` reach the kernel seam with the same route,
+    or refuse the same combination with the same error."""
+    import jax.numpy as jnp
+    from repro.kernels import autotune
+    from repro.parallel.apply import make_gemm_mesh, sharded_planned_apply
+    rng = np.random.default_rng(3)
+    spec = QuantSpec(planes=1 if density == "low" else 4, block_m=128,
+                     block_k=128, act_quant="per_token")
+    w = rng.normal(0, 0.05, size=(256, 256)).astype(np.float32)
+    x = jnp.asarray(rng.normal(0, 1, size=(4, 256)).astype(np.float32))
+    planned, sw = ops.plan_for(w, spec, order=order)
+    plan = ops.plan_dense_weight(w, spec, order=order)
+    splan = shard_plan(planned, (1, 1), sw=sw)
+    autotune.set_cache(autotune.AutotuneCache("mem"))   # no measured winner
+    try:
+        single = _route_taken(monkeypatch, lambda: ops.planned_dense_apply(
+            plan, x, spec, 256, fused=False, block_n=128, dispatch=dispatch,
+            order=order))
+        sharded = _route_taken(monkeypatch, lambda: sharded_planned_apply(
+            splan, x, spec, 256, block_n=128, dispatch=dispatch,
+            mesh=make_gemm_mesh((1, 1))))
+    finally:
+        autotune.reset_cache()
+    assert single == sharded
+    sparse_route = "pipelined" if order == "k_major" else "sparse"
+    if dispatch == "sparse" and order == "k_major":
+        assert single.startswith("ValueError") and "m_major" in single
+    elif dispatch == "auto":
+        assert single == (sparse_route if density == "low" else "dense")
+    else:
+        assert single == dispatch

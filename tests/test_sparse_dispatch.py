@@ -14,11 +14,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from _planned import planned_kernel
 from _propcheck import assert_cross_context_close
 from repro.core import encodings as enc
 from repro.core import quant as quantlib
 from repro.engine import QuantSpec, get_engine
-from repro.kernels import ops
+from repro.kernels import bw_gemm as bwk, ops
 from repro.kernels.bw_gemm import SCHED_COLS
 
 
@@ -93,9 +94,8 @@ def test_sparse_bit_matches_dense_random(rng):
     a = _llmish(rng, 256, 256)
     b = rng.integers(-128, 128, size=(256, 128)).astype(np.int8)
     planned = ops.plan_operand(a, block_m=128, block_k=128)
-    dense = np.asarray(ops.bw_gemm(planned, jnp.asarray(b), interpret=True))
-    sparse = np.asarray(ops.bw_gemm_sparse(planned, jnp.asarray(b),
-                                           interpret=True))
+    dense = planned_kernel(bwk.bw_gemm, planned, b)
+    sparse = planned_kernel(bwk.bw_gemm_sparse, planned, b)
     want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
     np.testing.assert_array_equal(sparse, dense)
     np.testing.assert_array_equal(sparse, want)
@@ -108,12 +108,10 @@ def test_sparse_fused_bit_matches_dense_fused(rng):
     bias = rng.normal(0, 0.1, size=(256,)).astype(np.float32)
     planned = ops.plan_operand(a, block_m=128, block_k=128)
     for act in (None, "silu"):
-        dense = np.asarray(ops.bw_gemm_fused(
-            planned, jnp.asarray(b), scale, bias, activation=act,
-            interpret=True))
-        sparse = np.asarray(ops.bw_gemm_sparse_fused(
-            planned, jnp.asarray(b), scale, bias, activation=act,
-            interpret=True))
+        dense = planned_kernel(bwk.bw_gemm_fused, planned, b, scale, bias,
+                               activation=act)
+        sparse = planned_kernel(bwk.bw_gemm_sparse_fused, planned, b, scale,
+                                bias, activation=act)
         np.testing.assert_array_equal(sparse, dense)
 
 
@@ -126,8 +124,7 @@ def test_sparse_adversarial_high_plane_only(rng):
     planned = ops.plan_operand(a, block_m=128, block_k=128)
     st = ops.schedule_stats(planned.schedule, planned.mask)
     assert st["nnz_blocks"] == 1, st
-    got = np.asarray(ops.bw_gemm_sparse(planned, jnp.asarray(b),
-                                        interpret=True))
+    got = planned_kernel(bwk.bw_gemm_sparse, planned, b)
     want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
     np.testing.assert_array_equal(got, want)
 
@@ -141,11 +138,10 @@ def test_sparse_all_zero_plane_returns_exact_zeros(rng):
     st = ops.schedule_stats(planned.schedule, planned.mask)
     assert st["nnz_blocks"] == 0 and st["density"] == 0.0
     assert st["steps"] == planned.mask.shape[1]          # one per row
-    got = np.asarray(ops.bw_gemm_sparse(planned, jnp.asarray(b),
-                                        interpret=True))
+    got = planned_kernel(bwk.bw_gemm_sparse, planned, b)
     assert got.shape == (256, 128) and (got == 0).all()
-    fused = np.asarray(ops.bw_gemm_sparse_fused(
-        planned, jnp.asarray(b), np.ones(256, np.float32), interpret=True))
+    fused = planned_kernel(bwk.bw_gemm_sparse_fused, planned, b,
+                           np.ones(256, np.float32))
     assert (fused == 0).all()
 
 
@@ -158,9 +154,8 @@ def test_sparse_fully_dense_mask_bit_matches_dense(rng):
     assert planned.density() == 1.0
     assert planned.schedule.shape[0] == planned.mask.size
     b = rng.integers(-128, 128, size=(128, 128)).astype(np.int8)
-    dense = np.asarray(ops.bw_gemm(planned, jnp.asarray(b), interpret=True))
-    sparse = np.asarray(ops.bw_gemm_sparse(planned, jnp.asarray(b),
-                                           interpret=True))
+    dense = planned_kernel(bwk.bw_gemm, planned, b)
+    sparse = planned_kernel(bwk.bw_gemm_sparse, planned, b)
     np.testing.assert_array_equal(sparse, dense)
 
 
@@ -171,8 +166,7 @@ def test_sparse_roundtrips_every_encoding(encoding, rng):
     a = rng.integers(-128, 128, size=(64, 64)).astype(np.int8)
     b = rng.integers(-128, 128, size=(64, 32)).astype(np.int8)
     planned = ops.plan_operand(a, encoding=encoding, block_m=64, block_k=64)
-    got = np.asarray(ops.bw_gemm_sparse(planned, jnp.asarray(b),
-                                        block_n=128, interpret=True))
+    got = planned_kernel(bwk.bw_gemm_sparse, planned, b)
     want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
     np.testing.assert_array_equal(got, want)
 
